@@ -16,13 +16,13 @@ JSONL and a Perfetto-loadable Chrome trace."""
 from __future__ import annotations
 
 import json
-import pathlib
 import sys
 import time
 import traceback
 
 from repro import obs
 from repro.analysis.retrace import install_compile_listener
+from repro.compile_cache import REPO_ROOT, enable_compile_cache
 
 MODULES = [
     ("fig1", "benchmarks.fig1_capacity"),
@@ -43,7 +43,7 @@ MODULES = [
 ]
 
 #: The accreting perf-trajectory file — one JSON list, one row per run.
-TRAJECTORY = pathlib.Path("BENCH_OBS.json")
+TRAJECTORY = REPO_ROOT / "BENCH_OBS.json"
 
 
 def append_trajectory(benches: dict, failures: int) -> None:
@@ -71,6 +71,7 @@ def append_trajectory(benches: dict, failures: int) -> None:
 
 def main() -> None:
     want = set(sys.argv[1:])
+    enable_compile_cache()
     install_compile_listener()  # compile events -> obs bus for every bench
     print("name,us_per_call,derived")
     failures = 0

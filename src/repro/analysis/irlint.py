@@ -60,7 +60,7 @@ import sys
 from typing import Iterator
 
 import jax
-from jax.core import ClosedJaxpr, Jaxpr
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
 from .linter import _dotted, _pragma_ids
 from .registry import IR_RULES, SOLVER_MODULES, AuditCase, SolverEntry, \

@@ -186,12 +186,9 @@ _APSP_PLATFORM: str | None = None
 def _apsp_platform() -> str:
     global _APSP_PLATFORM
     if _APSP_PLATFORM is None:
-        try:
-            import jax
+        import jax
 
-            _APSP_PLATFORM = jax.default_backend()
-        except Exception:  # pragma: no cover - jax always present here
-            _APSP_PLATFORM = "cpu"
+        _APSP_PLATFORM = jax.default_backend()
     return _APSP_PLATFORM
 
 

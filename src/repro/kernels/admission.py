@@ -10,8 +10,8 @@ the pair's length budget AND keeps the prefix simple:
 
 The numpy form materializes an (M, W, C) boolean broadcast for the
 membership test — at 10k-switch scale that temporary is the level's peak
-allocation.  The kernel here fuses the comparison with a W-step
-``fori_loop`` over the prefix columns, keeping only the (bm, bc) block and
+allocation.  The kernel here fuses the comparison with a W-step unrolled
+loop over the prefix columns, keeping only the (bm, bc) block and
 a same-shape accumulator resident; the ref backend is the same computation
 as straight-line jnp (the oracle the kernel is validated against).
 
@@ -67,18 +67,18 @@ def check_admission_dtype(*arrays) -> tuple:
 
 
 def admission_kernel(d_ref, r_ref, c_ref, p_ref, o_ref):
-    """One (bm, bc) mask block: compare + prefix-membership fori_loop."""
+    """One (bm, bc) mask block: compare + prefix-membership over W columns.
+
+    The W prefix columns are unrolled at trace time (W is a few hops, padded
+    to a sublane multiple): Mosaic cannot lower a slice of a loaded value at
+    a loop-carried index.
+    """
     ok = d_ref[...] <= r_ref[...]  # (bm, bc) <= (bm, 1) broadcast
     cand = c_ref[...]  # (bm, bc) int32
     pref = p_ref[...]  # (bm, W) int32, -1 beyond the prefix
-    w = pref.shape[1]
-
-    def body(t, seen):
-        return seen | (pref[:, t][:, None] == cand)
-
-    seen = jax.lax.fori_loop(
-        0, w, body, jnp.zeros(cand.shape, dtype=jnp.bool_)
-    )
+    seen = jnp.zeros(cand.shape, dtype=jnp.bool_)
+    for t in range(pref.shape[1]):
+        seen = seen | (pref[:, t : t + 1] == cand)
     o_ref[...] = (ok & ~seen).astype(jnp.int8)
 
 

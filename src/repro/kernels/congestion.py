@@ -15,16 +15,23 @@ memory-bound op — the kind of TPU-native restructuring the brief asks for
 reformulation of the multicommodity inner product).
 
 Grid: (P/bp, E/be), E innermost.
-  loads tile (1, be)  accumulates across the P-blocks  (init at pi == 0)
-  costs tile (bp, 1)  accumulates across the E-blocks  (init at ei == 0)
-Both accumulators are single-tile VMEM residents; B tiles are (bp, be).
+  costs tile (bp, 1)    accumulates across the E-blocks  (init at ei == 0)
+  loads block (E/be, be) stays resident for the whole grid; row ei
+                        accumulates across the P-blocks  (set at pi == 0)
+B tiles are (bp, be).  The loads block is resident because Pallas on TPU
+keeps an output block's contents only across consecutive grid steps that
+share its index: a (1, be) loads tile indexed by ei would be revisited once
+per P-block, which the TPU interpreter refuses and the compiler does not
+promise to read back.  The resident block costs E*4 bytes of VMEM (1.2 MB
+at 2E = 295k).
 
 Batched form: a stacked rank-3 incidence (Bt, P, E) with (Bt, P) rates and
 (Bt, E) prices runs the same kernel under a (Bt, P/bp, E/be) grid — the
 batch dimension is outermost, so each batch member still makes exactly one
-pass over its own B tiles per call and the accumulator tiles reset when the
-grid advances to the next member (pi == 0 / ei == 0 hold at each member's
-first visit).  This is the inner loop of ``core.flow.mw_concurrent_flow_batch``
+pass over its own B tiles per call, its loads block is resident over its
+whole (P/bp, E/be) sub-grid, and the accumulators reset when the grid
+advances to the next member (pi == 0 / ei == 0 hold at each member's first
+visit).  This is the inner loop of ``core.flow.mw_concurrent_flow_batch``
 on TPU: Bt independent MW instances per iteration with one fused launch.
 """
 
@@ -75,13 +82,29 @@ def check_congestion_dtype(incidence, rates, prices) -> tuple:
     return tuple(out)
 
 
+def _dot(x, y):
+    """f32 MXU product at full precision: rates and prices are f32, and a
+    single bf16 pass would round them to 8 mantissa bits."""
+    return jnp.dot(x, y, precision=jax.lax.Precision.HIGHEST,
+                   preferred_element_type=jnp.float32)
+
+
+def _accumulate_loads(loads_ref, pi, ei, contrib):
+    """Add one (1, be) loads contribution into row ``ei`` of the resident
+    (E/be, be) loads block; the first P-block writes instead of adding."""
+
+    @pl.when(pi == 0)
+    def _first():
+        loads_ref[pl.ds(ei, 1), :] = contrib
+
+    @pl.when(pi > 0)
+    def _rest():
+        loads_ref[pl.ds(ei, 1), :] += contrib
+
+
 def congestion_kernel(b_ref, r_ref, w_ref, loads_ref, costs_ref):
     pi = pl.program_id(0)
     ei = pl.program_id(1)
-
-    @pl.when(pi == 0)
-    def _init_loads():
-        loads_ref[...] = jnp.zeros_like(loads_ref)
 
     @pl.when(ei == 0)
     def _init_costs():
@@ -91,19 +114,15 @@ def congestion_kernel(b_ref, r_ref, w_ref, loads_ref, costs_ref):
     r = r_ref[...]  # (1, bp)
     w = w_ref[...]  # (1, be)
     # loads block: r (1, bp) @ B (bp, be) -> (1, be)
-    loads_ref[...] += jnp.dot(r, b, preferred_element_type=loads_ref.dtype)
+    _accumulate_loads(loads_ref, pi, ei, _dot(r, b))
     # costs block: B (bp, be) @ w^T (be, 1) -> (bp, 1)
-    costs_ref[...] += jnp.dot(b, w.T, preferred_element_type=costs_ref.dtype)
+    costs_ref[...] += _dot(b, w.T)
 
 
 def congestion_batch_kernel(b_ref, r_ref, w_ref, loads_ref, costs_ref):
     """Per-batch-member fused pass; grid (Bt, P/bp, E/be), E innermost."""
     pi = pl.program_id(1)
     ei = pl.program_id(2)
-
-    @pl.when(pi == 0)
-    def _init_loads():
-        loads_ref[...] = jnp.zeros_like(loads_ref)
 
     @pl.when(ei == 0)
     def _init_costs():
@@ -112,8 +131,8 @@ def congestion_batch_kernel(b_ref, r_ref, w_ref, loads_ref, costs_ref):
     b = b_ref[0]  # (bp, be)
     r = r_ref[0]  # (1, bp)
     w = w_ref[0]  # (1, be)
-    loads_ref[0, ...] += jnp.dot(r, b, preferred_element_type=loads_ref.dtype)
-    costs_ref[0, ...] += jnp.dot(b, w.T, preferred_element_type=costs_ref.dtype)
+    _accumulate_loads(loads_ref.at[0], pi, ei, _dot(r, b))
+    costs_ref[0, ...] += _dot(b, w.T)
 
 
 @solver_jit(spec="_ir_cases_congestion_batch")
@@ -135,6 +154,7 @@ def _congestion_pallas_batch(
     r_p = jnp.pad(rates, ((0, 0), (0, pp)))[:, None, :]
     w_p = jnp.pad(prices, ((0, 0), (0, ep)))[:, None, :]
     _, Pp, Ep = b_p.shape
+    ne = Ep // be
     loads, costs = pl.pallas_call(
         congestion_batch_kernel,
         grid=(Bt, Pp // bp, Ep // be),
@@ -144,16 +164,16 @@ def _congestion_pallas_batch(
             pl.BlockSpec((1, 1, be), lambda bi, pi, ei: (bi, 0, ei)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, be), lambda bi, pi, ei: (bi, 0, ei)),
+            pl.BlockSpec((1, ne, be), lambda bi, pi, ei: (bi, 0, 0)),
             pl.BlockSpec((1, bp, 1), lambda bi, pi, ei: (bi, pi, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((Bt, 1, Ep), jnp.float32),
+            jax.ShapeDtypeStruct((Bt, ne, be), jnp.float32),
             jax.ShapeDtypeStruct((Bt, Pp, 1), jnp.float32),
         ],
         interpret=interpret,
     )(b_p, r_p, w_p)
-    return loads[:, 0, :E], costs[:, :P, 0]
+    return loads.reshape(Bt, Ep)[:, :E], costs[:, :P, 0]
 
 
 @solver_jit(spec="_ir_cases_congestion")
@@ -188,6 +208,7 @@ def congestion_pallas(
     r_p = jnp.pad(rates, (0, pp))[None, :]  # (1, Pp)
     w_p = jnp.pad(prices, (0, ep))[None, :]  # (1, Ep)
     Pp, Ep = b_p.shape
+    ne = Ep // be
     loads, costs = pl.pallas_call(
         congestion_kernel,
         grid=(Pp // bp, Ep // be),
@@ -197,16 +218,16 @@ def congestion_pallas(
             pl.BlockSpec((1, be), lambda pi, ei: (0, ei)),
         ],
         out_specs=[
-            pl.BlockSpec((1, be), lambda pi, ei: (0, ei)),
+            pl.BlockSpec((ne, be), lambda pi, ei: (0, 0)),
             pl.BlockSpec((bp, 1), lambda pi, ei: (pi, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((1, Ep), jnp.float32),
+            jax.ShapeDtypeStruct((ne, be), jnp.float32),
             jax.ShapeDtypeStruct((Pp, 1), jnp.float32),
         ],
         interpret=interpret,
     )(b_p, r_p, w_p)
-    return loads[0, :E], costs[:P, 0]
+    return loads.reshape(Ep)[:E], costs[:P, 0]
 
 
 # ---- IR audit cases (python -m repro.analysis ir) ------------------------- #

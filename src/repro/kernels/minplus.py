@@ -13,8 +13,9 @@ over the K dimension, tiled so the working set stays in VMEM:
   grid = (M/bm, N/bn, K/bk), K innermost for sequential accumulation.
   For each (i, j, k): acc[bm, bn] = min(acc, min_over_t(a[:, t] + b[t, :])).
 
-The K-slice loop is a ``lax.fori_loop`` over the bk dimension, keeping the
-(bm, bn) accumulator resident and avoiding an O(bm*bk*bn) broadcast in VMEM.
+The K-slice loop is unrolled over the bk dimension with static slices,
+keeping the (bm, bn) accumulator resident and avoiding an O(bm*bk*bn)
+broadcast in VMEM.
 Default tiles (128, 128, 128) hold 3 f32 buffers = 192 KiB << 16 MiB VMEM;
 the lane dimension is 128-aligned as the VPU wants.
 """
@@ -59,7 +60,13 @@ def check_minplus_dtype(*arrays) -> tuple:
 
 
 def minplus_kernel(a_ref, b_ref, o_ref):
-    """One (bm, bn) output tile; accumulates the min over K blocks."""
+    """One (bm, bn) output tile; accumulates the min over K blocks.
+
+    The bk rank-1 updates are unrolled at trace time: each takes a static
+    column of the A tile and a static row of the B tile.  Mosaic cannot
+    lower a slice of a loaded value at a loop-carried index (a
+    ``dynamic_slice`` on the lane axis), which a ``fori_loop`` over t needs.
+    """
 
     @pl.when(pl.program_id(2) == 0)
     def _init():
@@ -67,14 +74,10 @@ def minplus_kernel(a_ref, b_ref, o_ref):
 
     a = a_ref[...]  # (bm, bk)
     b = b_ref[...]  # (bk, bn)
-    bk = a.shape[1]
-
-    def body(t, acc):
-        # rank-1 tropical update: candidates via column t of a + row t of b
-        cand = a[:, t][:, None] + b[t, :][None, :]
-        return jnp.minimum(acc, cand)
-
-    acc = jax.lax.fori_loop(0, bk, body, o_ref[...])
+    acc = o_ref[...]
+    for t in range(a.shape[1]):
+        # rank-1 tropical update: column t of a + row t of b
+        acc = jnp.minimum(acc, a[:, t : t + 1] + b[t : t + 1, :])
     o_ref[...] = acc
 
 

@@ -53,7 +53,8 @@ def matmul_kernel(a_ref, b_ref, o_ref):
         o_ref[...] = jnp.zeros_like(o_ref)
 
     o_ref[...] += jnp.dot(
-        a_ref[...], b_ref[...], preferred_element_type=o_ref.dtype
+        a_ref[...], b_ref[...], precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=o_ref.dtype,
     )
 
 

@@ -11,11 +11,17 @@ import jax
 __all__ = ["make_production_mesh", "make_local_mesh"]
 
 
+def _auto(n: int) -> tuple:
+    """Auto axes: the runtime places arrays with ``with_sharding_constraint``,
+    which refuses the Explicit axes ``jax.make_mesh`` makes by default."""
+    return (jax.sharding.AxisType.Auto,) * n
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips/pod ('data','model'); 2 pods = 512 chips multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def make_local_mesh(model_parallel: int | None = None):
@@ -24,7 +30,7 @@ def make_local_mesh(model_parallel: int | None = None):
     mp = model_parallel or 1
     while n % mp:
         mp //= 2
-    return jax.make_mesh((n // mp, mp), ("data", "model"))
+    return jax.make_mesh((n // mp, mp), ("data", "model"), axis_types=_auto(2))
 
 
 def make_fabric_aware_mesh(fabric, pods: int, per_pod_shape=(16, 16)):

@@ -142,9 +142,7 @@ def test_jf102_fires_on_scatter_under_gather_backend():
 
 
 def test_jf103_fires_on_f64_and_silences_on_f32():
-    from jax.experimental import enable_x64
-
-    with enable_x64():
+    with jax.enable_x64():
         closed = jax.make_jaxpr(lambda v: v * 2.0)(np.ones(3, np.float64))
     entry = SolverEntry(module="toy", attr="f64")
     case = AuditCase(label="t", make=lambda: ((), {}))
@@ -346,23 +344,23 @@ def test_gather_backend_scatter_regression_caught(monkeypatch, fresh_traces):
 # golden primitive censuses (congestion backends + _path_cost_gather)
 # --------------------------------------------------------------------------- #
 
-# Pinned at PR 8 on jax 0.4.37.  A census change here means the lowering of
+# Pinned on jax 0.9.0.  A census change here means the lowering of
 # a bit-exactness-critical closure changed: update deliberately, with the
 # same scrutiny as an artifacts/ir_budget.json refresh.
 _GOLDEN = {
     "scatter": {
         "add": 8, "broadcast_in_dim": 5, "concatenate": 1, "gather": 3,
-        "lt": 4, "pjit": 3, "reshape": 6, "scatter-add": 1, "select_n": 4,
+        "lt": 4, "jit": 3, "reshape": 6, "scatter-add": 1, "select_n": 4,
         "slice": 4, "squeeze": 3,
     },
     "gather": {
         "add": 14, "broadcast_in_dim": 5, "concatenate": 2, "gather": 7,
-        "lt": 7, "pjit": 7, "reshape": 8, "select_n": 7, "slice": 7,
+        "lt": 7, "jit": 7, "reshape": 8, "select_n": 7, "slice": 7,
         "squeeze": 7,
     },
-    "dense": {"dot_general": 2, "pjit": 1},
+    "dense": {"dot_general": 2, "jit": 1},
     "path_cost_gather": {
-        "add": 6, "broadcast_in_dim": 1, "gather": 3, "lt": 3, "pjit": 3,
+        "add": 6, "broadcast_in_dim": 1, "gather": 3, "lt": 3, "jit": 3,
         "reshape": 3, "select_n": 3, "slice": 3, "squeeze": 3,
     },
 }

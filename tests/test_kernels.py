@@ -98,6 +98,28 @@ def test_congestion_batched_matches_ref(shape, block):
     np.testing.assert_allclose(np.asarray(cg), np.asarray(cw), rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("batched", [False, True], ids=["rank2", "rank3"])
+def test_congestion_tpu_interpret_multi_block(batched):
+    """P and 2E each span several (bp, be) blocks, one edge of each
+    unaligned.  The TPU interpreter refuses an output block revisited after
+    the grid moved off it, which Pallas on TPU does not promise to read
+    back; a loads tile that accumulated across P-blocks with E innermost
+    fails here."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    bp, be = 16, 128
+    P, E = 5 * bp + 3, 3 * be
+    shape = (2, P, E) if batched else (P, E)
+    B = jnp.asarray((RNG.uniform(size=shape) < 0.15).astype(np.float32))
+    r = jnp.asarray(RNG.uniform(size=shape[:-1]).astype(np.float32))
+    w = jnp.asarray(RNG.uniform(size=shape[:-2] + (E,)).astype(np.float32))
+    lg, cg = congestion_pallas(B, r, w, bp=bp, be=be,
+                               interpret=pltpu.InterpretParams())
+    lw, cw = ref.congestion_ref(B, r, w)
+    np.testing.assert_allclose(np.asarray(lg), np.asarray(lw), rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(cg), np.asarray(cw), rtol=1e-5, atol=1e-5)
+
+
 def test_congestion_batched_members_match_single():
     """Each rank-3 member equals its own rank-2 solve (both backends)."""
     Bt, P, E = 3, 23, 31

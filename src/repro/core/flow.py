@@ -1182,6 +1182,12 @@ def mw_concurrent_flow_batch(
     ``warm`` is an optional per-instance sequence of predecessor flow
     results/rate vectors, applied through each instance's ``row_map``
     exactly as in ``mw_concurrent_flow``.
+
+    Traced (``repro.obs``), the host phases are spans: ``mw/assemble``
+    (stacking a sequence; ``rows``), ``mw/upload`` (the host tables sent
+    and the carry's set-up; ``bytes``), ``mw/window_batch`` (one per window
+    dispatched) and ``mw/readback`` (the final evaluation copied back: the
+    host's wait for the device).
     """
     n_asked: int | None = None
     if isinstance(systems, PathSystemBatch):
@@ -1192,11 +1198,13 @@ def mw_concurrent_flow_batch(
         # bucket the batch size too (with masked-out empty fillers), so
         # probe waves of nearby sizes land on one compiled window scan
         pad_b = _bucket_up(n_asked, 4) if n_asked > 1 else n_asked
-        if pad_b != n_asked:
-            systems = systems + [
-                _empty_path_system() for _ in range(pad_b - n_asked)
-            ]
-        batch = PathSystemBatch.from_systems(systems)
+        with obs.span("mw/assemble",
+                      rows=sum(ps.n_paths for ps in systems)):
+            if pad_b != n_asked:
+                systems = systems + [
+                    _empty_path_system() for _ in range(pad_b - n_asked)
+                ]
+            batch = PathSystemBatch.from_systems(systems)
     B = batch.n_batch
     empty = batch.n_paths == 0
     method_tag = "mw-batch"
@@ -1211,33 +1219,32 @@ def mw_concurrent_flow_batch(
     if backend == "gather" and batch.slot_gather is None:
         backend = "scatter"  # skew guard tripped or a hand-built batch
     method_tag = f"mw-batch-{backend}"
-    slot_tab = (
-        jnp.asarray(batch.slot_gather) if backend == "gather" else None
-    )
-    owner_tab = (
-        jnp.asarray(batch.owner_gather)
-        if backend == "gather" and batch.owner_gather is not None
-        else None
-    )
     x_init = np.ones((B, batch.p_max), dtype=np.float32)
     if warm is not None:
         for i, (ps, w) in enumerate(zip(batch.systems, warm)):
             if w is not None and ps.row_map is not None and ps.n_paths:
                 x_init[i, : ps.n_paths] = _warm_split(ps, w)
-    pe = jnp.asarray(batch.path_edges)
-    owner = jnp.asarray(batch.path_owner)
-    demands = jnp.asarray(batch.demands)
-    inv_cap = jnp.asarray(batch.inv_cap)
-    slot_valid = jnp.asarray(batch.slot_valid)
-    carry = _mw_carry_init_batch(jnp.asarray(x_init), owner, inv_cap, demands)
+    tabs = ((batch.slot_gather, batch.owner_gather) if backend == "gather"
+            else (None, None))
+    host = (batch.path_edges, batch.path_owner, batch.demands,
+            batch.inv_cap, batch.slot_valid, x_init, *tabs)
+    with obs.span("mw/upload",
+                  bytes=sum(a.nbytes for a in host if a is not None)):
+        pe, owner, demands, inv_cap, slot_valid, x_dev, slot_tab, owner_tab = (
+            None if a is None else jnp.asarray(a) for a in host
+        )
+        carry = _mw_carry_init_batch(x_dev, owner, inv_cap, demands)
     done = np.zeros(B, dtype=np.int64)
     active = ~empty
     adaptive = early_stop or target_alpha is not None
     if not adaptive:
-        carry = _mw_window_batch(
-            pe, owner, demands, inv_cap, slot_valid, carry, 0, iters,
-            jnp.asarray(active), iters, iters, backend, slot_tab, owner_tab,
-        )
+        with obs.span("mw/window_batch", t0=0, step=iters,
+                      active=int(active.sum())):
+            carry = _mw_window_batch(
+                pe, owner, demands, inv_cap, slot_valid, carry, 0, iters,
+                jnp.asarray(active), iters, iters, backend, slot_tab,
+                owner_tab,
+            )
         done[active] = iters
     else:
         best_prev = np.zeros(B)
@@ -1278,12 +1285,14 @@ def mw_concurrent_flow_batch(
                     best_prev[b] = max(best[b], best_prev[b])
         if active.any():
             obs.counter("mw/stop/budget").inc(int(active.sum()))
-    alpha, rates, max_load = _mw_final_batch(
-        pe, owner, demands, inv_cap, carry, backend, slot_tab
-    )
-    alpha = np.asarray(alpha)
-    rates = np.asarray(rates)
-    max_load = np.asarray(max_load)
+    # the host waits here for the device to finish the solve
+    with obs.span("mw/readback", instances=B):
+        alpha, rates, max_load = _mw_final_batch(
+            pe, owner, demands, inv_cap, carry, backend, slot_tab
+        )
+        alpha = np.asarray(alpha)
+        rates = np.asarray(rates)
+        max_load = np.asarray(max_load)
     out = []
     for b in range(B):
         if empty[b]:

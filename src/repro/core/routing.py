@@ -797,79 +797,92 @@ def _k_shortest_unique(
     every shard is block-local — and both caps keep their
     ``REPRO_ROUTE_TILE_BYTES`` derivation with ``n`` the widest group's
     node count (the actual tile width), not the composed total.
+
+    The call is one ``build/enumerate`` span whose ``pairs`` counts the
+    reachable pairs entering the first round; each shard of each round is
+    a ``build/shard`` child, so the children's ``pairs`` over the parent's
+    is the enumerations attempted per pair routed.
     """
-    Q = len(src)
-    results: list[list[list[int]]] = [[] for _ in range(Q)]
-    if isinstance(dist, _BlockDist):
-        base = dist.pair_hops(src, dst)
-        n = dist.n_tile  # tiles (and their row budget) are group-wide
-        ctx_of = dist.shard_ctx
-        blocks = dist.bases
-    else:
-        base = hops_to_f32(dist[src, dst])
-        n = dist.shape[0]
-        blocks = None
-
-        def ctx_of(rows: np.ndarray, s: np.ndarray, d: np.ndarray) -> tuple:
-            return nbr, _dist_tile(dist, rows), s, d
-
-    active = np.flatnonzero(np.isfinite(base))
-    if len(active) == 0:
-        return results
-    rows_cap = max(1, _FRONTIER_TILE_BYTES // (4 * (n + 1)))
-    # frontier temporaries measure ~65 KiB per expanding pair on the paper's
-    # degree-36 graphs (diameter 4); budget each shard against that rate so
-    # the knob really caps the frontier working set, not just the tile
-    pairs_cap = max(256, _FRONTIER_TILE_BYTES // (64 << 10))
-
-    if slack_init is not None:
-        slack = np.minimum(slack_init, max_slack)
-    else:
-        slack = np.zeros(Q, dtype=np.int64)
-    if counts is not None and max_slack >= 1 and len(counts):
-        d = base[active].astype(np.int64)
-        pos = d >= 1  # src == dst pairs keep slack 0
-        ai, di = active[pos], d[pos]
-        w_d = counts[di - 1, src[ai], dst[ai]]
-        w_d1 = counts[np.minimum(di, len(counts) - 1), src[ai], dst[ai]]
-        w_d1 = np.where(di < len(counts), w_d1, 0.0)
-        slack[ai] = np.where(w_d >= k, 0, np.where(w_d + w_d1 >= k, 1, 2))
-        slack = np.minimum(slack, max_slack)
-
-    while len(active):
-        still = []
-        # bucket by slack: <= 1 runs without the repeated-vertex prune (the
-        # admissibility prune is already exact there), >= 2 runs with it.
-        # Small batches (the update_path_system re-enumeration subsets) run
-        # as one bucket with the prune on — always exact, and one round's
-        # fixed per-level numpy overhead instead of two's.
-        if len(active) <= 64:
-            buckets = [(False, active)]
+    with obs.span("build/enumerate") as sp:
+        Q = len(src)
+        results: list[list[list[int]]] = [[] for _ in range(Q)]
+        if isinstance(dist, _BlockDist):
+            base = dist.pair_hops(src, dst)
+            n = dist.n_tile  # tiles (and their row budget) are group-wide
+            ctx_of = dist.shard_ctx
+            blocks = dist.bases
         else:
-            lo = slack[active] <= 1
-            buckets = [(True, active[lo]), (False, active[~lo])]
-        for lo_slack, sel in buckets:
-            for sh in _shard_by_dst(sel, dst, rows_cap, pairs_cap, blocks):
-                obs.counter("build/shards").inc()
-                with obs.span("build/shard", pairs=len(sh),
-                              lo_slack=bool(lo_slack)):
-                    rows = np.unique(dst[sh])  # sorted — searchsorted below
-                    nbr_sh, tile, src_sh, dst_sh = ctx_of(
-                        rows, src[sh], dst[sh]
-                    )
-                    dst_row = np.searchsorted(rows, dst[sh])
-                    found = _batched_round(
-                        nbr_sh, tile, src_sh, dst_sh, dst_row,
-                        base[sh] + slack[sh], k, max_enum,
-                        check_simple=not lo_slack,
-                    )
-                    for j, q in enumerate(sh):
-                        results[q] = found[j]
-                        if len(found[j]) < k and slack[q] < max_slack:
-                            still.append(q)
-        active = np.asarray(sorted(still), dtype=np.int64)
-        slack[active] += 1
-    return results
+            base = hops_to_f32(dist[src, dst])
+            n = dist.shape[0]
+            blocks = None
+
+            def ctx_of(rows: np.ndarray, s: np.ndarray,
+                       d: np.ndarray) -> tuple:
+                return nbr, _dist_tile(dist, rows), s, d
+
+        active = np.flatnonzero(np.isfinite(base))
+        sp.set(pairs=len(active))
+        if len(active) == 0:
+            return results
+        rows_cap = max(1, _FRONTIER_TILE_BYTES // (4 * (n + 1)))
+        # frontier temporaries measure ~65 KiB per expanding pair on the
+        # paper's degree-36 graphs (diameter 4); budget each shard against
+        # that rate so the knob really caps the frontier working set, not
+        # just the tile
+        pairs_cap = max(256, _FRONTIER_TILE_BYTES // (64 << 10))
+
+        if slack_init is not None:
+            slack = np.minimum(slack_init, max_slack)
+        else:
+            slack = np.zeros(Q, dtype=np.int64)
+        if counts is not None and max_slack >= 1 and len(counts):
+            d = base[active].astype(np.int64)
+            pos = d >= 1  # src == dst pairs keep slack 0
+            ai, di = active[pos], d[pos]
+            w_d = counts[di - 1, src[ai], dst[ai]]
+            w_d1 = counts[np.minimum(di, len(counts) - 1), src[ai],
+                          dst[ai]]
+            w_d1 = np.where(di < len(counts), w_d1, 0.0)
+            slack[ai] = np.where(w_d >= k, 0,
+                                 np.where(w_d + w_d1 >= k, 1, 2))
+            slack = np.minimum(slack, max_slack)
+
+        while len(active):
+            still = []
+            # bucket by slack: <= 1 runs without the repeated-vertex prune
+            # (the admissibility prune is already exact there), >= 2 runs
+            # with it.  Small batches (the update_path_system re-enumeration
+            # subsets) run as one bucket with the prune on — always exact,
+            # and one round's fixed per-level numpy overhead instead of
+            # two's.
+            if len(active) <= 64:
+                buckets = [(False, active)]
+            else:
+                lo = slack[active] <= 1
+                buckets = [(True, active[lo]), (False, active[~lo])]
+            for lo_slack, sel in buckets:
+                for sh in _shard_by_dst(sel, dst, rows_cap, pairs_cap,
+                                        blocks):
+                    obs.counter("build/shards").inc()
+                    with obs.span("build/shard", pairs=len(sh),
+                                  lo_slack=bool(lo_slack)):
+                        rows = np.unique(dst[sh])  # sorted: searchsorted
+                        nbr_sh, tile, src_sh, dst_sh = ctx_of(
+                            rows, src[sh], dst[sh]
+                        )
+                        dst_row = np.searchsorted(rows, dst[sh])
+                        found = _batched_round(
+                            nbr_sh, tile, src_sh, dst_sh, dst_row,
+                            base[sh] + slack[sh], k, max_enum,
+                            check_simple=not lo_slack,
+                        )
+                        for j, q in enumerate(sh):
+                            results[q] = found[j]
+                            if len(found[j]) < k and slack[q] < max_slack:
+                                still.append(q)
+            active = np.asarray(sorted(still), dtype=np.int64)
+            slack[active] += 1
+        return results
 
 
 def _k_shortest_paths_dfs(
@@ -1249,6 +1262,12 @@ def build_path_system_batch(
           v
         PathSystemBatch.from_systems      (common envelope, gather tables)
 
+    Traced (``repro.obs``), the call is one ``build/batch`` span whose
+    children are its stages in order: ``build/prepare`` (entries, grouping,
+    pair keys, composition, neighbor tables), ``build/apsp`` (the APSP
+    cache misses), ``build/slack``, ``build/enumerate``, ``build/slots``
+    (distribution and slot conversion) and ``build/assemble``.
+
     Returns a ``core.flow.PathSystemBatch`` whose ``systems[i]`` is
     **byte-identical** to ``build_path_system(tops[i], comms[i], ...)``:
     per-pair enumeration never leaves its block (the composed neighbor
@@ -1277,9 +1296,80 @@ def build_path_system_batch(
         raise ValueError("build_path_system_batch needs at least one instance")
 
     B = len(tops)
-    entries = [_topo_entry(t, cache=cache) for t in tops]
+    with obs.span("build/batch", instances=B):
+        with obs.span("build/prepare", instances=B):
+            entries = [_topo_entry(t, cache=cache) for t in tops]
+            inst_group, group_rep, group_keys, inst_keys = _group_instances(
+                tops, comms
+            )
+            G = len(group_rep)
+            # ---- block-diagonal composition ------------------------------ #
+            sizes = np.array([tops[group_rep[g]].n_switches for g in range(G)],
+                             dtype=np.int64)
+            bases = np.concatenate([[0], np.cumsum(sizes)[:-1]])
+            group_nbr = [_cached_nbr(tops[r], entries[r]) for r in group_rep]
+            offs = np.concatenate(
+                [[0], np.cumsum([len(gk) for gk in group_keys])]
+            ).astype(np.int64)
+            src_all = np.empty(int(offs[-1]), dtype=np.int64)
+            dst_all = np.empty(int(offs[-1]), dtype=np.int64)
+            for g in range(G):
+                sl = slice(int(offs[g]), int(offs[g + 1]))
+                src_all[sl] = group_keys[g] // sizes[g] + bases[g]
+                dst_all[sl] = group_keys[g] % sizes[g] + bases[g]
 
-    # ---- group instances by edge-set fingerprint ------------------------- #
+        # the APSP misses: adjacency, the min-plus kernel and its tiles
+        missed = [r for r in group_rep if "dist" not in entries[r]]
+        with obs.span("build/apsp",
+                      switches=sum(tops[r].n_switches for r in missed)):
+            group_dist = [_cached_dist(tops[r], entries[r]) for r in group_rep]
+
+        with obs.span("build/slack", pairs=len(src_all)):
+            slack_all = np.empty(len(src_all), dtype=np.int64)
+            for g in range(G):
+                rep = group_rep[g]
+                sl = slice(int(offs[g]), int(offs[g + 1]))
+                slack_all[sl] = _group_slack_init(
+                    tops[rep], entries[rep], group_dist[g],
+                    src_all[sl] - bases[g], dst_all[sl] - bases[g], k,
+                    max_slack,
+                )
+
+        # ---- ONE combined enumeration over every group's pairs ----------- #
+        uniq = _k_shortest_unique(
+            None, _BlockDist(group_dist, group_nbr, bases), src_all, dst_all,
+            k, max_slack, max_enum, slack_init=slack_all,
+        )
+
+        # ---- distribute per instance, stream slot assembly --------------- #
+        with obs.span("build/slots") as sp:
+            systems = []
+            for i in range(B):
+                g = int(inst_group[i])
+                inv = np.searchsorted(group_keys[g], inst_keys[i]) + int(
+                    offs[g])
+                systems.append(_instance_system(
+                    tops[i], entries[i], comms[i], [uniq[q] for q in inv],
+                    k, max_slack, keep_node_paths,
+                ))
+            rows = sum(ps.n_paths for ps in systems)
+            sp.set(rows=rows)
+        with obs.span("build/assemble", rows=rows):
+            batch = PathSystemBatch.from_systems(systems, bucket=bucket)
+        if checks_enabled():
+            check_built_batch(batch, tops, name="build_path_system_batch")
+    return batch
+
+
+def _group_instances(tops: list, comms: list) -> tuple:
+    """Group instances by edge-set fingerprint (identical topologies share
+    a block) and give each its canonical pair keys ``min * n + max``.
+
+    Returns ``(inst_group, group_rep, group_keys, inst_keys)``: each
+    instance's group, each group's representative instance, each group's
+    sorted unique pair keys, and each instance's pair keys.
+    """
+    B = len(tops)
     gid_of: dict[tuple, int] = {}
     group_rep: list[int] = []  # representative instance index per group
     inst_group = np.empty(B, dtype=np.int64)
@@ -1296,7 +1386,6 @@ def build_path_system_batch(
     for i in range(B):
         members[int(inst_group[i])].append(i)
 
-    # ---- per-instance canonical pair keys, per-group unique pair sets ---- #
     inst_keys: list[np.ndarray] = []
     for i in range(B):
         n_g = tops[i].n_switches
@@ -1307,86 +1396,42 @@ def build_path_system_batch(
         np.unique(np.concatenate([inst_keys[i] for i in members[g]]))
         for g in range(G)
     ]
+    return inst_group, group_rep, group_keys, inst_keys
 
-    # ---- block-diagonal composition -------------------------------------- #
-    sizes = np.array([tops[group_rep[g]].n_switches for g in range(G)],
-                     dtype=np.int64)
-    bases = np.concatenate([[0], np.cumsum(sizes)[:-1]])
-    group_dist = []
-    group_nbr = []
-    for g in range(G):
-        rep = group_rep[g]
-        group_dist.append(_cached_dist(tops[rep], entries[rep]))
-        group_nbr.append(_cached_nbr(tops[rep], entries[rep]))
 
-    offs = np.concatenate(
-        [[0], np.cumsum([len(gk) for gk in group_keys])]
-    ).astype(np.int64)
-    src_all = np.empty(int(offs[-1]), dtype=np.int64)
-    dst_all = np.empty(int(offs[-1]), dtype=np.int64)
-    slack_all = np.empty(int(offs[-1]), dtype=np.int64)
-    for g in range(G):
-        gk = group_keys[g]
-        n_g = int(sizes[g])
-        b = int(bases[g])
-        rep = group_rep[g]
-        s_u, d_u = gk // n_g, gk % n_g
-        sl = slice(int(offs[g]), int(offs[g + 1]))
-        src_all[sl] = s_u + b
-        dst_all[sl] = d_u + b
-        slack_all[sl] = _group_slack_init(
-            tops[rep], entries[rep], group_dist[g], s_u, d_u, k, max_slack
-        )
-
-    # ---- ONE combined enumeration over every group's pairs --------------- #
-    uniq = _k_shortest_unique(
-        None, _BlockDist(group_dist, group_nbr, bases), src_all, dst_all,
-        k, max_slack, max_enum, slack_init=slack_all,
+def _instance_system(top, entry, comm, found: list, k: int, max_slack: int,
+                     keep_node_paths: bool) -> PathSystem:
+    """One instance's ``PathSystem`` from its commodities' enumerated
+    unique-pair paths (``found[j]`` for commodity j, in local ids, low id
+    first): copy, reverse where src > dst, and stream slot assembly."""
+    s_i = np.asarray(comm.src, dtype=np.int64)
+    d_i = np.asarray(comm.dst, dtype=np.int64)
+    # enumeration already collected LOCAL ids (block-compact shards), so
+    # distribution is copy + src>dst reversal, as in k_shortest_paths
+    # — no per-element offset arithmetic here
+    all_paths: list[list[list[int]]] = [
+        [p[::-1] for p in f] if r
+        else [list(p) for p in f]  # copy so duplicate pairs never alias
+        for f, r in zip(found, (s_i > d_i).tolist())
+    ]
+    unrouted = np.array([len(p) == 0 for p in all_paths], dtype=bool)
+    E = top.n_edges
+    pe, path_len, owner, kept = _paths_to_slots(top, entry, all_paths)
+    return PathSystem(
+        n_edges=E,
+        path_edges=pe,
+        path_len=path_len,
+        path_owner=owner,
+        demands=comm.demand[~unrouted].astype(np.float32),
+        capacities=np.ones(2 * E, dtype=np.float32),
+        n_commodities=int(kept),
+        node_paths=all_paths if keep_node_paths else None,
+        unrouted=unrouted,
+        src=s_i.copy(),
+        dst=d_i.copy(),
+        k=k,
+        max_slack=max_slack,
     )
-
-    # ---- distribute per instance, stream slot assembly ------------------- #
-    systems = []
-    for i in range(B):
-        g = int(inst_group[i])
-        inv = np.searchsorted(group_keys[g], inst_keys[i]) + int(offs[g])
-        s_i = np.asarray(comms[i].src, dtype=np.int64)
-        d_i = np.asarray(comms[i].dst, dtype=np.int64)
-        # enumeration already collected LOCAL ids (block-compact shards),
-        # so distribution is copy + src>dst reversal, like the sequential
-        # driver — no per-element offset arithmetic here
-        rev = (s_i > d_i).tolist()
-        all_paths: list[list[list[int]]] = []
-        for j, q in enumerate(inv.tolist()):
-            found = uniq[q]
-            if rev[j]:
-                paths = [p[::-1] for p in found]
-            else:
-                # copy so duplicate pairs never alias
-                paths = [list(p) for p in found]
-            all_paths.append(paths)
-        unrouted = np.array([len(p) == 0 for p in all_paths], dtype=bool)
-        E = tops[i].n_edges
-        pe, path_len, owner, kept = _paths_to_slots(tops[i], entries[i],
-                                                    all_paths)
-        systems.append(PathSystem(
-            n_edges=E,
-            path_edges=pe,
-            path_len=path_len,
-            path_owner=owner,
-            demands=comms[i].demand[~unrouted].astype(np.float32),
-            capacities=np.ones(2 * E, dtype=np.float32),
-            n_commodities=int(kept),
-            node_paths=all_paths if keep_node_paths else None,
-            unrouted=unrouted,
-            src=s_i.copy(),
-            dst=d_i.copy(),
-            k=k,
-            max_slack=max_slack,
-        ))
-    batch = PathSystemBatch.from_systems(systems, bucket=bucket)
-    if checks_enabled():
-        check_built_batch(batch, tops, name="build_path_system_batch")
-    return batch
 
 
 def ecmp_path_system(

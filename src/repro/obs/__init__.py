@@ -5,8 +5,7 @@ Three pieces, one import:
 * :mod:`repro.obs.trace` — hierarchical host-boundary spans with JSONL and
   Chrome-trace (Perfetto) export, gated by the registry-validated
   ``REPRO_TRACE`` knob (no-op when off).
-* :mod:`repro.obs.metrics` — counters / gauges / log2-histograms for
-  solver telemetry, plus the process event bus that the XLA compile
+* :mod:`repro.obs.metrics` — counters / gauges for solver telemetry, plus the process event bus that the XLA compile
   listener (``repro.analysis.retrace``) publishes into.
 * :mod:`repro.obs.bench` — the single copy of the benchmark timing /
   memory helpers every ``benchmarks/figN`` driver shares.
@@ -33,11 +32,9 @@ from .bench import (
 from .metrics import (
     Counter,
     Gauge,
-    Hist2,
     counter,
     emit,
     gauge,
-    hist,
     reset_metrics,
     snapshot,
     subscribe,
@@ -63,7 +60,6 @@ from .trace import (
 __all__ = [
     "Counter",
     "Gauge",
-    "Hist2",
     "Span",
     "TRACE_OUT",
     "Timer",

@@ -1,8 +1,7 @@
 """CLI: ``python -m repro.obs report [paths...]`` — summarize trace logs.
 
 ``report`` reads trace JSONL files (default ``{REPRO_TRACE_OUT}/*.jsonl``)
-and prints a per-span-name table: count, total/mean/max wall seconds, and
-peak RSS watermark.  Pure stdlib, like the lint CLI — it runs anywhere.
+and prints a per-span-name table: count, total/mean/max wall seconds.  Pure stdlib, like the lint CLI — it runs anywhere.
 
 ``python -m repro.obs smoke`` is the CI obs-smoke lane: trace a toy MW
 solve end to end, assert the traced result is bit-identical to an
@@ -43,28 +42,27 @@ def report(argv: list[str]) -> int:
         print(f"no trace JSONL found for {' '.join(requested)} "
               "(run with REPRO_TRACE=1 first)", file=sys.stderr)
         return 1
-    # name -> [count, total_s, max_s, max_rss_mb]
+    # name -> [count, total_s, max_s]
     agg: dict[str, list[float]] = {}
     n_events = 0
     for rec in _iter_records(paths):
         if rec.get("kind") != "span":
             n_events += 1
             continue
-        row = agg.setdefault(rec["name"], [0, 0.0, 0.0, 0.0])
+        row = agg.setdefault(rec["name"], [0, 0.0, 0.0])
         row[0] += 1
         row[1] += rec["wall_s"]
         row[2] = max(row[2], rec["wall_s"])
-        row[3] = max(row[3], rec.get("rss_mb", 0.0))
     if not agg and not n_events:
         print("no records found", file=sys.stderr)
         return 1
     width = max([len(n) for n in agg] + [4])
     print(f"{'span':<{width}}  {'count':>6}  {'total_s':>9}  "
-          f"{'mean_s':>9}  {'max_s':>9}  {'rss_mb':>8}")
+          f"{'mean_s':>9}  {'max_s':>9}")
     for name in sorted(agg, key=lambda n: -agg[n][1]):
-        count, total, mx, rss = agg[name]
+        count, total, mx = agg[name]
         print(f"{name:<{width}}  {int(count):>6}  {total:>9.4f}  "
-              f"{total / count:>9.4f}  {mx:>9.4f}  {rss:>8.1f}")
+              f"{total / count:>9.4f}  {mx:>9.4f}")
     if n_events:
         print(f"(+ {n_events} instant/counter events)")
     return 0

@@ -1,4 +1,4 @@
-"""Counters / gauges / log2-histograms + the obs event bus.
+"""Counters / gauges + the obs event bus.
 
 Solver telemetry that a span timeline can't express: HOW MANY commodities
 a delta update spliced vs re-enumerated, how far the MW alpha got per
@@ -11,10 +11,6 @@ Metric types
 ------------
 * :class:`Counter` — monotone accumulator (int or float; ``inc``).
 * :class:`Gauge` — last-write-wins value (``set``).
-* :class:`Hist2` — log2-binned histogram (bin ``b`` holds values in
-  ``[2^b, 2^(b+1))``; zeros/negatives land in the underflow bin), the same
-  binning discipline the sim's FCT histogram uses, with exact sum/count so
-  means stay exact.
 
 Unlike the tracer there is no off switch: a metric update is a dict lookup
 and an add under the GIL, and every call site sits at a host boundary that
@@ -36,7 +32,6 @@ they stalled the sweep.  ``track_compiles()`` is a bus subscriber.
 
 from __future__ import annotations
 
-import math
 import threading
 from typing import Any, Callable
 
@@ -45,11 +40,9 @@ from . import trace as _trace
 __all__ = [
     "Counter",
     "Gauge",
-    "Hist2",
     "counter",
     "emit",
     "gauge",
-    "hist",
     "reset_metrics",
     "snapshot",
     "subscribe",
@@ -93,47 +86,6 @@ class Gauge:
         return self.value
 
 
-#: Underflow bin index for values <= 0 (no finite log2).
-_UNDERFLOW = -1
-
-
-class Hist2:
-    """Log2-binned histogram with exact sum/count.
-
-    ``observe(v)`` increments bin ``floor(log2(v))`` (values in
-    ``[2^b, 2^(b+1))`` share bin ``b``); ``v <= 0`` lands in the underflow
-    bin.  Bins are a sparse dict, so microsecond stalls and 200-second
-    builds coexist without preallocating a range.
-    """
-
-    __slots__ = ("name", "bins", "total", "count")
-
-    def __init__(self, name: str) -> None:
-        self.name = name
-        self.bins: dict[int, int] = {}
-        self.total: float = 0.0
-        self.count: int = 0
-
-    def observe(self, v: float) -> None:
-        v = float(v)
-        b = math.floor(math.log2(v)) if v > 0 else _UNDERFLOW
-        with _LOCK:
-            self.bins[b] = self.bins.get(b, 0) + 1
-            self.total += v
-            self.count += 1
-
-    def mean(self) -> float:
-        return self.total / self.count if self.count else 0.0
-
-    def to_value(self) -> dict:
-        return {
-            "bins": {str(k): v for k, v in sorted(self.bins.items())},
-            "sum": self.total,
-            "count": self.count,
-            "mean": self.mean(),
-        }
-
-
 _REG: dict[str, Any] = {}
 
 
@@ -161,13 +113,8 @@ def gauge(name: str) -> Gauge:
     return _get(name, Gauge)
 
 
-def hist(name: str) -> Hist2:
-    return _get(name, Hist2)
-
-
 def snapshot() -> dict:
-    """``{name: value}`` for every registered metric (hists expand to
-    their bin dict + exact sum/count/mean)."""
+    """``{name: value}`` for every registered metric."""
     with _LOCK:
         items = list(_REG.items())
     return {name: m.to_value() for name, m in sorted(items)}

@@ -23,11 +23,17 @@ Design constraints (INVARIANTS.md OB-1):
   the instrumented hot paths pay an ``if`` and a dict build per *host
   boundary* (windows are 50 iterations; segments are hundreds of steps).
 * **Cheap measurements only while enabled** — wall clock
-  (``perf_counter``), thread id, ``ru_maxrss`` watermark (one syscall),
-  and a tracemalloc delta ONLY when the caller already started
-  tracemalloc (hooking every allocation inflates numpy-heavy wall clock
-  1.3-2x; the tracer must not do that behind the bench's back — the
-  ``<5%% overhead`` acceptance row would be meaningless).
+  (``perf_counter``), thread id, and a tracemalloc delta ONLY when the
+  caller already started tracemalloc (hooking every allocation inflates
+  numpy-heavy wall clock 1.3-2x; the tracer must not do that behind the
+  bench's back — the ``<5%% overhead`` acceptance row would be
+  meaningless).
+* **On the profiler's clock too** — while enabled, a span also opens a
+  ``jax.profiler.TraceAnnotation`` of its name around the same interval,
+  so a JAX profiler trace taken meanwhile holds every span on its own
+  host plane, beside the device's operations.  ``jax`` is taken from
+  ``sys.modules`` when something else already imported it; this module
+  never imports it.
 
 Spans nest per thread: a build running on the ``stream_builds`` prefetch
 worker records its own thread id and parents correctly under whatever
@@ -41,6 +47,7 @@ import dataclasses
 import json
 import os
 import pathlib
+import sys
 import threading
 import time
 import tracemalloc
@@ -99,7 +106,6 @@ class Span:
     depth: int
     t0: float  # perf_counter seconds (process-relative timeline)
     wall_s: float
-    rss_mb: float  # ru_maxrss watermark at span exit (process lifetime mark)
     trmalloc_delta: int | None  # bytes, only when tracemalloc was tracing
     attrs: dict
 
@@ -113,7 +119,6 @@ class Span:
             "depth": self.depth,
             "t0_s": self.t0,
             "wall_s": self.wall_s,
-            "rss_mb": self.rss_mb,
         }
         if self.trmalloc_delta is not None:
             rec["tracemalloc_delta_bytes"] = self.trmalloc_delta
@@ -122,11 +127,15 @@ class Span:
         return rec
 
 
-def _rss_mb() -> float:
-    """Process peak RSS in MiB (ru_maxrss is KiB on Linux)."""
-    import resource
-
-    return resource.getrusage(resource.RUSAGE_SELF).ru_maxrss / 1024.0
+def _annotation(name: str):
+    """An entered ``jax.profiler.TraceAnnotation`` named ``name``, or None
+    while JAX is not imported (a span then has no profiler copy)."""
+    prof = getattr(sys.modules.get("jax"), "profiler", None)
+    if prof is None:
+        return None
+    ann = prof.TraceAnnotation(name)
+    ann.__enter__()
+    return ann
 
 
 class _Tracer:
@@ -174,7 +183,7 @@ class _SpanCtx:
     """Live span context manager (only ever constructed while enabled)."""
 
     __slots__ = ("name", "attrs", "span_id", "parent_id", "depth", "t0",
-                 "_tm0")
+                 "_tm0", "_ann")
 
     def __init__(self, name: str, attrs: dict) -> None:
         self.name = name
@@ -191,11 +200,18 @@ class _SpanCtx:
             if tracemalloc.is_tracing()
             else None
         )
+        self._ann = _annotation(self.name)
         self.t0 = time.perf_counter()
         return self
 
+    def set(self, **attrs: Any) -> None:
+        """Add attributes known only once the span's work has run."""
+        self.attrs.update(attrs)
+
     def __exit__(self, *exc: Any) -> None:
         wall = time.perf_counter() - self.t0
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         stack = _TRACER._stack()
         if stack and stack[-1] == self.span_id:
             stack.pop()
@@ -211,7 +227,6 @@ class _SpanCtx:
                 depth=self.depth,
                 t0=self.t0,
                 wall_s=wall,
-                rss_mb=_rss_mb(),
                 trmalloc_delta=delta,
                 attrs=self.attrs,
             )
@@ -226,6 +241,9 @@ class _NoopCtx:
     def __enter__(self) -> "_NoopCtx":
         return self
 
+    def set(self, **attrs: Any) -> None:
+        return None
+
     def __exit__(self, *exc: Any) -> None:
         return None
 
@@ -236,8 +254,9 @@ _NOOP = _NoopCtx()
 def span(name: str, **attrs: Any):
     """Context manager timing one named host-boundary interval.
 
-        with obs.span("build/shard", pairs=128, tile=shape):
+        with obs.span("build/shard", pairs=128, tile=shape) as sp:
             ...host enumeration...
+            sp.set(found=n)  # an attribute known only at the end
 
     Disabled (``REPRO_TRACE`` unset / :func:`set_trace(False)`), returns a
     shared no-op object: the call costs one flag test and the kwargs dict.
@@ -343,7 +362,6 @@ def chrome_trace_events(records: "Iterator[dict] | list[dict] | None" = None,
         }
         if kind == "span":
             args = dict(rec.get("attrs") or {})
-            args["rss_mb"] = rec.get("rss_mb")
             if "tracemalloc_delta_bytes" in rec:
                 args["tracemalloc_delta_bytes"] = rec[
                     "tracemalloc_delta_bytes"

@@ -415,6 +415,45 @@ def _migrate_carry(
     return new_carry, record
 
 
+def _apply_events(evs, tops: list, systems: list, comms, heal_store: dict):
+    """Apply ``evs`` in order to every instance, replacing ``tops[i]`` and
+    ``systems[i]`` in place; returns each instance's composed row map (new
+    path row -> old row, -1 fresh) and slot map (old slot -> new, -1 gone).
+    """
+    B = len(tops)
+    rm_tot = [np.arange(systems[i].n_paths, dtype=np.int64) for i in range(B)]
+    sm_tot = [np.arange(systems[i].n_slots, dtype=np.int64) for i in range(B)]
+    for ev in evs:
+        for i in range(B):
+            top_new, ps_new = _apply_event(
+                ev, tops[i], systems[i], comms[i], i, heal_store
+            )
+            rm_step = ps_new.row_map
+            if rm_step is None:  # full rebuild: all rows fresh
+                rm_tot[i] = np.full(ps_new.n_paths, -1, np.int64)
+            else:
+                rm_step = np.asarray(rm_step, np.int64)
+                nt = np.full(len(rm_step), -1, np.int64)
+                ok = rm_step >= 0
+                nt[ok] = rm_tot[i][rm_step[ok]]
+                rm_tot[i] = nt
+            sm_step = _slot_map(tops[i], top_new)
+            st = np.full(len(sm_tot[i]), -1, np.int64)
+            ok = sm_tot[i] >= 0
+            st[ok] = sm_step[sm_tot[i][ok]]
+            sm_tot[i] = st
+            tops[i], systems[i] = top_new, ps_new
+    return rm_tot, sm_tot
+
+
+def _restack(span: str, systems: list, policy: str, cfg, backend: str):
+    """The batch and scan inputs of ``systems`` (stacked, uploaded), inside
+    the span named ``span``."""
+    with obs.span(span, rows=sum(ps.n_paths for ps in systems)):
+        batch = PathSystemBatch.from_systems(list(systems))
+        return batch, _scan_inputs(batch, policy, cfg, backend)
+
+
 def simulate_events(
     tops: Sequence,
     comms: Sequence,
@@ -524,42 +563,20 @@ def simulate_events(
             with obs.span("sim/reroute", step=int(t0), events=len(evs)):
                 old_systems = list(systems)
                 old_batch = batch
-                rm_tot = [
-                    np.arange(systems[i].n_paths, dtype=np.int64)
-                    for i in range(B)
-                ]
-                sm_tot = [
-                    np.arange(systems[i].n_slots, dtype=np.int64)
-                    for i in range(B)
-                ]
-                for ev in evs:
-                    for i in range(B):
-                        top_new, ps_new = _apply_event(
-                            ev, tops[i], systems[i], comms[i], i, heal_store
-                        )
-                        rm_step = ps_new.row_map
-                        if rm_step is None:  # full rebuild: all rows fresh
-                            rm_tot[i] = np.full(ps_new.n_paths, -1, np.int64)
-                        else:
-                            rm_step = np.asarray(rm_step, np.int64)
-                            nt = np.full(len(rm_step), -1, np.int64)
-                            ok = rm_step >= 0
-                            nt[ok] = rm_tot[i][rm_step[ok]]
-                            rm_tot[i] = nt
-                        sm_step = _slot_map(tops[i], top_new)
-                        st = np.full(len(sm_tot[i]), -1, np.int64)
-                        ok = sm_tot[i] >= 0
-                        st[ok] = sm_step[sm_tot[i][ok]]
-                        sm_tot[i] = st
-                        tops[i], systems[i] = top_new, ps_new
-                batch = PathSystemBatch.from_systems(list(systems))
-                inp = _scan_inputs(batch, policy, cfg, backend)
+                with obs.span("sim/reroute/update", events=len(evs),
+                              instances=B):
+                    rm_tot, sm_tot = _apply_events(evs, tops, systems, comms,
+                                                   heal_store)
+                batch, inp = _restack("sim/reroute/restack", systems, policy,
+                                      cfg, backend)
                 if carry is not None:
-                    carry, rec = _migrate_carry(
-                        carry, old_batch, old_systems, systems, batch, inp,
-                        comms, rm_tot, sm_tot, lag, cfg, policy, g_del,
-                        g_off, gdum,
-                    )
+                    with obs.span("sim/reroute/migrate", instances=B,
+                                  flows=B * cfg.max_flows):
+                        carry, rec = _migrate_carry(
+                            carry, old_batch, old_systems, systems, batch,
+                            inp, comms, rm_tot, sm_tot, lag, cfg, policy,
+                            g_del, g_off, gdum,
+                        )
                     rec["step"] = t0
                     rec["kinds"] = [e.kind for e in evs]
                     rec["tags"] = [e.tag for e in evs]
@@ -575,8 +592,8 @@ def simulate_events(
                         int(np.sum(rec["killed"]))
                     )
         if batch is None:
-            batch = PathSystemBatch.from_systems(list(systems))
-            inp = _scan_inputs(batch, policy, cfg, backend)
+            batch, inp = _restack("sim/restack", systems, policy, cfg,
+                                  backend)
         if g_del is None:
             gdum = max(kg, inp["n_comm"])
             g_del = np.zeros((B, gdum + 1), np.float32)

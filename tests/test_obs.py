@@ -2,11 +2,13 @@
 
 The load-bearing contract: spans live only at host boundaries, so a traced
 run executes the IDENTICAL compiled program as an untraced one — asserted
-bit-for-bit over an MW solve (single + batch), a delta-update build, and a
-``simulate_events`` fail/heal chain.  Plus the tracer/metrics unit surface:
-span nesting, the zero-overhead no-op path, Chrome-trace (Perfetto) export
-schema, log2 histogram binning, the event bus, the report CLI, and the
-``REPRO_TRACE`` registry knob's import-time validation.
+bit-for-bit over an MW solve (single + batch), a batch and a delta-update
+build, and a ``simulate_events`` fail/heal chain.  Each host phase of a
+route build, an MW solve and a sim re-route is its own span, and spans
+land natively on the JAX profiler's host plane.  Plus the tracer/metrics
+unit surface: span nesting, the zero-overhead no-op path, Chrome-trace
+(Perfetto) export schema, counters and gauges, the event bus, the report
+CLI, and the ``REPRO_TRACE`` registry knob's import-time validation.
 """
 
 from __future__ import annotations
@@ -88,10 +90,27 @@ def test_span_nesting_and_fields(traced):
     assert outer.parent_id == -1
     assert inner.depth == outer.depth + 1
     assert outer.wall_s >= inner.wall_s >= 0.0
-    assert outer.rss_mb > 0.0
     assert outer.attrs == {"kind": "test"}
     rec = outer.to_record()
     assert rec["kind"] == "span" and rec["name"] == "outer"
+    # no per-span process-lifetime RSS mark: it named nothing of the span
+    assert "rss_mb" not in rec and not hasattr(outer, "rss_mb")
+
+
+def test_span_attrs_set_inside(traced):
+    """An attribute known only once the work ran is added by ``set``; the
+    disabled span takes the same call and keeps nothing."""
+    with obs.span("counted", a=1) as sp:
+        sp.set(found=7)
+    (got,) = obs.get_spans()
+    assert got.attrs == {"a": 1, "found": 7}
+    prev = obs.set_trace(False)
+    try:
+        with obs.span("off") as sp:
+            sp.set(found=1)
+    finally:
+        obs.set_trace(prev)
+    assert [s.name for s in obs.get_spans()] == ["counted"]
 
 
 def test_jsonl_and_chrome_export(traced, tmp_path):
@@ -140,20 +159,14 @@ def test_report_cli(traced, tmp_path, capsys):
 
 
 def test_counter_gauge_hist():
+    """Counters and gauges (the log2 histogram is gone: nothing read it)."""
     obs.reset_metrics()
     obs.counter("t/c").inc()
     obs.counter("t/c").inc(2.5)
     obs.gauge("t/g").set(0.75)
-    h = obs.hist("t/h")
-    for v in (0.0, 1.0, 1.5, 2.0, 7.9, 8.0):
-        h.observe(v)
     snap = obs.snapshot()
-    assert snap["t/c"] == pytest.approx(3.5)
-    assert snap["t/g"] == pytest.approx(0.75)
-    # log2 bins: 0.0 underflows; 1.0/1.5 -> bin 0; 2.0 -> 1; 7.9 -> 2; 8 -> 3
-    assert snap["t/h"]["bins"] == {"-1": 1, "0": 2, "1": 1, "2": 1, "3": 1}
-    assert snap["t/h"]["count"] == 6
-    assert snap["t/h"]["mean"] == pytest.approx((1 + 1.5 + 2 + 7.9 + 8) / 6)
+    assert snap == {"t/c": pytest.approx(3.5), "t/g": pytest.approx(0.75)}
+    assert not hasattr(obs, "hist") and not hasattr(obs, "Hist2")
     with pytest.raises(TypeError):
         obs.gauge("t/c")  # registered as a Counter
     obs.reset_metrics()
@@ -351,8 +364,145 @@ def test_buildpipe_metrics_recorded():
     snap = obs.snapshot()
     assert snap["pipeline/builds"] == 4
     assert snap["pipeline/stall_s"] >= 0.0
-    assert snap["pipeline/stall_s_hist"]["count"] == 4
+    assert snap["pipeline/overlap_s"] >= 0.0
+    assert set(snap) == {"pipeline/builds", "pipeline/stall_s",
+                         "pipeline/overlap_s"}
     obs.reset_metrics()
+
+
+# --------------------------------------------------------------------------- #
+# host phases as spans
+# --------------------------------------------------------------------------- #
+
+
+def _children(spans, parent):
+    return sorted((s for s in spans if s.parent_id == parent.span_id),
+                  key=lambda s: s.t0)
+
+
+def _two_instances():
+    tops = [jellyfish(24, 8, 5, seed=4), jellyfish(20, 8, 5, seed=5)]
+    comms = [random_permutation_traffic(t, seed=s) for s, t in enumerate(tops)]
+    return tops, comms
+
+
+def test_route_build_phases_are_spans(traced):
+    from repro.core import build_path_system_batch
+
+    tops, comms = _two_instances()
+    batch = build_path_system_batch(tops, comms, k=4, max_slack=2,
+                                    cache=False)
+    spans = obs.get_spans()
+    (top,) = [s for s in spans if s.name == "build/batch"]
+    kids = _children(spans, top)
+    assert [s.name for s in kids] == [
+        "build/prepare", "build/apsp", "build/slack", "build/enumerate",
+        "build/slots", "build/assemble"]
+    by = {s.name: s for s in kids}
+    assert by["build/apsp"].attrs["switches"] == 44  # both fabrics missed
+    assert by["build/slots"].attrs["rows"] == sum(
+        ps.n_paths for ps in batch.systems)
+    assert by["build/assemble"].attrs == by["build/slots"].attrs
+    assert sum(s.wall_s for s in kids) <= top.wall_s
+    enum = by["build/enumerate"]
+    shards = [s for s in spans if s.name == "build/shard"]
+    assert shards and all(s.parent_id == enum.span_id for s in shards)
+    # every reachable pair entering is attempted at least once
+    assert 0 < enum.attrs["pairs"] <= sum(s.attrs["pairs"] for s in shards)
+    assert enum.attrs["pairs"] <= by["build/slack"].attrs["pairs"]
+
+
+def test_route_build_traced_byte_identical():
+    from repro.core import build_path_system_batch
+
+    tops, comms = _two_instances()
+    base = build_path_system_batch(tops, comms, k=4, cache=False)
+    prev = obs.set_trace(True)
+    try:
+        traced = build_path_system_batch(tops, comms, k=4, cache=False)
+    finally:
+        obs.set_trace(prev)
+        obs.reset_trace()
+    for name in ("path_edges", "path_owner", "demands", "inv_cap",
+                 "slot_valid", "slot_gather", "owner_gather"):
+        a, b = getattr(base, name), getattr(traced, name)
+        assert a.dtype == b.dtype and a.tobytes() == b.tobytes(), name
+    for a, b in zip(base.systems, traced.systems):
+        for name in ("path_edges", "path_len", "path_owner", "demands",
+                     "unrouted"):
+            assert getattr(a, name).tobytes() == getattr(b, name).tobytes()
+
+
+def test_mw_batch_phases_are_spans(traced):
+    tops = [jellyfish(20, 8, 5, seed=s) for s in range(3)]
+    systems = [
+        build_path_system(t, random_permutation_traffic(t, seed=s), k=4)
+        for s, t in enumerate(tops)
+    ]
+    obs.reset_trace()
+    mw_concurrent_flow_batch(systems, iters=30)  # non-adaptive
+    names = [s.name for s in sorted(obs.get_spans(), key=lambda s: s.t0)]
+    assert names == ["mw/assemble", "mw/upload", "mw/window_batch",
+                     "mw/readback"]
+    by = {s.name: s for s in obs.get_spans()}
+    assert by["mw/assemble"].attrs["rows"] == sum(
+        ps.n_paths for ps in systems)
+    assert by["mw/upload"].attrs["bytes"] > sum(
+        ps.path_edges.nbytes for ps in systems)
+    assert by["mw/window_batch"].attrs == {"t0": 0, "step": 30, "active": 3}
+
+
+def test_sim_reroute_phases_are_spans(traced):
+    tops = [jellyfish(20, 8, 5, seed=s + 1) for s in range(2)]
+    comms = [
+        permutation_commodities(
+            t, random_server_permutation(t.n_servers, np.random.default_rng(s))
+        )
+        for s, t in enumerate(tops)
+    ]
+    sched = [Event(step=10, kind="fail_links", n_links=2, seed=5, tag="f")]
+    simulate_events(tops, comms, sched, steady_poisson(20, 3.0), k=4,
+                    policy="ecmp", seed=3,
+                    config=SimConfig(max_flows=64, max_arrivals=8,
+                                     wf_iters=4))
+    spans = obs.get_spans()
+    (reroute,) = [s for s in spans if s.name == "sim/reroute"]
+    assert [s.name for s in _children(spans, reroute)] == [
+        "sim/reroute/update", "sim/reroute/restack", "sim/reroute/migrate"]
+    (first,) = [s for s in spans if s.name == "sim/restack"]
+    assert first.t0 < reroute.t0 and first.attrs["rows"] > 0
+
+
+def test_spans_land_on_the_profiler_host_plane(traced, tmp_path):
+    import jax
+    from jax.profiler import ProfileData
+
+    with jax.profiler.trace(str(tmp_path)):
+        with obs.span("obs_test/native"):
+            jax.numpy.ones(8).block_until_ready()
+    (path,) = tmp_path.rglob("*.xplane.pb")
+    names = {
+        e.name
+        for plane in ProfileData.from_file(str(path)).planes
+        if plane.name.startswith("/host:")
+        for line in plane.lines for e in line.events
+    }
+    assert "obs_test/native" in names
+
+
+def test_import_pulls_in_no_jax():
+    env = dict(os.environ, REPRO_TRACE="1")
+    env["PYTHONPATH"] = str(ROOT / "src")
+    proc = subprocess.run(
+        [sys.executable, "-c",
+         "import sys, repro.obs as o\n"
+         "with o.span('x'): pass\n"
+         "print(sorted(m for m in sys.modules if m.split('.')[0] in "
+         "('jax', 'numpy')))"],
+        env=env, capture_output=True, text=True, cwd=str(ROOT),
+    )
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert proc.stdout.strip() == "[]"
 
 
 # --------------------------------------------------------------------------- #

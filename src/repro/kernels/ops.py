@@ -138,7 +138,7 @@ def congestion_loads(incidence, rates, backend: str = "auto", **blocks):
     congestion primitive's *load* half twice per round but never consumes
     path costs.  On CPU the reference is a plain (batched) matmul — half
     the work of ``congestion_ref``.  On TPU the fused kernel reads each B
-    tile from HBM once whether it feeds one MXU pass or two, so the fused
+    tile from HBM once whether it forms one product or two, so the fused
     call costs the same HBM traffic and we simply drop the costs output.
     """
     if backend == "ref" or (backend == "auto" and not _on_tpu()):

@@ -7,7 +7,8 @@ import pytest
 import jax.numpy as jnp
 
 from repro.kernels import ref
-from repro.kernels.congestion import congestion_pallas
+from repro.kernels.congestion import (_congestion_tiles, _step_bytes,
+                                      _STEP_BYTES, congestion_pallas)
 from repro.kernels.minplus import minplus_pallas
 from repro.kernels.power import matmul_pallas
 from repro.kernels import ops
@@ -118,6 +119,59 @@ def test_congestion_tpu_interpret_multi_block(batched):
     lw, cw = ref.congestion_ref(B, r, w)
     np.testing.assert_allclose(np.asarray(lg), np.asarray(lw), rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(np.asarray(cg), np.asarray(cw), rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("P, S", [(24576, 10240), (24576, 9216),
+                                  (196608, 73728), (18012, 9216), (83, 384)],
+                         ids=str)
+def test_congestion_tiles_from_shape(P, S):
+    """The tile divides the aligned dims (so the pad is a no-op for aligned
+    operands) and its step fits the budget; the dense cell's (4, 24576,
+    10240) stack runs full width in at most about 1,500 steps, and a
+    jf2048-wide S (73,728 slots) splits E."""
+    bp, be = _congestion_tiles(P, S)
+    Pp, Sp = -(-P // 8) * 8, -(-S // 128) * 128
+    assert bp % 8 == 0 and Pp % bp == 0
+    assert be % 128 == 0 and Sp % be == 0
+    assert _step_bytes(bp, be) <= _STEP_BYTES
+    if (P, S) == (24576, 10240):
+        assert be == S and 4 * (P // bp) <= 1536
+    if S == 73728:
+        assert be < S
+
+
+def test_congestion_default_tiles_multi_block():
+    """At a shape where the tiles picked from the shape span several row
+    blocks (P unaligned), the default call matches the reference."""
+    Bt, P, E = 2, 2045, 1280
+    bp, be = _congestion_tiles(P, E)
+    assert bp < P and be == E
+    B = jnp.asarray((RNG.uniform(size=(Bt, P, E)) < 0.01).astype(np.float32))
+    r = jnp.asarray(RNG.uniform(size=(Bt, P)).astype(np.float32))
+    w = jnp.asarray(RNG.uniform(size=(Bt, E)).astype(np.float32))
+    lg, cg = congestion_pallas(B, r, w, interpret=True)
+    lw, cw = ref.congestion_ref(B, r, w)
+    np.testing.assert_allclose(np.asarray(lg), np.asarray(lw), rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(cg), np.asarray(cw), rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("batched", [False, True], ids=["rank2", "rank3"])
+@pytest.mark.parametrize("blocks", [{}, {"bp": 16, "be": 128}],
+                         ids=["shape_tiles", "forced_tiles"])
+def test_congestion_exact_integer_sums(batched, blocks):
+    """Every term b * r and b * w is an exact f32 value: with integer rates
+    and prices whose sums stay below 2**24 every sum is exact in any order,
+    so the kernel equals the reference bit for bit."""
+    P, E = 5 * 16 + 3, 3 * 128
+    shape = (3, P, E) if batched else (P, E)
+    B = jnp.asarray((RNG.uniform(size=shape) < 0.3).astype(np.float32))
+    r = jnp.asarray(RNG.integers(0, 1000, size=shape[:-1]).astype(np.float32))
+    w = jnp.asarray(
+        RNG.integers(0, 1000, size=shape[:-2] + (E,)).astype(np.float32))
+    lg, cg = congestion_pallas(B, r, w, interpret=True, **blocks)
+    lw, cw = ref.congestion_ref(B, r, w)
+    np.testing.assert_array_equal(np.asarray(lg), np.asarray(lw))
+    np.testing.assert_array_equal(np.asarray(cg), np.asarray(cw))
 
 
 def test_congestion_batched_members_match_single():

@@ -27,6 +27,9 @@ from repro.kernels.power import matmul_pallas
 # Phase C of chip_smoke.py: 4 seeds of jellyfish(512, 24, 18) under
 # permutation traffic, k=8 -> ~24.6k paths over 2E = 9,216 link slots.
 P, SLOTS, BATCH = 24576, 9216, 4
+# The benchmark cell mw_jf512x4_dense: 4 fabrics of jellyfish(512, 24, 18),
+# one permutation each -> 24,576 paths over 10,240 slots.
+CELL_SLOTS = 10240
 # apsp_minplus_blocked streams 512 x 512 tiles through the min-plus kernel.
 TILE = 512
 
@@ -61,6 +64,13 @@ def _cases():
         "congestion_rank3": (congestion_pallas,
                              [((BATCH, P, SLOTS), f32), ((BATCH, P), f32),
                               ((BATCH, SLOTS), f32)]),
+        "congestion_rank2_cell": (congestion_pallas,
+                                  [((P, CELL_SLOTS), f32), ((P,), f32),
+                                   ((CELL_SLOTS,), f32)]),
+        "congestion_rank3_cell": (congestion_pallas,
+                                  [((BATCH, P, CELL_SLOTS), f32),
+                                   ((BATCH, P), f32),
+                                   ((BATCH, CELL_SLOTS), f32)]),
         "minplus_tile": (minplus_pallas,
                          [((TILE, TILE), f32), ((TILE, TILE), f32)]),
         "matmul": (matmul_pallas, [((2048, 2048), f32), ((2048, 8), f32)]),

@@ -1,27 +1,22 @@
-"""Shared helpers for the paper-figure benchmarks."""
+"""Shared helpers for the paper-figure benchmarks.
+
+The Fig 1c capacity search they call lives in ``repro.core.capacity``.
+"""
 
 from __future__ import annotations
 
 import json
 import pathlib
 
-import numpy as np
-
 from repro.core import (
     build_path_system,
-    build_path_system_batch,
-    jellyfish_heterogeneous,
     lp_concurrent_flow,
-    max_feasible,
     mw_concurrent_flow,
     mw_concurrent_flow_batch,
-    pipeline_enabled,
     random_permutation_traffic,
-    speculative_max_feasible,
-    stream_builds,
 )
 from repro import env
-from repro.core.flow import LP_PATH_LIMIT
+from repro.core.capacity import wants_mw
 from repro.obs.bench import Timer  # noqa: F401 — the one shared bench timer
 
 ART = pathlib.Path(env.read("REPRO_BENCH_OUT"))
@@ -36,23 +31,6 @@ def save(name: str, payload: dict) -> None:
     (ART / f"{name}.json").write_text(json.dumps(payload, indent=1))
 
 
-#: alpha_of / batch_alphas auto dispatch: exact LP at or below this many
-#: path variables, the MW solver beyond (single-core HiGHS needs minutes
-#: much past ~10k variables).  The default sits deliberately ABOVE
-#: flow.LP_PATH_LIMIT's 20000: sweep alphas are REPORTED figure numbers, so
-#: the benches hold onto the exact LP a bit longer than interactive
-#: ``throughput()`` callers would tolerate.  Setting REPRO_LP_PATH_LIMIT
-#: (validated at flow import) steers BOTH cutoffs to the same value.
-MW_MIN_PATHS = (
-    LP_PATH_LIMIT if env.is_set("REPRO_LP_PATH_LIMIT") else 30000
-)
-
-
-def _wants_mw(ps, method: str) -> bool:
-    """The single LP-vs-MW dispatch predicate every sweep driver shares."""
-    return method == "mw" or (method == "auto" and ps.n_paths > MW_MIN_PATHS)
-
-
 def alpha_of(top, seed=0, k=8, slack=3, method="auto", iters=500,
              mw_backend="auto", early_stop=False, target_alpha=None) -> float:
     """Max concurrent flow alpha for a random permutation matrix.
@@ -63,15 +41,15 @@ def alpha_of(top, seed=0, k=8, slack=3, method="auto", iters=500,
     congestion backend (see repro.kernels.ops.preferred_congestion_backend).
 
     ``target_alpha`` stops a probe as soon as the exactly-evaluated alpha
-    reaches it — what the ``max_servers_at_full_capacity`` bisection passes
-    so "clearly feasible" probes cost a fraction of the full iteration
-    budget.  Figure sweeps keep ``early_stop=False`` (the default) so
+    reaches it — what the ``repro.core.max_servers_at_full_capacity``
+    bisection passes so "clearly feasible" probes cost a fraction of the
+    full iteration budget.  Figure sweeps keep ``early_stop=False`` (the default) so
     reported alphas stay at the fixed-budget quality; only stopping *after*
     the decision threshold is reached can never change a probe's verdict.
     """
     comm = random_permutation_traffic(top, seed=seed)
     ps = build_path_system(top, comm, k=k, max_slack=slack)
-    if _wants_mw(ps, method):
+    if wants_mw(ps, method):
         return mw_concurrent_flow(
             ps, iters=iters, backend=mw_backend, early_stop=early_stop,
             target_alpha=target_alpha,
@@ -84,13 +62,13 @@ def batch_alphas(ps_list, method="auto", iters=500, mw_backend="auto",
     """Per-instance alpha for many independent path systems.
 
     Solver selection is PER INSTANCE and identical to ``alpha_of`` (exact
-    LP at or below ``MW_MIN_PATHS`` path variables, MW above), so the
-    returned alphas match a sequential loop; the MW instances are solved in
-    ONE ``mw_concurrent_flow_batch`` call — the sweep drivers' way onto the
-    batched solver.
+    LP at or below ``repro.core.capacity.MW_MIN_PATHS`` path variables, MW
+    above), so the returned alphas match a sequential loop; the MW instances
+    are solved in ONE ``mw_concurrent_flow_batch`` call — the sweep
+    drivers' way onto the batched solver.
     """
     out = [0.0] * len(ps_list)
-    mw_ids = [i for i, ps in enumerate(ps_list) if _wants_mw(ps, method)]
+    mw_ids = [i for i, ps in enumerate(ps_list) if wants_mw(ps, method)]
     if mw_ids:
         res = mw_concurrent_flow_batch(
             [ps_list[i] for i in mw_ids], iters=iters, backend=mw_backend,
@@ -102,179 +80,6 @@ def batch_alphas(ps_list, method="auto", iters=500, mw_backend="auto",
     for i in sorted(lp_ids):
         out[i] = lp_concurrent_flow(ps_list[i]).alpha
     return out
-
-
-def spread_servers(total: int, n_switches: int) -> np.ndarray:
-    per = total // n_switches
-    extra = total - per * n_switches
-    servers = np.full(n_switches, per, dtype=np.int64)
-    servers[:extra] += 1
-    return servers
-
-
-def jellyfish_same_equipment(n_switches: int, ports: int, n_servers: int, seed=0):
-    """Jellyfish on identical switching equipment hosting n_servers."""
-    return jellyfish_heterogeneous(
-        np.full(n_switches, ports), spread_servers(n_servers, n_switches), seed=seed
-    )
-
-
-def _probe_systems(top, n_matrices, k):
-    """One probe's path systems, traffic seeds 0..n_matrices-1, slack=3.
-
-    With the build pipeline enabled (``REPRO_BUILD_PIPELINE``, default on)
-    all of a probe's matrices build as ONE ``build_path_system_batch`` —
-    one combined frontier pass instead of n_matrices separate ones.  The
-    batch builder's bit-exactness contract (INVARIANTS.md CT-build) makes
-    the returned systems byte-identical to the sequential loop, so every
-    downstream verdict is unchanged.
-    """
-    if pipeline_enabled():
-        comms = [
-            random_permutation_traffic(top, seed=s) for s in range(n_matrices)
-        ]
-        batch = build_path_system_batch(
-            [top] * n_matrices, comms, k=k, max_slack=3
-        )
-        return list(batch.systems)
-    # lazy fallback: the LP short-circuit in _probe_verdict stops building
-    # the moment a matrix rejects the probe, exactly as the pre-pipeline
-    # driver did
-    return (
-        build_path_system(
-            top, random_permutation_traffic(top, seed=s), k=k, max_slack=3
-        )
-        for s in range(n_matrices)
-    )
-
-
-def _probe_verdict(systems, tol, method):
-    """LP short-circuit + MW deferral over already-built probe systems."""
-    mw_systems = []
-    for ps in systems:
-        if _wants_mw(ps, method):
-            mw_systems.append(ps)
-        elif lp_concurrent_flow(ps).alpha < 1.0 - tol:
-            return False, mw_systems
-    return True, mw_systems
-
-
-def _probe_matrices(top, n_matrices, k, tol, method):
-    """The full-capacity probe body shared by the sequential and wave
-    drivers — ONE copy, so their per-(candidate, seed, matrix) decisions
-    cannot drift apart (the speculative search's "identical server count"
-    contract rides on that).
-
-    LP-sized matrices verdict sequentially with a short-circuit (the first
-    infeasible one settles the probe); MW-sized ones are returned for the
-    caller to fold into a single batched solve.  slack=3 matches the
-    alpha_of probe this replaced.  Returns ``(lp_ok, mw_systems)``.
-    """
-    return _probe_verdict(_probe_systems(top, n_matrices, k), tol, method)
-
-
-def supports_full_capacity(top, n_matrices=3, k=8, tol=1e-6,
-                           method="auto", iters=500,
-                           mw_backend="auto") -> bool:
-    # the probe only needs "alpha >= 1": let the MW path stop the moment it
-    # exhibits a feasible alpha-1 flow instead of polishing past it.  No
-    # plateau early-stop — a probe that has NOT reached the target must burn
-    # the full budget, or near-boundary instances (slow crawl toward 1.0)
-    # would be misclassified as infeasible relative to the fixed-budget run.
-    lp_ok, mw_systems = _probe_matrices(top, n_matrices, k, tol, method)
-    if not lp_ok:
-        return False
-    if mw_systems:
-        res = mw_concurrent_flow_batch(mw_systems, iters=iters,
-                                       target_alpha=1.0, backend=mw_backend)
-        return all(r.alpha >= 1.0 - tol for r in res)
-    return True
-
-
-def max_servers_at_full_capacity(
-    n_switches: int, ports: int, lo: int, hi: int, seeds=(0,), k=8,
-    wave_levels: int = 1, method: str = "auto", n_matrices: int = 3,
-    tol: float = 1e-6, iters: int = 500, mw_backend: str = "auto",
-) -> int:
-    """Binary search (paper §4 methodology) for the largest server count the
-    equipment supports at full capacity, validated across topology seeds.
-
-    ``wave_levels > 1`` probes speculatively: each wave evaluates every
-    candidate the next ``wave_levels`` bisection steps could ask about
-    (``core.bisection.speculative_max_feasible``), batching all of the
-    wave's MW-sized (candidate x topology seed x traffic matrix) solves
-    into one ``mw_concurrent_flow_batch`` call.  The per-candidate verdict
-    is the same conjunction over the same per-instance solvers
-    (``_probe_matrices`` is literally the shared probe body), so the final
-    server count is identical to the sequential search; only the wall-clock
-    shrinks (by ~2x at ``wave_levels=2`` where MW probes dominate).
-    LP-sized probes keep the sequential short-circuit inside each candidate.
-
-    Caveat: the identity is exact under the order-preserving congestion
-    backends (gather/scatter — every CPU batch).  On TPU, ``auto`` sizes
-    the dense-kernel budget by the WHOLE stack, and the wave's larger
-    batches can resolve a different backend than the sequential probes'
-    smaller ones; dense reassociates (~1e-4 alpha drift), so a probe
-    sitting within that of the 1.0 threshold could flip.  Pass an explicit
-    ``mw_backend`` ("scatter") there if strict wave==sequential identity
-    matters more than the fused-kernel speed.
-    """
-
-    def ok(m: int) -> bool:
-        for seed in seeds:
-            top = jellyfish_same_equipment(n_switches, ports, m, seed=seed)
-            if not supports_full_capacity(top, n_matrices=n_matrices, k=k,
-                                          tol=tol, method=method, iters=iters,
-                                          mw_backend=mw_backend):
-                return False
-        return True
-
-    if wave_levels <= 1:
-        return max_feasible(lo, hi, ok)
-
-    def ok_batch(candidates):
-        verdicts = [True] * len(candidates)
-        mw_systems, owner = [], []
-        # one build unit per (candidate, seed); with the pipeline enabled
-        # stream_builds prefetches unit i+1 on the background worker while
-        # the consumer runs unit i's LP verdicts, so host enumeration
-        # overlaps the probe solves.  Results arrive in submission order,
-        # so the verdict fold below is the sequential loop verbatim.
-        tasks = [(ci, m, seed) for ci, m in enumerate(candidates)
-                 for seed in seeds]
-
-        def build_thunk(m, seed):
-            def thunk():
-                top = jellyfish_same_equipment(n_switches, ports, m,
-                                               seed=seed)
-                return _probe_systems(top, n_matrices, k)
-            return thunk
-
-        stream = stream_builds(build_thunk(m, seed) for _, m, seed in tasks)
-        for (ci, m, seed), systems in zip(tasks, stream):
-            if not verdicts[ci]:
-                continue  # an earlier LP matrix rejected this candidate
-            lp_ok, mws = _probe_verdict(systems, tol, method)
-            mw_systems.extend(mws)
-            owner.extend([ci] * len(mws))
-            if not lp_ok:
-                verdicts[ci] = False
-        # LP-rejected candidates' MW systems are dead weight: solving them
-        # burns a full target_alpha=1.0 budget and inflates the batch's
-        # common padding envelope for the surviving probes
-        keep = [i for i, ci in enumerate(owner) if verdicts[ci]]
-        mw_systems = [mw_systems[i] for i in keep]
-        owner = [owner[i] for i in keep]
-        if mw_systems:
-            res = mw_concurrent_flow_batch(
-                mw_systems, iters=iters, target_alpha=1.0, backend=mw_backend
-            )
-            for ci, r in zip(owner, res):
-                if r.alpha < 1.0 - tol:
-                    verdicts[ci] = False
-        return verdicts
-
-    return speculative_max_feasible(lo, hi, ok_batch, levels=wave_levels)
 
 
 def csv_row(name: str, us_per_call: float, derived: str) -> str:

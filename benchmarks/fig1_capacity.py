@@ -4,17 +4,23 @@
 vs servers at full bisection for commodity port counts.
 1c is the measured headline: same switching equipment as a k-ary fat-tree,
 binary-search the server count Jellyfish supports at full capacity under
-random-permutation traffic with optimal (LP) routing.
+random-permutation traffic with optimal (LP) routing.  The search is
+``repro.core.capacity.max_servers_at_full_capacity``.
 """
 
 from __future__ import annotations
 
 import numpy as np
 
-from repro.core import bollobas_bound, fattree_equipment, set_build_pipeline
+from repro.core import (
+    bollobas_bound,
+    fattree_equipment,
+    max_servers_at_full_capacity,
+    set_build_pipeline,
+)
 from repro.core.routing import clear_routing_cache
 
-from .common import FULL, Timer, csv_row, max_servers_at_full_capacity, save
+from .common import FULL, Timer, csv_row, save
 
 
 def fig1ab() -> dict:

@@ -10,10 +10,15 @@ from __future__ import annotations
 
 import numpy as np
 
-from repro.core import DD_CATALOG, degree_diameter_graph, jellyfish_heterogeneous
+from repro.core import (
+    DD_CATALOG,
+    degree_diameter_graph,
+    jellyfish_heterogeneous,
+    spread_servers,
+)
 from repro.core.routing import clear_routing_cache, set_apsp_backend
 
-from .common import Timer, alpha_of, csv_row, save, spread_servers
+from .common import Timer, alpha_of, csv_row, save
 
 
 # (catalog name, servers per switch) — tuned so the dd-graph is above

@@ -6,9 +6,15 @@ from __future__ import annotations
 
 import numpy as np
 
-from repro.core import jellyfish_heterogeneous, swdc_hex3d, swdc_ring, swdc_torus2d
+from repro.core import (
+    jellyfish_heterogeneous,
+    spread_servers,
+    swdc_hex3d,
+    swdc_ring,
+    swdc_torus2d,
+)
 
-from .common import FULL, Timer, alpha_of, csv_row, save, spread_servers
+from .common import FULL, Timer, alpha_of, csv_row, save
 
 SIDE = 22 if FULL else 14  # torus side; ring/jf sized to match (N = side^2)
 

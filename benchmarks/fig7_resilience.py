@@ -31,6 +31,7 @@ from repro.core import (
     jellyfish,
     pipeline_enabled,
     random_permutation_traffic,
+    same_equipment_jellyfish,
     stream_builds,
     update_path_system,
 )
@@ -40,7 +41,6 @@ from .common import (
     Timer,
     batch_alphas,
     csv_row,
-    jellyfish_same_equipment,
     save,
 )
 
@@ -146,7 +146,7 @@ def run() -> list[str]:
     k = 8
     eq = fattree_equipment(k)
     ft = fattree(k)
-    jf = jellyfish_same_equipment(
+    jf = same_equipment_jellyfish(
         eq["switches"], eq["ports_per_switch"], int(eq["servers"] * 1.15), seed=0
     )
     fractions = (0.0, 0.03, 0.06, 0.09, 0.12, 0.15)

@@ -19,10 +19,11 @@ from repro.core import (
     lp_concurrent_flow,
     mptcp_throughput,
     random_permutation_traffic,
+    same_equipment_jellyfish,
 )
 from repro.sim import fattree_ecmp_check
 
-from .common import FULL, Timer, csv_row, jellyfish_same_equipment, save
+from .common import FULL, Timer, csv_row, save
 
 
 def _mptcp_mean(top, seed, k=16):
@@ -61,7 +62,7 @@ def fig8() -> list[dict]:
     for n_sw, ports, sps in ((40, 10, 4), (80, 12, 4), (120, 14, 5)):
         a_opt, a_mp = [], []
         for seed in range(3):
-            top = jellyfish_same_equipment(n_sw, ports, n_sw * sps, seed=seed)
+            top = same_equipment_jellyfish(n_sw, ports, n_sw * sps, seed=seed)
             comm = random_permutation_traffic(top, seed=seed)
             opt = lp_concurrent_flow(
                 build_path_system(top, comm, k=24, max_slack=4)
@@ -90,7 +91,7 @@ def fig9() -> list[dict]:
         lo, hi = eq["servers"] // 2, 2 * eq["servers"]
         while lo < hi:
             mid = (lo + hi + 1) // 2
-            top = jellyfish_same_equipment(eq["switches"], k, mid, seed=0)
+            top = same_equipment_jellyfish(eq["switches"], k, mid, seed=0)
             tp = np.mean([_mptcp_mean(top, s) for s in range(2)])
             if tp >= ft_tp - 1e-3:
                 lo = mid

@@ -17,9 +17,10 @@ from repro.core import (
     mw_concurrent_flow,
     plan_cables,
     random_permutation_traffic,
+    same_equipment_jellyfish,
 )
 
-from .common import Timer, csv_row, jellyfish_same_equipment, save
+from .common import Timer, csv_row, save
 
 
 def run() -> list[str]:
@@ -29,7 +30,7 @@ def run() -> list[str]:
         ft = fattree(k)
         eq = fattree_equipment(k)  # 1024 servers, 320 switches
         n_sw = int(eq["switches"] * 0.82)
-        jf = jellyfish_same_equipment(n_sw, k, eq["servers"], seed=0)
+        jf = same_equipment_jellyfish(n_sw, k, eq["servers"], seed=0)
         comm = random_permutation_traffic(jf, seed=0)
         alpha = mw_concurrent_flow(
             build_path_system(jf, comm, k=8), iters=400

@@ -33,7 +33,13 @@ from repro.core import (
     stream_builds,
     update_path_system,
 )
-from repro.core import fattree_equipment, max_feasible, mw_concurrent_flow_batch
+from repro.core import (
+    fattree_equipment,
+    max_feasible,
+    max_servers_at_full_capacity,
+    mw_concurrent_flow_batch,
+    same_equipment_jellyfish,
+)
 from repro.core.flow import _fold_sum, _path_cost_gather
 from repro.core.routing import _k_shortest_paths_dfs, clear_routing_cache
 from repro.kernels import ops
@@ -51,8 +57,6 @@ from .common import (
     Timer,
     alpha_of,
     csv_row,
-    jellyfish_same_equipment,
-    max_servers_at_full_capacity,
     save,
 )
 
@@ -276,7 +280,7 @@ def _speculative_bisection_row() -> dict:
 
     def ok_legacy(m: int) -> bool:
         # the pre-batching probe: one single-instance MW solve per matrix
-        top = jellyfish_same_equipment(n_sw, ports, m, seed=0)
+        top = same_equipment_jellyfish(n_sw, ports, m, seed=0)
         return all(
             alpha_of(top, seed=s, k=8, method="mw", iters=iters,
                      target_alpha=1.0)

@@ -22,6 +22,7 @@ from repro.core import (
     fattree,
     fattree_equipment,
     random_permutation_traffic,
+    same_equipment_jellyfish,
 )
 from repro.sim import (
     ecmp_path_system,
@@ -30,7 +31,7 @@ from repro.sim import (
     path_diversity,
 )
 
-from .common import Timer, csv_row, jellyfish_same_equipment, save
+from .common import Timer, csv_row, save
 
 #: The paper's instance: same switching equipment as a k=14 fat-tree
 #: (245 switches x 14 ports), 686 servers.
@@ -53,7 +54,7 @@ def _hashed_link_counts(ps, salt: int = 0) -> np.ndarray:
 
 def jellyfish_diversity(seed: int = 0) -> dict:
     eq = fattree_equipment(FT_K)
-    top = jellyfish_same_equipment(eq["switches"], FT_K, eq["servers"], seed=seed)
+    top = same_equipment_jellyfish(eq["switches"], FT_K, eq["servers"], seed=seed)
     comm = random_permutation_traffic(top, seed=seed)
     ecmp64 = ecmp_path_system(top, comm, n_ways=64)
     ksp8 = build_path_system(top, comm, k=8)

@@ -28,6 +28,13 @@ from .flow import (
     throughput,
 )
 from .buildpipe import pipeline_enabled, set_build_pipeline, stream_builds
+from .capacity import (
+    max_servers_at_full_capacity,
+    probe_wave,
+    same_equipment_jellyfish,
+    spread_servers,
+    supports_full_capacity,
+)
 from .jellyfish import jellyfish, jellyfish_heterogeneous, rrg
 from .legup import CostModel, ExpansionStage, jellyfish_arc, legup_arc
 from .metrics import (
@@ -94,6 +101,8 @@ __all__ = [
     "FlowResult", "PathSystemBatch", "mw_concurrent_flow",
     "mw_concurrent_flow_batch", "lp_concurrent_flow",
     "lp_edge_concurrent_flow", "throughput",
+    "spread_servers", "same_equipment_jellyfish", "probe_wave",
+    "supports_full_capacity", "max_servers_at_full_capacity",
     "MptcpResult", "mptcp_throughput",
     "fail_links", "fail_switches",
     "CablePlan", "localized_jellyfish", "plan_cables",

@@ -18,11 +18,12 @@ normalized by one partition's server bandwidth, bracketing it with the
 spectral lower bound.
 
 This module also hosts the paper-§4 *binary-search* machinery
-(``max_feasible`` / ``speculative_max_feasible``): the Fig 1c
-``max_servers_at_full_capacity`` search spends all of its wall-clock inside
-one throughput probe per bracket-halving, so the speculative driver
-evaluates several levels of the bisection tree per wave — one batched
-``mw_concurrent_flow_batch`` call answers every probe the next ``levels``
+(``max_feasible`` / ``speculative_max_feasible``): the Fig 1c search,
+``core.capacity.max_servers_at_full_capacity``, spends all of its
+wall-clock inside one throughput probe per bracket-halving, so the
+speculative driver evaluates several levels of the bisection tree per
+wave — one ``core.capacity.probe_wave`` call answers every probe the next
+``levels``
 halvings could possibly ask — and then descends the tree with the answers
 in hand.  The result is IDENTICAL to the sequential search for any
 predicate (both monotone and not): the wave only precomputes the exact

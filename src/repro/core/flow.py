@@ -74,9 +74,9 @@ BIT-exactly (alpha diff 0.0, identical adaptive iteration counts) against
 the sequential ``scatter`` backend, whose accumulation order the gather
 tables reproduce; small CPU instances default the sequential solver to
 ``dense``, where reassociation-level drift (~1e-4 after the anneal) is
-expected.  The speculative bisection
-(``core.bisection.speculative_max_feasible``) and the benchmark sweep
-drivers (``benchmarks.common.batch_alphas``) sit on top.
+expected.  The Fig 1c capacity search (``core.capacity``, whose
+``probe_wave`` batches a speculative bisection wave) and the benchmark
+sweep drivers (``benchmarks.common.batch_alphas``) sit on top.
 
 ``REPRO_LP_PATH_LIMIT`` (validated at import) moves the ``throughput()``
 LP-vs-MW cutoff from its 20000-path default.
@@ -704,9 +704,9 @@ def mw_concurrent_flow(
     so a run that never plateaus is bit-identical to ``early_stop=False``.
     ``target_alpha`` additionally stops as soon as the best (exactly
     evaluated) alpha reaches it — the feasibility-probe mode that keeps the
-    ``max_servers_at_full_capacity`` bisection from burning the full budget
-    on clearly-feasible probes.  ``FlowResult.iters`` reports the iterations
-    actually run.
+    ``core.capacity.max_servers_at_full_capacity`` bisection from burning
+    the full budget on clearly-feasible probes.  ``FlowResult.iters``
+    reports the iterations actually run.
     """
     if ps.n_paths == 0:
         return FlowResult(0.0, np.zeros(0), np.inf, "mw", 0)
@@ -1186,8 +1186,11 @@ def mw_concurrent_flow_batch(
     Traced (``repro.obs``), the host phases are spans: ``mw/assemble``
     (stacking a sequence; ``rows``), ``mw/upload`` (the host tables sent
     and the carry's set-up; ``bytes``), ``mw/window_batch`` (one per window
-    dispatched) and ``mw/readback`` (the final evaluation copied back: the
-    host's wait for the device).
+    dispatched; ``active`` live instances of ``instances`` computed, the
+    padded batch), ``mw/sync`` (adaptive solves only, one per window: the
+    host's read of every instance's best alpha and the stop decisions) and
+    ``mw/readback`` (the final evaluation copied back: the host's wait for
+    the device).
     """
     n_asked: int | None = None
     if isinstance(systems, PathSystemBatch):
@@ -1239,7 +1242,7 @@ def mw_concurrent_flow_batch(
     adaptive = early_stop or target_alpha is not None
     if not adaptive:
         with obs.span("mw/window_batch", t0=0, step=iters,
-                      active=int(active.sum())):
+                      active=int(active.sum()), instances=B):
             carry = _mw_window_batch(
                 pe, owner, demands, inv_cap, slot_valid, carry, 0, iters,
                 jnp.asarray(active), iters, iters, backend, slot_tab,
@@ -1253,7 +1256,7 @@ def mw_concurrent_flow_batch(
         while t0 < iters and active.any():
             step = min(check_every, iters - t0)
             with obs.span("mw/window_batch", t0=t0, step=step,
-                          active=int(active.sum())):
+                          active=int(active.sum()), instances=B):
                 carry = _mw_window_batch(
                     pe, owner, demands, inv_cap, slot_valid, carry, t0, step,
                     jnp.asarray(active), iters, check_every, backend,
@@ -1261,28 +1264,31 @@ def mw_concurrent_flow_batch(
                 )
                 t0 += step
                 done[active] += step
-                best = np.asarray(carry[2])
             obs.counter("mw/windows_batch").inc()
-            if obs.trace_enabled():
-                obs.counter_event("mw/alpha_batch_mean",
-                                  float(best[active].mean()))
-            for b in np.flatnonzero(active):
-                # identical decision sequence to mw_concurrent_flow's
-                # window loop, applied per instance
-                if target_alpha is not None and best[b] >= target_alpha:
-                    active[b] = False
-                    obs.counter("mw/stop/target").inc()
-                    continue
-                if early_stop:
-                    if best[b] - best_prev[b] < rel_tol * max(best[b], 1e-12):
-                        stall[b] += 1
-                        if stall[b] >= patience:
-                            active[b] = False
-                            obs.counter("mw/stop/plateau").inc()
-                            continue
-                    else:
-                        stall[b] = 0
-                    best_prev[b] = max(best[b], best_prev[b])
+            # the host waits here for the window, then decides who stops
+            with obs.span("mw/sync", t0=t0, active=int(active.sum())):
+                best = np.asarray(carry[2])
+                if obs.trace_enabled():
+                    obs.counter_event("mw/alpha_batch_mean",
+                                      float(best[active].mean()))
+                for b in np.flatnonzero(active):
+                    # identical decision sequence to mw_concurrent_flow's
+                    # window loop, applied per instance
+                    if target_alpha is not None and best[b] >= target_alpha:
+                        active[b] = False
+                        obs.counter("mw/stop/target").inc()
+                        continue
+                    if early_stop:
+                        if best[b] - best_prev[b] < rel_tol * max(best[b],
+                                                                  1e-12):
+                            stall[b] += 1
+                            if stall[b] >= patience:
+                                active[b] = False
+                                obs.counter("mw/stop/plateau").inc()
+                                continue
+                        else:
+                            stall[b] = 0
+                        best_prev[b] = max(best[b], best_prev[b])
         if active.any():
             obs.counter("mw/stop/budget").inc(int(active.sum()))
     # the host waits here for the device to finish the solve

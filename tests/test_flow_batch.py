@@ -288,7 +288,7 @@ def test_speculative_wave_rounds():
 
 def test_speculative_bisection_end_to_end_equal():
     """fig1c-style searches (MW probes) agree across drivers."""
-    from benchmarks.common import max_servers_at_full_capacity
+    from repro.core import max_servers_at_full_capacity
 
     kw = dict(seeds=(0,), k=4, method="mw", n_matrices=2)
     seq = max_servers_at_full_capacity(12, 8, 10, 30, **kw)

@@ -449,7 +449,9 @@ def test_mw_batch_phases_are_spans(traced):
         ps.n_paths for ps in systems)
     assert by["mw/upload"].attrs["bytes"] > sum(
         ps.path_edges.nbytes for ps in systems)
-    assert by["mw/window_batch"].attrs == {"t0": 0, "step": 30, "active": 3}
+    # three instances, bucketed to a batch of four
+    assert by["mw/window_batch"].attrs == {"t0": 0, "step": 30, "active": 3,
+                                           "instances": 4}
 
 
 def test_sim_reroute_phases_are_spans(traced):

@@ -30,9 +30,9 @@ def test_jellyfish_fewer_cables_than_fattree():
     k = 16
     eq = fattree_equipment(k)
     ft = fattree(k)
-    from benchmarks.common import jellyfish_same_equipment
+    from repro.core import same_equipment_jellyfish
 
-    jf = jellyfish_same_equipment(int(eq["switches"] * 0.82), k,
+    jf = same_equipment_jellyfish(int(eq["switches"] * 0.82), k,
                                   eq["servers"], seed=0)
     total_ft = ft.n_edges + ft.n_servers
     total_jf = jf.n_edges + jf.n_servers

@@ -24,6 +24,7 @@ from .contracts import (
     check_hop_matrix,
     check_path_system,
     check_path_system_batch,
+    check_segment_layout,
     check_sim_state,
     checks_enabled,
     set_check_enabled,
@@ -37,6 +38,7 @@ __all__ = [
     "check_hop_matrix",
     "check_path_system",
     "check_path_system_batch",
+    "check_segment_layout",
     "check_sim_state",
     "checks_enabled",
     "irlint",

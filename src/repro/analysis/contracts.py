@@ -9,7 +9,8 @@ canonical (length, lexicographic) tie order that makes delta updates
 bit-identical to rebuilds, and the int16 ``INT16_INF`` distance sentinel.
 This module checks them *at the boundaries where the structures are made*
 — ``build_path_system`` / ``update_path_system`` /
-``PathSystemBatch.from_systems`` / ``from_shared`` / ``sim.simulate`` —
+``PathSystemBatch.from_systems`` / ``from_shared`` / ``sim.simulate``, and
+the MW batch solver's row layout —
 behind ``REPRO_CHECK=1`` (see ``repro.env``; the tier-1 test suite turns
 it on by default via ``conftest.py``).
 
@@ -33,6 +34,7 @@ __all__ = [
     "check_hop_matrix",
     "check_path_system",
     "check_path_system_batch",
+    "check_segment_layout",
     "check_sim_state",
     "checks_enabled",
     "set_check_enabled",
@@ -300,10 +302,11 @@ def check_path_system_batch(batch, *, name: str = "path_system_batch",
     Padded slots must be *infinite capacity* (``inv_cap == 0`` exactly,
     masked by ``slot_valid``), padded path rows must belong to the
     zero-demand dummy commodity and hold each instance's own ``n_slots``
-    sentinel, and the gather fan-in tables must point back at hops of the
-    slot/commodity they index.  Per-instance content is compared against
-    the first ``max_instances`` source systems (the rest are shape-checked
-    only, keeping the validator O(batch envelope)).
+    sentinel (the dummy owning exactly each instance's tail), and the
+    gather fan-in table must point back at hops of the slot it indexes.
+    Per-instance content is compared against the first ``max_instances``
+    source systems (the rest are shape-checked only, keeping the validator
+    O(batch envelope)).
     """
     name = f"path_system_batch[{name}]"
     pe = np.asarray(batch.path_edges)
@@ -342,6 +345,17 @@ def check_path_system_batch(batch, *, name: str = "path_system_batch",
         if np.any(n_paths < 0) or np.any(n_paths > P):
             i = int(np.argmax((n_paths < 0) | (n_paths > P)))
             _fail(name, f"n_paths[{i}]={n_paths[i]} outside [0, P={P}]")
+        # every instance: the dummy commodity owns exactly the padding tail
+        tail = np.arange(P)[None, :] >= n_paths[:, None]
+        if np.any((owner == K) != tail):
+            i, p = map(int, np.argwhere((owner == K) != tail)[0])
+            if tail[i, p]:
+                _fail(name, f"padded row {p} of instance {i} must belong to "
+                            f"the dummy commodity {K}; path_owner[{i}, {p}]="
+                            f"{owner[i, p]}")
+            _fail(name, f"real row {p} of instance {i} (n_paths="
+                        f"{n_paths[i]}) must not belong to the dummy "
+                        f"commodity {K}")
         for i, ps in enumerate(batch.systems[:max_instances]):
             Si = ps.n_slots
             if not (np.all(sval[i, :Si]) and not np.any(sval[i, Si:])):
@@ -357,11 +371,6 @@ def check_path_system_batch(batch, *, name: str = "path_system_batch",
             if int(n_paths[i]) != pb:
                 _fail(name, f"n_paths[{i}]={int(n_paths[i])} but source "
                             f"system has {pb} paths")
-            if np.any(owner[i, pb:] != K):
-                p = pb + int(np.argmax(owner[i, pb:] != K))
-                _fail(name, f"padded row {p} of instance {i} must belong to "
-                            f"the dummy commodity {K}; path_owner[{i}, {p}]="
-                            f"{owner[i, p]}")
             if np.any(pe[i, pb:, :] != Si):
                 p, j = map(int, np.argwhere(pe[i, pb:, :] != Si)[0])
                 _fail(name, f"padded row {pb + p} of instance {i} must hold "
@@ -409,8 +418,8 @@ def check_path_system_batch(batch, *, name: str = "path_system_batch",
             _fail(name, "shared batch n_paths must all equal the source "
                         f"system's {ps.n_paths}")
 
-    # gather fan-in tables: every non-sentinel pointer must point back at a
-    # hop of the slot (row of the commodity) it is indexed under
+    # gather fan-in table: every non-sentinel pointer must point back at a
+    # hop of the slot it is indexed under
     if batch.slot_gather is not None:
         tab = np.asarray(batch.slot_gather)
         flat = (pe.reshape(pe.shape[0], -1) if stacked
@@ -430,24 +439,27 @@ def check_path_system_batch(batch, *, name: str = "path_system_batch",
                 _fail(name, f"slot_gather[{i}, {int(s_idx[j])}, "
                             f"{int(d_idx[j])}] points at a hop of slot "
                             f"{int(flat[i, tabs[i, s_idx[j], d_idx[j]]])}")
-    if batch.owner_gather is not None:
-        tab = np.asarray(batch.owner_gather)
-        own = owner if stacked else np.broadcast_to(owner[None],
-                                                    (1, owner.shape[0]))
-        tabs = tab if stacked else tab[None]
-        Pmax = own.shape[1]
-        if np.any(tabs < 0) or np.any(tabs > Pmax):
-            idx = tuple(map(int, np.argwhere((tabs < 0) | (tabs > Pmax))[0]))
-            _fail(name, f"owner_gather{list(idx)}={tabs[idx]} outside "
-                        f"[0, P={Pmax}]")
-        nb = min(tabs.shape[0], max_instances)
-        for i in range(nb):
-            k_idx, d_idx = np.nonzero(tabs[i] < Pmax)
-            if k_idx.size and np.any(own[i, tabs[i, k_idx, d_idx]] != k_idx):
-                j = int(np.argmax(own[i, tabs[i, k_idx, d_idx]] != k_idx))
-                _fail(name, f"owner_gather[{i}, {int(k_idx[j])}, "
-                            f"{int(d_idx[j])}] points at a row of commodity "
-                            f"{int(own[i, tabs[i, k_idx[j], d_idx[j]]])}")
+
+
+def check_segment_layout(batch, *, name: str = "mw_batch") -> None:
+    """Validate the row layout the batched MW normalisation sums over.
+
+    ``_seg_norm`` forms each commodity's split sum by shifted adds over
+    neighbouring rows, so every instance's owners must never decrease
+    along its row (each commodity one contiguous run: the canonical
+    layout of CT-ps, with the dummy's padding after it).  Checked where
+    the MW solver takes a batch: the simulator's waterfill accepts any
+    row order.
+    """
+    name = f"segment_layout[{name}]"
+    owner = np.asarray(batch.path_owner)
+    own = owner if owner.ndim == 2 else owner[None]
+    if np.any(np.diff(own, axis=1) < 0):
+        i, p = map(int, np.argwhere(np.diff(own, axis=1) < 0)[0])
+        _fail(name, f"instance {i} path rows must be grouped by commodity "
+                    f"in order (canonical layout); path_owner[{i}, {p}]="
+                    f"{own[i, p]} > path_owner[{i}, {p + 1}]="
+                    f"{own[i, p + 1]}")
 
 
 def check_built_batch(batch, tops, *, name: str = "build_path_system_batch",

@@ -62,12 +62,15 @@ the softmax; padded path rows belong to a zero-demand dummy commodity), and
   rank-3 incidence through ``ops.congestion`` (one fused-kernel pass per
   batch member per iteration on TPU), or — the CPU default for batches —
   ``gather``: transposed fan-in tables precomputed at batch build time
-  (for every slot, the flat positions of the path hops crossing it; for
-  every commodity, its path rows), which turn the XLA scatter-adds that
-  dominate the scatter backend's iteration (~5 ms at RRG(512), serialized
-  element loop) into vectorized gather+sum (~0.13 ms measured).  The
-  tables are why batched solves are several times faster than the same
-  instances solved sequentially on CPU, not just less dispatch overhead.
+  (for every slot, the flat positions of the path hops crossing it),
+  which turn the XLA scatter-add that dominates the scatter backend's
+  iteration (~5 ms at RRG(512), serialized element loop) into vectorized
+  gather+sum (~0.13 ms measured).  The tables are why batched solves are
+  several times faster than the same instances solved sequentially on
+  CPU, not just less dispatch overhead;
+* every backend normalises each commodity's split over its contiguous
+  run of path rows (``_seg_norm``): at most ``seg_max`` dense shifted
+  adds and selects, no scatter or gather.
 
 Per-instance results match ``mw_concurrent_flow`` to float tolerance —
 BIT-exactly (alpha diff 0.0, identical adaptive iteration counts) against
@@ -96,7 +99,11 @@ import jax.numpy as jnp
 
 from .. import env
 from .. import obs
-from ..analysis.contracts import check_path_system_batch, checks_enabled
+from ..analysis.contracts import (
+    check_path_system_batch,
+    check_segment_layout,
+    checks_enabled,
+)
 from ..analysis.registry import AuditCase, solver_jit
 from .routing import PathSystem
 from ..kernels import ops
@@ -298,6 +305,64 @@ def _bucket_up_geom(n: int) -> int:
     n = max(int(n), 1)
     step = max(256, 1 << max(n.bit_length() - 3, 0))
     return _bucket_up(n, step)
+
+
+def _seg_passes(owner: np.ndarray) -> int:
+    """Static pass count of ``_seg_norm`` for one owner vector: its
+    longest commodity (one contiguous run of rows in the canonical layout),
+    bucketed like L so that nearby systems share one compiled scan."""
+    owner = np.asarray(owner)
+    return _bucket_up(int(np.bincount(owner).max()) if owner.size else 1, 4)
+
+
+def _shift(a: jnp.ndarray, j: int, fill) -> jnp.ndarray:
+    """``out[..., p] = a[..., p + j]`` along the last axis, ``fill`` where
+    ``p + j`` falls off it (``j < 0`` moves rows toward the end)."""
+    cfg = [(0, 0, 0)] * (a.ndim - 1) + [(-j, j, 0)]
+    return jax.lax.pad(a, jnp.asarray(fill, a.dtype), cfg)
+
+
+def _seg_layout(owner: jnp.ndarray, seg_max: int, dummy: int | None = None):
+    """Each path row's place in its commodity's run, for ``_seg_norm``.
+
+    ``owner`` is (P,) or (Bt, P) in the canonical layout (CT-ps): owners
+    never decrease along a row, so each commodity is one contiguous run of
+    at most ``seg_max`` rows.  Returns int32 ``(pos, run)``: ``pos`` the
+    row's offset from its run's head, ``run`` the rows from it to the
+    run's end (the run's length at its head).  Rows of the ``dummy``
+    commodity (a stacked batch's padding tail) get ``pos = -1``: their
+    divisor is pinned to 1, so its arbitrarily long run never sets the
+    pass count.  Dense shifted compares; computed once per program call,
+    outside the scan.
+    """
+    pos = jnp.zeros(owner.shape, jnp.int32)
+    run = jnp.ones(owner.shape, jnp.int32)
+    for j in range(1, min(seg_max, owner.shape[-1])):
+        pos = pos + (_shift(owner, -j, -1) == owner).astype(jnp.int32)
+        run = run + (_shift(owner, j, -1) == owner).astype(jnp.int32)
+    if dummy is not None:
+        pos = jnp.where(owner == dummy, -1, pos)
+    return pos, run
+
+
+def _seg_norm(x: jnp.ndarray, pos: jnp.ndarray, run: jnp.ndarray,
+              seg_max: int) -> jnp.ndarray:
+    """Divide each split weight by its commodity's sum (``_seg_layout``).
+
+    The sum forms at each run's head LEFT-TO-RIGHT, ``((x0 + x1) + x2)
+    ...``, the association of XLA:CPU's in-order scatter-add and of
+    ``_ordered_fan_in_sum`` (the masked-off terms add an exact 0), and is
+    then broadcast back over the run by ``seg_max`` selects: ``2 seg_max``
+    dense passes over ``x``, no scatter or gather.
+    """
+    n = min(seg_max, x.shape[-1])
+    s = x
+    for j in range(1, n):
+        s = s + jnp.where(j < run, _shift(x, j, 0.0), 0.0)
+    div = jnp.ones_like(x)
+    for q in range(n):
+        div = jnp.where(pos == q, _shift(s, -q, 0.0), div)
+    return x / div
 
 
 def make_congestion_fn_batch(
@@ -543,7 +608,8 @@ def _resolve_backend(
 
 
 @solver_jit(spec="_ir_cases_mw_window")
-@functools.partial(jax.jit, static_argnames=("iters_total", "n_steps", "backend"))
+@functools.partial(jax.jit, static_argnames=("iters_total", "n_steps",
+                                              "seg_max", "backend"))
 def _mw_window(
     path_edges: jnp.ndarray,  # (P, L) int32 padded with S (= n_slots)
     owner: jnp.ndarray,  # (P,) int32
@@ -554,6 +620,7 @@ def _mw_window(
     valid_steps,  # traced scalar: steps that actually advance the iterate
     iters_total: int,  # anneal horizon (the FULL budget, not the window)
     n_steps: int,
+    seg_max: int,  # _seg_norm pass count (_seg_passes of owner)
     backend: str = "scatter",
 ):
     """``n_steps`` MW iterations starting at global step ``t0``.
@@ -571,12 +638,8 @@ def _mw_window(
     instead of the last window tracing a fresh scan.
     """
     S = inv_cap.shape[0]
-    K = demands.shape[0]
     fused = make_congestion_fn(path_edges, S, backend)
-
-    def seg_norm(x):
-        s = jnp.zeros((K,), jnp.float32).at[owner].add(x)
-        return x / s[owner]
+    pos, run = _seg_layout(owner, seg_max)
 
     def body(carry, t):
         x, rel_prev, best_alpha, best_x = carry
@@ -604,7 +667,7 @@ def _mw_window(
         g = costs * demands[owner]
         g = g / jnp.maximum(jnp.max(g), 1e-12)
         eta = 2.0 / jnp.sqrt(1.0 + t.astype(jnp.float32))
-        x_next = seg_norm(x * jnp.exp(-eta * g))
+        x_next = _seg_norm(x * jnp.exp(-eta * g), pos, run, seg_max)
         x = jnp.where(live, x_next, x)
         rel = jnp.where(live, rel, rel_prev)
         return (x, rel, best_alpha, best_x), None
@@ -639,14 +702,12 @@ def _mw_final(
 
 
 @solver_jit(spec="_ir_cases_mw_carry_init")
-@jax.jit
+@functools.partial(jax.jit, static_argnames=("seg_max",))
 def _mw_carry_init(
     x_init: jnp.ndarray, owner: jnp.ndarray, inv_cap: jnp.ndarray,
-    demands: jnp.ndarray,
+    demands: jnp.ndarray, seg_max: int,
 ):
-    K = demands.shape[0]
-    s = jnp.zeros((K,), jnp.float32).at[owner].add(x_init)
-    x0 = x_init / s[owner]
+    x0 = _seg_norm(x_init, *_seg_layout(owner, seg_max), seg_max)
     return (x0, jnp.zeros_like(inv_cap), jnp.float32(0.0), x0)
 
 
@@ -719,13 +780,15 @@ def mw_concurrent_flow(
     owner = jnp.asarray(ps.path_owner)
     demands = jnp.asarray(ps.demands, dtype=jnp.float32)
     inv_cap = jnp.asarray(1.0 / ps.capacities, dtype=jnp.float32)
+    seg_max = _seg_passes(ps.path_owner)
     carry = _mw_carry_init(
-        jnp.asarray(x_init, dtype=jnp.float32), owner, inv_cap, demands
+        jnp.asarray(x_init, dtype=jnp.float32), owner, inv_cap, demands,
+        seg_max,
     )
     adaptive = early_stop or target_alpha is not None
     if not adaptive:
         carry = _mw_window(pe, owner, demands, inv_cap, carry, 0, iters, iters,
-                           iters, backend)
+                           iters, seg_max, backend)
         done = iters
     else:
         done = 0
@@ -739,7 +802,7 @@ def mw_concurrent_flow(
             step = min(check_every, iters - done)
             with obs.span("mw/window", t0=done, step=step):
                 carry = _mw_window(pe, owner, demands, inv_cap, carry, done,
-                                   step, iters, check_every, backend)
+                                   step, iters, check_every, seg_max, backend)
                 done += step
                 best = float(carry[2])  # best alpha so far (exact evals)
             obs.counter("mw/windows").inc()
@@ -794,17 +857,19 @@ class PathSystemBatch:
     table and per-instance demands only — the sweep-over-traffic-matrices
     case, where stacking B copies of the incidence would be pure waste.
 
-    Construction also precomputes the TRANSPOSED fan-in tables that back
+    Construction also precomputes the TRANSPOSED fan-in table that backs
     the ``gather`` congestion path (the CPU default for batches):
     ``slot_gather[.., s, :]`` holds the flat positions (``p * L + l``) of
-    every real path hop crossing slot s, and ``owner_gather[.., k, :]`` the
-    path rows of commodity k, both padded with an out-of-range sentinel
-    that gathers a zero.  Slot loads and per-commodity split sums then
-    become vectorized gather+sum instead of XLA scatter-adds (which execute
-    as a serialized element loop on CPU and dominate the scatter backend's
-    iteration).  A skew guard skips the tables when one slot's fan-in would
-    blow the table up past ``_GATHER_TABLE_GUARD`` times the hop count —
-    the driver falls back to ``scatter``.
+    every real path hop crossing slot s, padded with an out-of-range
+    sentinel that gathers a zero.  Slot loads then become vectorized
+    gather+sum instead of an XLA scatter-add (which executes as a
+    serialized element loop on CPU and dominates the scatter backend's
+    iteration).  A skew guard skips the table when one slot's fan-in would
+    blow it up past ``_GATHER_TABLE_GUARD`` times the hop count — the
+    driver falls back to ``scatter``.  The per-commodity split sums need
+    no table: each instance's rows keep the canonical layout, every
+    commodity one contiguous run and the dummy's rows at the tail
+    (``seg_max``, ``_seg_norm``).
     """
 
     path_edges: np.ndarray  # (B, P, L) int32 — or (P, L) when shared
@@ -815,10 +880,9 @@ class PathSystemBatch:
     n_paths: np.ndarray  # (B,) true per-instance path counts
     systems: list  # the original PathSystem objects (result slicing, warm)
     shared: bool = False
-    # transposed fan-in tables for the gather backend (None: skew guard hit
+    # transposed fan-in table for the gather backend (None: skew guard hit
     # or a hand-built batch; the solver then falls back to scatter)
     slot_gather: np.ndarray | None = None  # (B, S, D) int32 — or (S, D)
-    owner_gather: np.ndarray | None = None  # (B, K, D2) int32 — or (K, D2)
 
     @property
     def n_batch(self) -> int:
@@ -831,6 +895,13 @@ class PathSystemBatch:
     @property
     def s_max(self) -> int:
         return self.inv_cap.shape[-1]
+
+    @property
+    def seg_max(self) -> int:
+        """Pass count of the window's split normalisation: the longest
+        real commodity over the instances, bucketed (``_seg_passes``)."""
+        uniq = {id(ps): ps for ps in self.systems}.values()
+        return max(_seg_passes(ps.path_owner) for ps in uniq)
 
     @staticmethod
     def _slot_table(pe2d: np.ndarray, n_slots: int) -> tuple[np.ndarray, np.ndarray]:
@@ -857,7 +928,8 @@ class PathSystemBatch:
 
     @staticmethod
     def _owner_table(owner: np.ndarray, n_comm: int, n_rows: int) -> np.ndarray:
-        """(K, D2) path-row table for ONE instance's real commodities."""
+        """(K, D2) path-row table for ONE instance's real commodities (the
+        simulator's per-commodity candidate rows), padded with ``n_rows``."""
         order = np.argsort(owner, kind="stable")
         cnt = np.bincount(owner, minlength=n_comm)
         d = int(cnt.max()) if n_comm else 0
@@ -918,30 +990,16 @@ class PathSystemBatch:
             if ps.n_slots:
                 inv[i, : ps.n_slots] = 1.0 / ps.capacities
                 sval[i, : ps.n_slots] = True
-        # transposed fan-in tables (positions use the COMMON (P, L) layout)
+        # transposed fan-in table (positions use the COMMON (P, L) layout)
         per = [cls._slot_table(pe[i], ps.n_slots) for i, ps in enumerate(systems)]
         d = max((t.shape[1] for t, _ in per), default=0)
         if bucket:
             d = _bucket_up(max(d, 1), 8)
         slot_tab: np.ndarray | None = None
-        owner_tab: np.ndarray | None = None
         if 0 < S * max(d, 1) <= _GATHER_TABLE_GUARD * (P * L + 1):
             slot_tab = np.full((B, S, max(d, 1)), P * L, dtype=np.int32)
             for i, (t, _) in enumerate(per):
                 slot_tab[i, : t.shape[0], : t.shape[1]] = t
-            otabs = [
-                cls._owner_table(np.asarray(ps.path_owner), ps.n_commodities, P)
-                if ps.n_paths
-                else None
-                for ps in systems
-            ]
-            d2 = max((t.shape[1] for t in otabs if t is not None), default=1)
-            if bucket:
-                d2 = _bucket_up(d2, 4)
-            owner_tab = np.full((B, K, d2), P, dtype=np.int32)
-            for i, t in enumerate(otabs):
-                if t is not None:
-                    owner_tab[i, : t.shape[0], : t.shape[1]] = t
         batch = cls(
             path_edges=pe,
             path_owner=owner,
@@ -951,7 +1009,6 @@ class PathSystemBatch:
             n_paths=np.array([ps.n_paths for ps in systems], dtype=np.int64),
             systems=systems,
             slot_gather=slot_tab,
-            owner_gather=owner_tab,
         )
         if checks_enabled():
             check_path_system_batch(batch, name="from_systems")
@@ -981,14 +1038,12 @@ class PathSystemBatch:
         pe = np.asarray(ps.path_edges, dtype=np.int32)
         owner = np.asarray(ps.path_owner, dtype=np.int32)
         slot_tab: np.ndarray | None = None
-        owner_tab: np.ndarray | None = None
         if ps.n_paths:
             tab, _ = cls._slot_table(pe, ps.n_slots)
             d = max(tab.shape[1], 1)
             if S * d <= _GATHER_TABLE_GUARD * (pe.size + 1):
                 slot_tab = np.full((S, d), pe.size, dtype=np.int32)
                 slot_tab[: tab.shape[0], : tab.shape[1]] = tab
-                owner_tab = cls._owner_table(owner, ps.n_commodities, ps.n_paths)
         batch = cls(
             path_edges=pe,
             path_owner=owner,
@@ -999,7 +1054,6 @@ class PathSystemBatch:
             systems=[ps] * dem.shape[0],
             shared=True,
             slot_gather=slot_tab,
-            owner_gather=owner_tab,
         )
         if checks_enabled():
             check_path_system_batch(batch, name="from_shared")
@@ -1027,38 +1081,18 @@ def _batch_demand_per_path(demands, owner):
     return jnp.take_along_axis(demands, owner, axis=1)
 
 
-def _batch_seg_norm(x, owner, n_comm, owner_gather=None):
-    """Per-instance, per-commodity normalization of split weights.
-
-    With ``owner_gather`` (the gather backend) the per-commodity sums come
-    from the transposed path-row table instead of a scatter-add — summed
-    left-to-right in row order, matching the scatter-add's association
-    bit-exactly.  The dummy commodity's divisor is pinned to 1 (its padded
-    rows never feed anything real, and a true sum there would need the
-    scatter this path avoids).
-    """
-    Bt = x.shape[0]
-    if owner_gather is not None:
-        xp = jnp.concatenate([x, jnp.zeros((Bt, 1), jnp.float32)], axis=1)
-        s = _ordered_fan_in_sum(xp, owner_gather)
-        if owner.ndim == 1:  # shared: no dummy commodity
-            return x / s[:, owner]
-        s = jnp.concatenate([s, jnp.ones((Bt, 1), jnp.float32)], axis=1)
-        return x / jnp.take_along_axis(s, owner, axis=1)
-    if owner.ndim == 1:
-        s = jnp.zeros((Bt, n_comm), jnp.float32).at[:, owner].add(x)
-        return x / s[:, owner]
-    bidx = jnp.arange(Bt)[:, None]
-    s = jnp.zeros((Bt, n_comm), jnp.float32).at[bidx, owner].add(x)
-    return x / jnp.take_along_axis(s, owner, axis=1)
+def _batch_seg_layout(owner, n_comm, seg_max):
+    """``_seg_layout`` for either owner rank: a stacked (Bt, P) owner pads
+    with the dummy commodity ``n_comm - 1``; the shared (P,) one has none."""
+    return _seg_layout(owner, seg_max, None if owner.ndim == 1 else n_comm - 1)
 
 
 @solver_jit(spec="_ir_cases_mw_carry_init_batch")
-@jax.jit
-def _mw_carry_init_batch(x_init, owner, inv_cap, demands):
+@functools.partial(jax.jit, static_argnames=("seg_max",))
+def _mw_carry_init_batch(x_init, owner, inv_cap, demands, seg_max: int):
     Bt, K = demands.shape
     S = inv_cap.shape[-1]
-    x0 = _batch_seg_norm(x_init, owner, K)
+    x0 = _seg_norm(x_init, *_batch_seg_layout(owner, K, seg_max), seg_max)
     return (
         x0,
         jnp.zeros((Bt, S), jnp.float32),
@@ -1068,7 +1102,8 @@ def _mw_carry_init_batch(x_init, owner, inv_cap, demands):
 
 
 @solver_jit(spec="_ir_cases_mw_window_batch")
-@functools.partial(jax.jit, static_argnames=("iters_total", "n_steps", "backend"))
+@functools.partial(jax.jit, static_argnames=("iters_total", "n_steps",
+                                              "seg_max", "backend"))
 def _mw_window_batch(
     path_edges,  # (Bt, P, L) int32 — or (P, L) shared
     owner,  # (Bt, P) int32 — or (P,) shared
@@ -1081,9 +1116,9 @@ def _mw_window_batch(
     active,  # (Bt,) bool: instances still iterating (frozen ones pass through)
     iters_total: int,
     n_steps: int,
+    seg_max: int,  # _seg_norm pass count (PathSystemBatch.seg_max)
     backend: str = "scatter",
-    slot_gather=None,  # fan-in tables; required by the gather backend
-    owner_gather=None,
+    slot_gather=None,  # fan-in table; required by the gather backend
 ):
     """Batched mirror of ``_mw_window``: per-instance masked updates.
 
@@ -1098,7 +1133,7 @@ def _mw_window_batch(
     Bt, K = demands.shape
     S = inv_cap.shape[-1]
     fused = make_congestion_fn_batch(path_edges, S, Bt, backend, slot_gather)
-    seg_tab = owner_gather if backend == "gather" else None
+    pos, run = _batch_seg_layout(owner, K, seg_max)
     dem = _batch_demand_per_path(demands, owner)
     inv = inv_cap if inv_cap.ndim == 2 else inv_cap[None, :]
     neg_inf = jnp.float32(-jnp.inf)
@@ -1122,7 +1157,7 @@ def _mw_window_batch(
         g = costs * dem
         g = g / jnp.maximum(jnp.max(g, axis=1, keepdims=True), 1e-12)
         eta = 2.0 / jnp.sqrt(1.0 + t.astype(jnp.float32))
-        x_next = _batch_seg_norm(x * jnp.exp(-eta * g), owner, K, seg_tab)
+        x_next = _seg_norm(x * jnp.exp(-eta * g), pos, run, seg_max)
         x = jnp.where(live[:, None], x_next, x)
         rel = jnp.where(live[:, None], rel, rel_prev)
         return (x, rel, best_alpha, best_x), None
@@ -1187,8 +1222,9 @@ def mw_concurrent_flow_batch(
     (stacking a sequence; ``rows``), ``mw/upload`` (the host tables sent
     and the carry's set-up; ``bytes``), ``mw/window_batch`` (one per window
     dispatched; ``active`` live instances of ``instances`` computed, the
-    padded batch), ``mw/sync`` (adaptive solves only, one per window: the
-    host's read of every instance's best alpha and the stop decisions) and
+    padded batch; ``seg_max`` the split normalisation's pass count),
+    ``mw/sync`` (adaptive solves only, one per window: the host's read of
+    every instance's best alpha and the stop decisions) and
     ``mw/readback`` (the final evaluation copied back: the host's wait for
     the device).
     """
@@ -1208,6 +1244,8 @@ def mw_concurrent_flow_batch(
                     _empty_path_system() for _ in range(pad_b - n_asked)
                 ]
             batch = PathSystemBatch.from_systems(systems)
+    if checks_enabled():
+        check_segment_layout(batch, name="mw_concurrent_flow_batch")
     B = batch.n_batch
     empty = batch.n_paths == 0
     method_tag = "mw-batch"
@@ -1227,26 +1265,25 @@ def mw_concurrent_flow_batch(
         for i, (ps, w) in enumerate(zip(batch.systems, warm)):
             if w is not None and ps.row_map is not None and ps.n_paths:
                 x_init[i, : ps.n_paths] = _warm_split(ps, w)
-    tabs = ((batch.slot_gather, batch.owner_gather) if backend == "gather"
-            else (None, None))
     host = (batch.path_edges, batch.path_owner, batch.demands,
-            batch.inv_cap, batch.slot_valid, x_init, *tabs)
+            batch.inv_cap, batch.slot_valid, x_init,
+            batch.slot_gather if backend == "gather" else None)
     with obs.span("mw/upload",
                   bytes=sum(a.nbytes for a in host if a is not None)):
-        pe, owner, demands, inv_cap, slot_valid, x_dev, slot_tab, owner_tab = (
+        pe, owner, demands, inv_cap, slot_valid, x_dev, slot_tab = (
             None if a is None else jnp.asarray(a) for a in host
         )
-        carry = _mw_carry_init_batch(x_dev, owner, inv_cap, demands)
+        seg_max = batch.seg_max
+        carry = _mw_carry_init_batch(x_dev, owner, inv_cap, demands, seg_max)
     done = np.zeros(B, dtype=np.int64)
     active = ~empty
     adaptive = early_stop or target_alpha is not None
     if not adaptive:
         with obs.span("mw/window_batch", t0=0, step=iters,
-                      active=int(active.sum()), instances=B):
+                      active=int(active.sum()), instances=B, seg_max=seg_max):
             carry = _mw_window_batch(
                 pe, owner, demands, inv_cap, slot_valid, carry, 0, iters,
-                jnp.asarray(active), iters, iters, backend, slot_tab,
-                owner_tab,
+                jnp.asarray(active), iters, iters, seg_max, backend, slot_tab,
             )
         done[active] = iters
     else:
@@ -1256,11 +1293,12 @@ def mw_concurrent_flow_batch(
         while t0 < iters and active.any():
             step = min(check_every, iters - t0)
             with obs.span("mw/window_batch", t0=t0, step=step,
-                          active=int(active.sum()), instances=B):
+                          active=int(active.sum()), instances=B,
+                          seg_max=seg_max):
                 carry = _mw_window_batch(
                     pe, owner, demands, inv_cap, slot_valid, carry, t0, step,
-                    jnp.asarray(active), iters, check_every, backend,
-                    slot_tab, owner_tab,
+                    jnp.asarray(active), iters, check_every, seg_max, backend,
+                    slot_tab,
                 )
                 t0 += step
                 done[active] += step
@@ -1465,6 +1503,7 @@ def throughput(ps: PathSystem, method: str = "auto", iters: int = 400) -> FlowRe
 
 _IR_P, _IR_L, _IR_S, _IR_K = 6, 3, 8, 3  # paths, max hops, slots, commodities
 _IR_B, _IR_D = 2, 4  # batch, gather fan-in width
+_IR_SEG = 4  # _seg_passes of the IR owner vector (runs of 2 rows)
 
 
 def _ir_seq_args():
@@ -1494,7 +1533,6 @@ def _ir_batch_args():
     inv2 = np.ones((_IR_B, _IR_S), np.float32)
     sval2 = np.ones((_IR_B, _IR_S), bool)
     slot_gather = np.full((_IR_B, _IR_S, _IR_D), _IR_P * _IR_L, np.int32)
-    owner_gather = np.full((_IR_B, _IR_K, _IR_D), _IR_P, np.int32)
     carry_b = (
         np.ones((_IR_B, _IR_P), np.float32),
         np.zeros((_IR_B, _IR_S), np.float32),
@@ -1502,7 +1540,7 @@ def _ir_batch_args():
         np.ones((_IR_B, _IR_P), np.float32),
     )
     active = np.ones(_IR_B, bool)
-    return pe3, owner2, dem2, inv2, sval2, slot_gather, owner_gather, carry_b, active
+    return pe3, owner2, dem2, inv2, sval2, slot_gather, carry_b, active
 
 
 _IR_DENSE_EXEMPT = {
@@ -1520,7 +1558,8 @@ def _ir_cases_mw_window():
             pe, owner, demands, inv_cap, carry = _ir_seq_args()
             return (
                 (pe, owner, demands, inv_cap, carry, np.int32(0), np.int32(4)),
-                {"iters_total": 10, "n_steps": 4, "backend": backend},
+                {"iters_total": 10, "n_steps": 4, "seg_max": _IR_SEG,
+                 "backend": backend},
             )
 
         return make
@@ -1553,7 +1592,8 @@ def _ir_cases_mw_carry_init():
 
     def make():
         _, owner, demands, inv_cap, _ = _ir_seq_args()
-        return (np.ones(_IR_P, np.float32), owner, inv_cap, demands), {}
+        return ((np.ones(_IR_P, np.float32), owner, inv_cap, demands),
+                {"seg_max": _IR_SEG})
 
     return [AuditCase(label="seq", make=make)]
 
@@ -1563,8 +1603,9 @@ def _ir_cases_mw_carry_init_batch():
     import numpy as np
 
     def make():
-        _, owner2, dem2, inv2, _, _, _, _, _ = _ir_batch_args()
-        return (np.ones((_IR_B, _IR_P), np.float32), owner2, inv2, dem2), {}
+        _, owner2, dem2, inv2, _, _, _, _ = _ir_batch_args()
+        return ((np.ones((_IR_B, _IR_P), np.float32), owner2, inv2, dem2),
+                {"seg_max": _IR_SEG})
 
     return [AuditCase(label="batch", make=make)]
 
@@ -1575,12 +1616,12 @@ def _ir_cases_mw_window_batch():
 
     def mk(backend, with_gather):
         def make():
-            (pe3, owner2, dem2, inv2, sval2, slot_gather, owner_gather,
-             carry_b, active) = _ir_batch_args()
-            kw = {"iters_total": 10, "n_steps": 4, "backend": backend}
+            (pe3, owner2, dem2, inv2, sval2, slot_gather, carry_b,
+             active) = _ir_batch_args()
+            kw = {"iters_total": 10, "n_steps": 4, "seg_max": _IR_SEG,
+                  "backend": backend}
             if with_gather:
                 kw["slot_gather"] = jnp.asarray(slot_gather)
-                kw["owner_gather"] = jnp.asarray(owner_gather)
             return (
                 (pe3, owner2, dem2, inv2, sval2, carry_b, np.int32(0),
                  np.int32(4), active),
@@ -1606,7 +1647,7 @@ def _ir_cases_mw_final_batch():
     from ..analysis.registry import AuditCase
 
     def make():
-        (pe3, owner2, dem2, inv2, _, slot_gather, _, carry_b, _) = _ir_batch_args()
+        (pe3, owner2, dem2, inv2, _, slot_gather, carry_b, _) = _ir_batch_args()
         return (
             (pe3, owner2, dem2, inv2, carry_b),
             {"backend": "gather", "slot_gather": jnp.asarray(slot_gather)},

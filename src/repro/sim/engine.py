@@ -891,7 +891,7 @@ def _ir_cases_waterfill():
 
     def mk(backend, with_gather):
         def make():
-            (pe3, _, _, inv2, sval2, slot_gather, _, _, _) = _ir_batch_args()
+            (pe3, _, _, inv2, sval2, slot_gather, _, _) = _ir_batch_args()
             B, P = pe3.shape[0], pe3.shape[1]
             nflow = np.ones((B, P), np.float32)
             cap = np.ones_like(inv2)
@@ -913,7 +913,7 @@ def _ir_cases_sim_scan():
     from ..core.flow import _ir_batch_args
 
     def make():
-        (pe3, owner2, _, inv2, sval2, slot_gather, _, _, _) = _ir_batch_args()
+        (pe3, owner2, _, inv2, sval2, slot_gather, _, _) = _ir_batch_args()
         B, P = pe3.shape[0], pe3.shape[1]
         S = inv2.shape[-1]
         K = int(owner2.max()) + 1

@@ -15,6 +15,7 @@ Three layers, three test groups:
 """
 
 import dataclasses
+import functools
 import os
 import pathlib
 import subprocess
@@ -29,6 +30,7 @@ from repro.analysis import (
     RULES,
     check_path_system,
     check_path_system_batch,
+    check_segment_layout,
     check_sim_state,
     lint_paths,
     lint_source,
@@ -375,6 +377,59 @@ def test_contract_batch_padded_row_owner(checks_on):
     bad = dataclasses.replace(batch, path_owner=owner)
     with pytest.raises(ContractViolation, match="padded row"):
         check_path_system_batch(bad)
+
+
+def _layout_batch():
+    systems = []
+    for s in range(2):
+        top = jellyfish(20 + 8 * s, 8, 4, seed=s)
+        comm = random_permutation_traffic(top, seed=s + 7)
+        systems.append(build_path_system(top, comm, k=4))
+    return PathSystemBatch.from_systems(systems)
+
+
+def test_contract_batch_segment_layout_holds(checks_on):
+    """The canonical layout the split normalisation sums over holds for
+    built batches, stacked and shared, on every instance."""
+    batch = _layout_batch()
+    check_path_system_batch(batch, max_instances=0)
+    check_segment_layout(batch)
+    ps = batch.systems[0]
+    shared = PathSystemBatch.from_shared(ps, np.ones((3, ps.n_commodities)))
+    check_path_system_batch(shared, max_instances=0)
+    check_segment_layout(shared)
+
+
+@pytest.mark.parametrize("corrupt, match", [
+    ("owner_decreases", "grouped by commodity"),
+    ("shared_owner_decreases", "grouped by commodity"),
+    ("dummy_inside", "must not belong to the dummy"),
+    ("real_in_tail", "padded row"),
+])
+def test_contract_batch_segment_layout_fires(checks_on, corrupt, match):
+    """A row layout that ``_seg_norm`` would sum wrongly is refused, past
+    ``max_instances`` (no per-instance comparison) too."""
+    batch = _layout_batch()
+    owner = batch.path_owner.copy()
+    n1 = int(batch.n_paths[1])
+    check = functools.partial(check_path_system_batch, max_instances=0)
+    if corrupt == "shared_owner_decreases":
+        ps = batch.systems[0]
+        batch = PathSystemBatch.from_shared(ps, np.ones((2, ps.n_commodities)))
+        owner = batch.path_owner.copy()
+        owner[[0, -1]] = owner[[-1, 0]]
+        check = check_segment_layout
+    elif corrupt == "owner_decreases":
+        first = int(np.argmax(owner[1] != owner[1, 0]))
+        owner[1, [0, first]] = owner[1, [first, 0]]
+        check = check_segment_layout
+    elif corrupt == "dummy_inside":
+        owner[1, n1 - 1] = batch.demands.shape[1] - 1
+    else:
+        owner[1, n1] = owner[1, n1 - 1]
+    bad = dataclasses.replace(batch, path_owner=owner)
+    with pytest.raises(ContractViolation, match=match):
+        check(bad)
 
 
 def test_contract_sim_result_fires(checks_on):

@@ -170,13 +170,139 @@ def test_batch_result_independent_of_composition():
 def test_pathsystembatch_gather_tables_cover_real_hops():
     systems = _systems((24, 40))
     batch = PathSystemBatch.from_systems(systems)
-    assert batch.slot_gather is not None and batch.owner_gather is not None
+    assert batch.slot_gather is not None
     B, S, D = batch.slot_gather.shape
     P, L = batch.path_edges.shape[1:]
     for i, ps in enumerate(systems):
         real = int((batch.slot_gather[i] < P * L).sum())
         hops = int(ps.path_len.sum())
         assert real == hops  # every real hop appears exactly once
+    # the shared path's one table, over its one (P, L) path table
+    ps = systems[1]
+    shared = PathSystemBatch.from_shared(ps, np.ones((2, ps.n_commodities)))
+    assert shared.slot_gather.ndim == 2
+    real = int((shared.slot_gather < shared.path_edges.size).sum())
+    assert real == int(ps.path_len.sum())
+
+
+# --------------------------------------------------------------------------- #
+# split normalisation over contiguous commodity runs
+# --------------------------------------------------------------------------- #
+
+
+def _owner_rows(runs, tail, dummy):
+    """Canonical owner row: commodity k repeated runs[k] times, then a
+    padding tail of the dummy commodity."""
+    return np.concatenate([np.repeat(np.arange(len(runs)), runs),
+                           np.full(tail, dummy)]).astype(np.int32)
+
+
+def _seg_case(name):
+    rng = np.random.default_rng(11)
+    if name == "ragged":  # runs of 1..8 rows, a 13-row dummy tail
+        runs = rng.permutation(np.repeat(np.arange(1, 9), 3))
+        owner = _owner_rows(runs, 13, len(runs))[None]
+        return owner, len(runs) + 1
+    if name == "mixed_seg_max":  # instances whose longest runs differ
+        K, P = 9, 80
+        rows = []
+        for top in (1, 5, 8):
+            runs = rng.integers(1, top + 1, size=K)
+            runs[0] = top
+            rows.append(_owner_rows(runs, P - runs.sum(), K))
+        return np.stack(rows), K + 1
+    if name == "shared":  # one owner row for every instance, no dummy
+        runs = rng.integers(1, 8, size=10)
+        return _owner_rows(runs, 0, 0), len(runs)
+    raise ValueError(name)
+
+
+@pytest.mark.parametrize("case", ["ragged", "mixed_seg_max", "shared"])
+def test_seg_norm_bit_exact_to_scatter_add(case):
+    """The shifted-add normalisation equals the XLA:CPU scatter-add one
+    bit for bit on every real row; the dummy tail (longer than seg_max)
+    keeps its divisor 1."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core import flow
+
+    owner, n_comm = _seg_case(case)
+    Bt = 3 if owner.ndim == 1 else owner.shape[0]
+    P = owner.shape[-1]
+    rng = np.random.default_rng(5)
+    x = (rng.random((Bt, P)) * np.exp(rng.normal(0, 4, (Bt, P)))).astype(
+        np.float32) + np.float32(1e-3)
+    own2 = np.broadcast_to(owner, (Bt, P))
+    seg_max = max(flow._seg_passes(o[o < n_comm - (owner.ndim == 2)])
+                  for o in own2)
+
+    @jax.jit
+    def new(x, owner):
+        pos, run = flow._batch_seg_layout(owner, n_comm, seg_max)
+        return flow._seg_norm(x, pos, run, seg_max)
+
+    @jax.jit
+    def scatter(x, owner):
+        owner = jnp.broadcast_to(owner, x.shape)
+        s = jnp.zeros((Bt, n_comm), jnp.float32).at[
+            jnp.arange(Bt)[:, None], owner].add(x)
+        return x / jnp.take_along_axis(s, owner, axis=1)
+
+    got = np.asarray(new(x, owner))
+    ref = np.asarray(scatter(x, owner))
+    real = own2 != n_comm - 1 if owner.ndim == 2 else np.ones_like(own2, bool)
+    np.testing.assert_array_equal(got[real], ref[real])
+    np.testing.assert_array_equal(got[~real], x[~real])
+
+
+def test_seg_norm_sequential_bit_exact_to_scatter_add():
+    """The sequential solver's (P,) rank: same helper, same bits."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core import flow
+
+    owner, K = _seg_case("shared")
+    x = np.random.default_rng(2).random(owner.shape).astype(np.float32)
+    seg_max = flow._seg_passes(owner)
+    got = jax.jit(lambda x: flow._seg_norm(
+        x, *flow._seg_layout(owner, seg_max), seg_max))(x)
+    s = jnp.zeros((K,), jnp.float32).at[owner].add(x)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(x / s[owner]))
+
+
+def _runs_system(runs):
+    """A hand-built canonical system: one single-hop path per row."""
+    P, E = int(sum(runs)), 8
+    return PathSystem(
+        n_edges=E,
+        path_edges=(np.arange(P) % (2 * E)).astype(np.int32)[:, None],
+        path_len=np.ones(P, np.int32),
+        path_owner=np.repeat(np.arange(len(runs)), runs).astype(np.int32),
+        demands=np.ones(len(runs), np.float32),
+        capacities=np.ones(2 * E, np.float32),
+        n_commodities=len(runs),
+    )
+
+
+def test_window_compile_shared_within_seg_max_bucket():
+    """seg_max is read from the batch and bucketed: longest runs of 5 and 7
+    rows share one compiled window, 9 rows compiles anew."""
+    from repro.core import flow
+
+    a, b, c = (_runs_system(r) for r in ([1, 5, 2, 3], [7, 1, 3, 2],
+                                          [9, 1, 2, 1]))
+    assert [PathSystemBatch.from_systems([s]).seg_max
+            for s in (a, b, c)] == [8, 8, 12]
+    mw_concurrent_flow_batch([a], iters=20, backend="scatter")
+    base = flow._mw_window_batch._cache_size()
+    res = mw_concurrent_flow_batch([b], iters=20, backend="scatter")
+    assert flow._mw_window_batch._cache_size() == base
+    seq = mw_concurrent_flow(b, iters=20, backend="scatter")
+    assert res[0].alpha == seq.alpha
+    mw_concurrent_flow_batch([c], iters=20, backend="scatter")
+    assert flow._mw_window_batch._cache_size() == base + 1
 
 
 # --------------------------------------------------------------------------- #

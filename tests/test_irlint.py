@@ -367,7 +367,7 @@ _GOLDEN = {
 
 
 def _census_congestion(backend):
-    pe3, _, _, inv2, _, slot_gather, _, _, _ = flow._ir_batch_args()
+    pe3, _, _, inv2, _, slot_gather, _, _ = flow._ir_batch_args()
     B, P, _ = pe3.shape
     S = inv2.shape[1]
     kw = {}
@@ -397,7 +397,7 @@ def test_congestion_census_invariants():
 
 
 def test_path_cost_gather_census_stable():
-    pe3, _, _, inv2, _, _, _, _, _ = flow._ir_batch_args()
+    pe3, _, _, inv2, _, _, _, _ = flow._ir_batch_args()
     B = pe3.shape[0]
     S = inv2.shape[1]
     pr_pad = np.ones((B, S + 1), np.float32)

@@ -424,7 +424,7 @@ def test_route_build_traced_byte_identical():
         obs.set_trace(prev)
         obs.reset_trace()
     for name in ("path_edges", "path_owner", "demands", "inv_cap",
-                 "slot_valid", "slot_gather", "owner_gather"):
+                 "slot_valid", "slot_gather"):
         a, b = getattr(base, name), getattr(traced, name)
         assert a.dtype == b.dtype and a.tobytes() == b.tobytes(), name
     for a, b in zip(base.systems, traced.systems):
@@ -450,8 +450,9 @@ def test_mw_batch_phases_are_spans(traced):
     assert by["mw/upload"].attrs["bytes"] > sum(
         ps.path_edges.nbytes for ps in systems)
     # three instances, bucketed to a batch of four
+    # seg_max: the split normalisation's passes, k = 4 paths bucketed to 4
     assert by["mw/window_batch"].attrs == {"t0": 0, "step": 30, "active": 3,
-                                           "instances": 4}
+                                           "instances": 4, "seg_max": 4}
 
 
 def test_sim_reroute_phases_are_spans(traced):

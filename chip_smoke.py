@@ -287,7 +287,7 @@ def phase_reference(sz: dict, kernel_check, state: dict) -> dict:
           f"MW alpha {mw.alpha} above the LP optimum {lp.alpha}")
     check(mw.alpha >= 0.98 * lp.alpha,
           f"MW alpha {mw.alpha} more than 2% below the LP {lp.alpha}")
-    if mw.method == "mw-dense" and _on_tpu():
+    if mw.method == "mw-batch-dense" and _on_tpu():
         shape = (ps.n_paths, ps.capacities.shape[0])
         kernel_check(congestion_pallas, shape, shape[:1], shape[1:])
     return {"servers": int(servers.sum()), "switches": eq["switches"],

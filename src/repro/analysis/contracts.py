@@ -9,7 +9,7 @@ canonical (length, lexicographic) tie order that makes delta updates
 bit-identical to rebuilds, and the int16 ``INT16_INF`` distance sentinel.
 This module checks them *at the boundaries where the structures are made*
 — ``build_path_system`` / ``update_path_system`` /
-``PathSystemBatch.from_systems`` / ``from_shared`` / ``sim.simulate``, and
+``PathSystemBatch.from_systems`` / ``sim.simulate``, and
 the MW batch solver's row layout —
 behind ``REPRO_CHECK=1`` (see ``repro.env``; the tier-1 test suite turns
 it on by default via ``conftest.py``).
@@ -315,7 +315,6 @@ def check_path_system_batch(batch, *, name: str = "path_system_batch",
     inv = np.asarray(batch.inv_cap)
     sval = np.asarray(batch.slot_valid)
     n_paths = np.asarray(batch.n_paths)
-    stacked = not batch.shared
 
     if np.any(inv[~sval] != 0.0):
         idx = tuple(map(int, np.argwhere((inv != 0.0) & ~sval)[0]))
@@ -328,104 +327,82 @@ def check_path_system_batch(batch, *, name: str = "path_system_batch",
         _fail(name, f"valid slot {idx} must have finite positive inv_cap; "
                     f"got {inv[idx]}")
 
-    if stacked:
-        if pe.ndim != 3 or owner.ndim != 2:
-            _fail(name, f"stacked batch needs rank-3 path_edges / rank-2 "
-                        f"path_owner; got {pe.shape} / {owner.shape}")
-        B, P, L = pe.shape
-        K = dem.shape[1] - 1
-        if np.any(dem[:, K] != 0.0):
-            i = int(np.argmax(dem[:, K] != 0.0))
-            _fail(name, f"dummy commodity column must be zero-demand; "
-                        f"demands[{i}, {K}]={dem[i, K]}")
-        if np.any(owner < 0) or np.any(owner > K):
-            i, p = map(int, np.argwhere((owner < 0) | (owner > K))[0])
-            _fail(name, f"path_owner[{i}, {p}]={owner[i, p]} outside "
-                        f"[0, dummy={K}]")
-        if np.any(n_paths < 0) or np.any(n_paths > P):
-            i = int(np.argmax((n_paths < 0) | (n_paths > P)))
-            _fail(name, f"n_paths[{i}]={n_paths[i]} outside [0, P={P}]")
-        # every instance: the dummy commodity owns exactly the padding tail
-        tail = np.arange(P)[None, :] >= n_paths[:, None]
-        if np.any((owner == K) != tail):
-            i, p = map(int, np.argwhere((owner == K) != tail)[0])
-            if tail[i, p]:
-                _fail(name, f"padded row {p} of instance {i} must belong to "
-                            f"the dummy commodity {K}; path_owner[{i}, {p}]="
-                            f"{owner[i, p]}")
-            _fail(name, f"real row {p} of instance {i} (n_paths="
-                        f"{n_paths[i]}) must not belong to the dummy "
-                        f"commodity {K}")
-        for i, ps in enumerate(batch.systems[:max_instances]):
-            Si = ps.n_slots
-            if not (np.all(sval[i, :Si]) and not np.any(sval[i, Si:])):
-                _fail(name, f"slot_valid[{i}] must mask exactly the first "
-                            f"n_slots={Si} slots")
-            if Si and not np.array_equal(
-                inv[i, :Si], (1.0 / np.asarray(ps.capacities,
-                                               np.float32)).astype(np.float32)
-            ):
-                _fail(name, f"inv_cap[{i}] does not equal 1/capacities of "
-                            f"source system {i}")
-            pb = ps.n_paths
-            if int(n_paths[i]) != pb:
-                _fail(name, f"n_paths[{i}]={int(n_paths[i])} but source "
-                            f"system has {pb} paths")
-            if np.any(pe[i, pb:, :] != Si):
-                p, j = map(int, np.argwhere(pe[i, pb:, :] != Si)[0])
-                _fail(name, f"padded row {pb + p} of instance {i} must hold "
-                            f"the instance sentinel n_slots={Si}; "
-                            f"path_edges[{i}, {pb + p}, {j}]="
-                            f"{pe[i, pb + p, j]}")
-            if pb:
-                sb = np.asarray(ps.path_edges)
-                lb = sb.shape[1]
-                if not np.array_equal(pe[i, :pb, :lb], sb):
-                    _fail(name, f"instance {i} path_edges differ from its "
-                                "source system")
-                if np.any(pe[i, :pb, lb:] != Si):
-                    _fail(name, f"instance {i} rows must pad columns beyond "
-                                f"L={lb} with the sentinel {Si}")
-                if not np.array_equal(owner[i, :pb],
-                                      np.asarray(ps.path_owner)):
-                    _fail(name, f"instance {i} path_owner differs from its "
-                                "source system")
-            ki = ps.n_commodities
-            if not np.array_equal(dem[i, :ki],
-                                  np.asarray(ps.demands, np.float32)):
-                _fail(name, f"instance {i} demands differ from its source "
-                            "system")
-            if np.any(dem[i, ki:] != 0.0):
-                _fail(name, f"instance {i} demand columns beyond "
-                            f"n_commodities={ki} must be zero (padding "
-                            "commodities must not attract flow)")
-    else:
-        ps = batch.systems[0]
-        if pe.ndim != 2:
-            _fail(name, f"shared batch needs rank-2 path_edges; got "
-                        f"{pe.shape}")
-        P, L = pe.shape
-        if not np.array_equal(pe, np.asarray(ps.path_edges, np.int32)):
-            _fail(name, "shared path_edges differ from the source system")
-        if dem.ndim != 2 or dem.shape[1] != ps.n_commodities:
-            _fail(name, f"shared-batch demands must be "
-                        f"(B, {ps.n_commodities}); got {dem.shape}")
-        if np.any(~np.isfinite(dem)) or np.any(dem < 0):
-            i, k = map(int, np.argwhere(~np.isfinite(dem) | (dem < 0))[0])
-            _fail(name, f"demands[{i}, {k}]={dem[i, k]} must be finite and "
-                        ">= 0")
-        if np.any(n_paths != ps.n_paths):
-            _fail(name, "shared batch n_paths must all equal the source "
-                        f"system's {ps.n_paths}")
+    if pe.ndim != 3 or owner.ndim != 2:
+        _fail(name, f"batch needs rank-3 path_edges / rank-2 "
+                    f"path_owner; got {pe.shape} / {owner.shape}")
+    B, P, L = pe.shape
+    K = dem.shape[1] - 1
+    if np.any(dem[:, K] != 0.0):
+        i = int(np.argmax(dem[:, K] != 0.0))
+        _fail(name, f"dummy commodity column must be zero-demand; "
+                    f"demands[{i}, {K}]={dem[i, K]}")
+    if np.any(owner < 0) or np.any(owner > K):
+        i, p = map(int, np.argwhere((owner < 0) | (owner > K))[0])
+        _fail(name, f"path_owner[{i}, {p}]={owner[i, p]} outside "
+                    f"[0, dummy={K}]")
+    if np.any(n_paths < 0) or np.any(n_paths > P):
+        i = int(np.argmax((n_paths < 0) | (n_paths > P)))
+        _fail(name, f"n_paths[{i}]={n_paths[i]} outside [0, P={P}]")
+    # every instance: the dummy commodity owns exactly the padding tail
+    tail = np.arange(P)[None, :] >= n_paths[:, None]
+    if np.any((owner == K) != tail):
+        i, p = map(int, np.argwhere((owner == K) != tail)[0])
+        if tail[i, p]:
+            _fail(name, f"padded row {p} of instance {i} must belong to "
+                        f"the dummy commodity {K}; path_owner[{i}, {p}]="
+                        f"{owner[i, p]}")
+        _fail(name, f"real row {p} of instance {i} (n_paths="
+                    f"{n_paths[i]}) must not belong to the dummy "
+                    f"commodity {K}")
+    for i, ps in enumerate(batch.systems[:max_instances]):
+        Si = ps.n_slots
+        if not (np.all(sval[i, :Si]) and not np.any(sval[i, Si:])):
+            _fail(name, f"slot_valid[{i}] must mask exactly the first "
+                        f"n_slots={Si} slots")
+        if Si and not np.array_equal(
+            inv[i, :Si], (1.0 / np.asarray(ps.capacities,
+                                           np.float32)).astype(np.float32)
+        ):
+            _fail(name, f"inv_cap[{i}] does not equal 1/capacities of "
+                        f"source system {i}")
+        pb = ps.n_paths
+        if int(n_paths[i]) != pb:
+            _fail(name, f"n_paths[{i}]={int(n_paths[i])} but source "
+                        f"system has {pb} paths")
+        if np.any(pe[i, pb:, :] != Si):
+            p, j = map(int, np.argwhere(pe[i, pb:, :] != Si)[0])
+            _fail(name, f"padded row {pb + p} of instance {i} must hold "
+                        f"the instance sentinel n_slots={Si}; "
+                        f"path_edges[{i}, {pb + p}, {j}]="
+                        f"{pe[i, pb + p, j]}")
+        if pb:
+            sb = np.asarray(ps.path_edges)
+            lb = sb.shape[1]
+            if not np.array_equal(pe[i, :pb, :lb], sb):
+                _fail(name, f"instance {i} path_edges differ from its "
+                            "source system")
+            if np.any(pe[i, :pb, lb:] != Si):
+                _fail(name, f"instance {i} rows must pad columns beyond "
+                            f"L={lb} with the sentinel {Si}")
+            if not np.array_equal(owner[i, :pb],
+                                  np.asarray(ps.path_owner)):
+                _fail(name, f"instance {i} path_owner differs from its "
+                            "source system")
+        ki = ps.n_commodities
+        if not np.array_equal(dem[i, :ki],
+                              np.asarray(ps.demands, np.float32)):
+            _fail(name, f"instance {i} demands differ from its source "
+                        "system")
+        if np.any(dem[i, ki:] != 0.0):
+            _fail(name, f"instance {i} demand columns beyond "
+                        f"n_commodities={ki} must be zero (padding "
+                        "commodities must not attract flow)")
 
     # gather fan-in table: every non-sentinel pointer must point back at a
     # hop of the slot it is indexed under
     if batch.slot_gather is not None:
-        tab = np.asarray(batch.slot_gather)
-        flat = (pe.reshape(pe.shape[0], -1) if stacked
-                else np.broadcast_to(pe.reshape(-1)[None],
-                                     (1, pe.size)))
-        tabs = tab if stacked else tab[None]
+        tabs = np.asarray(batch.slot_gather)
+        flat = pe.reshape(pe.shape[0], -1)
         PL = flat.shape[1]
         if np.any(tabs < 0) or np.any(tabs > PL):
             idx = tuple(map(int, np.argwhere((tabs < 0) | (tabs > PL))[0]))
@@ -452,8 +429,7 @@ def check_segment_layout(batch, *, name: str = "mw_batch") -> None:
     row order.
     """
     name = f"segment_layout[{name}]"
-    owner = np.asarray(batch.path_owner)
-    own = owner if owner.ndim == 2 else owner[None]
+    own = np.asarray(batch.path_owner)
     if np.any(np.diff(own, axis=1) < 0):
         i, p = map(int, np.argwhere(np.diff(own, axis=1) < 0)[0])
         _fail(name, f"instance {i} path rows must be grouped by commodity "

@@ -6,9 +6,9 @@ Before this module, ``retrace.py`` kept a hand-maintained 16-tuple of
 cache-size assertions and from any IR-level audit.  Now every module-level
 solver jit registers itself at definition site:
 
-    @solver_jit(spec="_ir_cases_mw_window")
+    @solver_jit(spec="_ir_cases_mw_window_batch")
     @functools.partial(jax.jit, static_argnames=(...))
-    def _mw_window(...): ...
+    def _mw_window_batch(...): ...
 
 and the registry is the single enumeration both consumers read:
 

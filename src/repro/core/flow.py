@@ -1,85 +1,76 @@
 """Maximum concurrent flow over a k-shortest-path system (paper §4).
 
 The paper computes "optimal routing" throughput with CPLEX on the exact
-multicommodity LP.  We provide two solvers over an explicit path system:
+multicommodity LP.  Over an explicit path system this module has the exact
+LP as its oracle and one multiplicative-weights (MW) solver for scale:
 
-* ``lp_concurrent_flow``   — exact LP (scipy/HiGHS), the oracle.  Restricted to
-  the path system, but with enough paths (k >= 8 and slack >= 2 on these
+* ``lp_concurrent_flow`` — exact LP (scipy/HiGHS).  Restricted to the path
+  system, but with enough paths (k >= 8 and slack >= 2 on these
   low-diameter graphs) it matches the edge-formulation optimum to <2%
   (validated in tests on small instances against an edge-based LP).
-* ``mw_concurrent_flow``   — jitted JAX mirror-descent / multiplicative-weights
-  iteration minimizing the smoothed max edge load.  This is the TPU-shaped
-  solver: its inner loop is exactly the fused gather/segment-sum
-  ("congestion") primitive implemented by ``repro.kernels.congestion``.
-
-Congestion backends
--------------------
-Each MW iteration needs the two incidence products ``loads = B^T r`` and
-``costs = B w`` (B the {0,1} path x directed-slot incidence).  Two
-interchangeable inner-loop backends compute them:
-
-* ``scatter`` — segment-sum / gather on the padded ``path_edges`` table; no
-  materialized B.  The CPU production path, and the only option when B is too
-  large to materialize.
-* ``dense``   — materializes B once and calls ``repro.kernels.ops.congestion``
-  (the fused Pallas kernel on TPU, reading each B tile from HBM once per
-  iteration; the jnp reference elsewhere).  ``backend="pallas"`` forces the
-  kernel (interpret mode off-TPU) for validation.
-
-``backend="auto"`` picks via ``repro.kernels.ops.preferred_congestion_backend``
-(problem size + platform).  To let the fused kernel compute both products in
-a single pass over B, the iteration uses softmax weights derived from the
-*previous* iterate's edge loads (a one-step price lag — the standard Jacobi
-pipelining); both backends implement the identical lagged recurrence, so they
-agree on alpha to float tolerance, and the per-iterate alpha bookkeeping uses
-exact current loads either way.
+* ``mw_concurrent_flow_batch`` — jitted JAX mirror-descent / MW iteration
+  minimizing the smoothed max edge load, over a batch of instances in one
+  window scan.  ``mw_concurrent_flow`` is its batch of one.
 
 Maximum concurrent flow: maximize alpha s.t. each commodity i routes
 ``alpha * d_i`` and edge loads respect capacities.  For the capacity question
 "does this topology support every server at full rate" the test is alpha >= 1.
 
-Batched solves
---------------
+The MW solver
+-------------
 Every headline sweep (the Fig 1c bisection, capacity-vs-size curves, Fig 7
-failure stages) solves MANY independent MW instances, and a single-instance
-solver leaves the device mostly idle while the driver loops in Python —
-worse, every instance has its own (P, S) shapes, so each sequential solve
-retraces and recompiles the window scan.  ``PathSystemBatch`` pads B path
-systems to a common (P_max, L_max, S_max, K_max) envelope with per-instance
-validity masks (padded slots carry infinite capacity and are masked out of
-the softmax; padded path rows belong to a zero-demand dummy commodity), and
-``mw_concurrent_flow_batch`` runs ONE batched window scan over the stack:
+failure stages) solves many independent instances, each with its own
+(P, S) shapes.  ``PathSystemBatch`` pads B path systems to a common,
+bucketed (P_max, L_max, S_max, K_max) envelope with per-instance validity
+masks (padded slots carry infinite capacity and are masked out of the
+softmax; padded path rows belong to a zero-demand dummy commodity), and
+``mw_concurrent_flow_batch`` runs ONE window scan over the stack:
 
 * per-instance adaptive state — plateau / ``target_alpha`` early-stop is
   tracked per instance on the host, and a converged instance's carry is
-  frozen bit-exactly (masked updates) while stragglers run on, so each
-  instance reports exactly the iteration count its sequential solve would;
-* a shared-topology fast path (``PathSystemBatch.from_shared``) keeps one
-  (P, L) path table and varies only demands, for sweeps over traffic
-  matrices on a fixed routing;
-* the congestion inner loop goes through ``make_congestion_fn_batch``:
-  a flat segment-sum with per-instance slot offsets (scatter), a stacked
-  rank-3 incidence through ``ops.congestion`` (one fused-kernel pass per
-  batch member per iteration on TPU), or — the CPU default for batches —
-  ``gather``: transposed fan-in tables precomputed at batch build time
-  (for every slot, the flat positions of the path hops crossing it),
-  which turn the XLA scatter-add that dominates the scatter backend's
-  iteration (~5 ms at RRG(512), serialized element loop) into vectorized
-  gather+sum (~0.13 ms measured).  The tables are why batched solves are
-  several times faster than the same instances solved sequentially on
-  CPU, not just less dispatch overhead;
+  frozen bit-exactly (masked updates) while stragglers run on;
 * every backend normalises each commodity's split over its contiguous
   run of path rows (``_seg_norm``): at most ``seg_max`` dense shifted
   adds and selects, no scatter or gather.
 
-Per-instance results match ``mw_concurrent_flow`` to float tolerance —
-BIT-exactly (alpha diff 0.0, identical adaptive iteration counts) against
-the sequential ``scatter`` backend, whose accumulation order the gather
-tables reproduce; small CPU instances default the sequential solver to
-``dense``, where reassociation-level drift (~1e-4 after the anneal) is
-expected.  The Fig 1c capacity search (``core.capacity``, whose
-``probe_wave`` batches a speculative bisection wave) and the benchmark
-sweep drivers (``benchmarks.common.batch_alphas``) sit on top.
+An instance's result does not depend on the batch it is solved in —
+B = 1, other instances, or bucket padding: padding is masked, and every
+sum keeps an association the envelope cannot change (``_fold_sum``,
+``_ordered_fan_in_sum``, ``_seg_norm``).  The Fig 1c capacity search
+(``core.capacity``, whose ``probe_wave`` batches a speculative bisection
+wave) and the benchmark sweeps (``benchmarks.common.batch_alphas``) sit
+on top.
+
+Congestion backends
+-------------------
+Each MW iteration needs the two incidence products ``loads = B^T r`` and
+``costs = B w`` (B the {0,1} path x directed-slot incidence).
+``make_loads_fn_batch`` writes each backend's loads half once;
+``make_congestion_fn_batch`` adds the costs half:
+
+* ``scatter`` — one flat segment-sum over ``Bt * (S + 1)`` slots with
+  per-instance slot offsets; no materialized B.
+* ``gather`` — the CPU default: transposed fan-in tables precomputed at
+  batch build time (for every slot, the flat positions of the path hops
+  crossing it) turn the XLA scatter-add, a serialized element loop on
+  CPU, into vectorized gathers.  Each slot's fan-in is accumulated in the
+  scatter-add's order, so the two backends agree bit for bit.
+* ``dense`` — materializes the stacked (Bt, P, S) incidence once and
+  calls ``repro.kernels.ops.congestion`` (the fused Pallas kernel on TPU,
+  reading each B tile from HBM once per iteration for both products; the
+  jnp reference elsewhere, whose matmuls reassociate: ~1e-4 alpha drift
+  after the anneal).  ``backend="pallas"`` forces the kernel (interpret
+  mode off-TPU) for validation.
+
+``PathSystemBatch.congestion_backend`` is the one place a backend is
+decided: ``auto`` takes ``ops.preferred_congestion_backend``'s batch policy
+(gather on CPU; dense or scatter by size on TPU), and ``gather`` falls
+back to ``scatter`` when the batch carries no fan-in tables.  To let the
+fused kernel compute both products in a single pass over B, the iteration
+uses softmax weights derived from the *previous* iterate's edge loads (a
+one-step price lag — the standard Jacobi pipelining); every backend
+implements the identical lagged recurrence, and the per-iterate alpha
+bookkeeping uses exact current loads.
 
 ``REPRO_LP_PATH_LIMIT`` (validated at import) moves the ``throughput()``
 LP-vs-MW cutoff from its 20000-path default.
@@ -155,9 +146,9 @@ def _fold_sum(x: jnp.ndarray) -> jnp.ndarray:
     MW anneal amplifies single-ulp differences into visible alpha drift.
     A positional halving tree is PADDING-INVARIANT: pad to a power of two
     and fold, and any all-zero half merges as an exact identity, so the
-    grouping of the real elements depends only on their positions.  Both
-    the sequential and the batched solver sum through this, which is what
-    keeps ragged/bucketed batches bit-identical to sequential solves.
+    grouping of the real elements depends only on their positions.  This
+    is what keeps an instance's result independent of the (ragged,
+    bucketed) envelope of the batch it is solved in.
     """
     n = x.shape[-1]
     if n == 0:
@@ -175,32 +166,27 @@ def _fold_sum(x: jnp.ndarray) -> jnp.ndarray:
 def _path_cost_gather(pr_pad: jnp.ndarray, path_edges: jnp.ndarray) -> jnp.ndarray:
     """Per-path price sums: L narrow hop-column gathers, halved positionally.
 
-    The obvious composite — one wide ``(Bt, P*L)`` take_along_axis (or the
-    ``pr_pad[:, path_edges]`` fancy-index for a shared table) reshaped back
-    and reduced — materializes the (Bt, P, L) intermediate and pays XLA:CPU's
-    wide-gather path; L narrow per-hop-column ``(Bt, P)`` gathers stay on
-    the vectorized row-gather path (the ``sim.engine._path_min_gather``
-    gotcha; see ROADMAP).  Min accumulates exactly in any order, but the sum
-    must keep ``_fold_sum``'s padding-invariant association — so instead of
-    stacking the columns (which re-materializes the rank-3 intermediate and
-    forfeits the win) the halving tree runs over the column LIST: zero-pad
-    to a power of two and combine ``cols[i] + cols[i+h]``.  Per element
-    that is the identical grouping ``_fold_sum`` applies along the stacked
-    axis, so the restructure is bit-exact — 3-10x faster than the wide
-    gather at solver shapes (``path_cost_gather`` row in kernels_bench).
+    The obvious composite — one wide ``(Bt, P*L)`` take_along_axis
+    reshaped back and reduced — materializes the (Bt, P, L) intermediate
+    and pays XLA:CPU's wide-gather path; L narrow per-hop-column
+    ``(Bt, P)`` gathers stay on the vectorized row-gather path (the
+    ``sim.engine._path_min_gather`` gotcha; see ROADMAP).  Min accumulates
+    exactly in any order, but the sum must keep ``_fold_sum``'s
+    padding-invariant association — so instead of stacking the columns
+    (which re-materializes the rank-3 intermediate and forfeits the win)
+    the halving tree runs over the column LIST: zero-pad to a power of two
+    and combine ``cols[i] + cols[i+h]``.  Per element that is the
+    identical grouping ``_fold_sum`` applies along the stacked axis, so the
+    restructure is bit-exact — 3-10x faster than the wide gather at solver
+    shapes (``path_cost_gather`` row in kernels_bench).
     """
-    Bt = pr_pad.shape[0]
-    shared = path_edges.ndim == 2
-    P, L = path_edges.shape[-2], path_edges.shape[-1]
+    Bt, P, L = path_edges.shape
     if L == 0:
         return jnp.zeros((Bt, P), pr_pad.dtype)
-    if shared:
-        cols = [pr_pad[:, path_edges[:, j]] for j in range(L)]
-    else:
-        cols = [
-            jnp.take_along_axis(pr_pad, path_edges[:, :, j], axis=1)
-            for j in range(L)
-        ]
+    cols = [
+        jnp.take_along_axis(pr_pad, path_edges[:, :, j], axis=1)
+        for j in range(L)
+    ]
     pow2 = 1 << (L - 1).bit_length() if L > 1 else 1
     if pow2 != L:
         zero = jnp.zeros((Bt, P), pr_pad.dtype)
@@ -227,66 +213,27 @@ def dense_incidence(path_edges: jnp.ndarray, n_slots: int) -> jnp.ndarray:
     return b[:, :n_slots]
 
 
-def make_congestion_fn(path_edges: jnp.ndarray, n_slots: int, backend: str):
-    """Fused (loads, costs) = (B^T r, B w) closure for the chosen backend.
-
-    Trace-time helper for the jitted solvers: ``scatter`` uses segment sums
-    over the padded path-edge table, ``dense``/``pallas`` materialize B once
-    (hoisted out of the scan by jit) and go through ``ops.congestion``.
-    """
-    P, L = path_edges.shape
-    if backend == "scatter":
-
-        def fused(rates, prices):
-            flat = jnp.repeat(rates, L)
-            loads = (
-                jnp.zeros((n_slots + 1,), jnp.float32)
-                .at[path_edges.reshape(-1)]
-                .add(flat)[:n_slots]
-            )
-            pr_pad = jnp.concatenate([prices, jnp.zeros((1,), jnp.float32)])
-            costs = _fold_sum(pr_pad[path_edges])
-            return loads, costs
-
-        return fused
-
-    if backend not in ("dense", "pallas"):
-        raise ValueError(f"unknown congestion backend: {backend!r}")
-    b = dense_incidence(path_edges, n_slots)
-    kernel_backend = "pallas" if backend == "pallas" else "auto"
-
-    def fused(rates, prices):
-        return ops.congestion(b, rates, prices, backend=kernel_backend)
-
-    return fused
-
-
 def _ordered_fan_in_sum(fr: jnp.ndarray, table: jnp.ndarray) -> jnp.ndarray:
     """Sum ``fr`` entries selected by a fan-in table, LEFT-TO-RIGHT.
 
-    ``fr`` is (Bt, N + 1) with a trailing zero pad; ``table`` is (S, D)
-    (shared) or (Bt, S, D) of indices into N+1, each row listing one
-    segment's members in ascending position order, padded with N.  The D
-    columns are accumulated one by one — a trace-time unroll, D is ~tens —
+    ``fr`` is (Bt, N + 1) with a trailing zero pad; ``table`` is
+    (Bt, S, D) of indices into N+1, each row listing one segment's members
+    in ascending position order, padded with N.  The D columns are
+    accumulated one by one — a trace-time unroll, D is ~tens —
     so each segment's sum associates exactly like the XLA scatter-add it
     replaces (updates applied in position order).  A tree-reduction ``sum``
     here would differ by an ulp and the MW anneal amplifies that into
     visible alpha drift over hundreds of iterations.
     """
-    d = table.shape[-1]
-    Bt = fr.shape[0]
-    S = table.shape[-2]
+    Bt, S, d = table.shape
     acc = jnp.zeros((Bt, S), jnp.float32)
     for j in range(d):
-        if table.ndim == 2:
-            acc = acc + fr[:, table[:, j]]
-        else:
-            acc = acc + jnp.take_along_axis(fr, table[:, :, j], axis=1)
+        acc = acc + jnp.take_along_axis(fr, table[:, :, j], axis=1)
     return acc
 
 
 #: Skip the transposed gather tables when slot-fan-in skew would inflate
-#: them past this multiple of the hop count (the driver falls back to the
+#: them past this multiple of the hop count (the solve falls back to the
 #: scatter backend).  Random-graph path systems sit far below it: fan-in is
 #: within ~4x of the mean at RRG(512..8192).
 _GATHER_TABLE_GUARD = 16
@@ -365,70 +312,46 @@ def _seg_norm(x: jnp.ndarray, pos: jnp.ndarray, run: jnp.ndarray,
     return x / div
 
 
-def make_congestion_fn_batch(
+def make_loads_fn_batch(
     path_edges: jnp.ndarray,
     n_slots: int,
-    n_batch: int,
     backend: str,
     slot_gather: jnp.ndarray | None = None,
 ):
-    """Batched fused (loads, costs) closure over a stack of path systems.
+    """Batched loads-only closure: (Bt, P) rates -> (Bt, S) ``B^T r``.
 
-    ``path_edges`` is (Bt, P, L) — or (P, L) for the shared-topology fast
-    path, where all instances route over the same table and only rates and
-    prices vary.  The closure maps (Bt, P) rates and (Bt, S) prices to
-    (Bt, S) loads and (Bt, P) costs:
+    Each backend's load accumulation, written once.  The MW solver's fused
+    closure (``make_congestion_fn_batch``) adds the costs half to it; the
+    flow-level simulator's waterfilling (``repro.sim.engine``) uses it
+    alone, since it needs per-slot loads and flow counts but never path
+    costs.  ``path_edges`` is the (Bt, P, L) stack, padded with each
+    instance's own ``n_slots`` sentinel:
 
     * ``scatter`` — ONE flat segment-sum over ``Bt * (S + 1)`` slots using
-      per-instance slot offsets (instance b's slot e lands at ``b*(S+1)+e``,
-      its padding sentinel in b's private garbage slot), so the whole batch
-      is a single scatter-add per iteration rather than Bt separate ones.
-    * ``dense``/``pallas`` — materializes the stacked rank-3 (Bt, P, S)
-      incidence once (hoisted out of the scan by jit) and calls
-      ``ops.congestion`` on it: one fused-kernel tile pass per batch member
-      per iteration.
-    * ``gather`` — the CPU default for batches: per-slot transposed fan-in
-      tables (``slot_gather``, precomputed by ``PathSystemBatch``) turn the
-      load accumulation into vectorized gathers — ~40x faster than the
-      serialized XLA scatter-add on CPU at RRG(512) shapes.  Each slot's
-      fan-in is accumulated left-to-right in flat-position order
-      (``_ordered_fan_in_sum``), the same order the scatter-add applies its
-      updates, so the two backends agree BIT-EXACTLY and the MW iteration
-      (whose annealing softmax amplifies even 1-ulp load differences over
-      hundreds of steps) follows the identical trajectory.
-
-    Within an instance the accumulation order therefore always matches the
-    single-instance ``make_congestion_fn``, which is what keeps batched
-    solves at bit parity with sequential ones.
+      per-instance slot offsets (instance b's slot e lands at
+      ``b*(S+1)+e``, its padding sentinel in b's private garbage slot), so
+      the whole batch is a single scatter-add rather than Bt separate ones.
+    * ``gather`` — per-slot transposed fan-in tables (``slot_gather``,
+      precomputed by ``PathSystemBatch``) turn the accumulation into
+      vectorized gathers — ~40x faster than the serialized XLA scatter-add
+      on CPU at RRG(512) shapes.  Each slot's fan-in is accumulated
+      left-to-right in flat-position order (``_ordered_fan_in_sum``), the
+      order the scatter-add applies its updates, so the two backends agree
+      BIT-EXACTLY and the MW iteration (whose annealing softmax amplifies
+      even 1-ulp load differences over hundreds of steps) follows the
+      identical trajectory.
+    * ``dense``/``pallas`` — materializes the stacked (Bt, P, S) incidence
+      once (hoisted out of any scan by jit) and calls
+      ``ops.congestion_loads`` on it.
     """
-    shared = path_edges.ndim == 2
+    Bt, P, L = path_edges.shape
     if backend == "gather":
         if slot_gather is None:
             raise ValueError(
                 "gather backend needs the PathSystemBatch fan-in tables"
             )
-        if shared:
-            P, L = path_edges.shape
 
-            def fused(rates, prices):
-                fr = jnp.concatenate(
-                    [
-                        jnp.repeat(rates, L, axis=1),
-                        jnp.zeros((n_batch, 1), jnp.float32),
-                    ],
-                    axis=1,
-                )
-                loads = _ordered_fan_in_sum(fr, slot_gather)
-                pr_pad = jnp.concatenate(
-                    [prices, jnp.zeros((n_batch, 1), jnp.float32)], axis=1
-                )
-                costs = _path_cost_gather(pr_pad, path_edges)
-                return loads, costs
-
-            return fused
-        Bt, P, L = path_edges.shape
-
-        def fused(rates, prices):
+        def loads_fn(rates):
             fr = jnp.concatenate(
                 [
                     jnp.repeat(rates, L, axis=1),
@@ -436,129 +359,10 @@ def make_congestion_fn_batch(
                 ],
                 axis=1,
             )
-            loads = _ordered_fan_in_sum(fr, slot_gather)
-            pr_pad = jnp.concatenate(
-                [prices, jnp.zeros((Bt, 1), jnp.float32)], axis=1
-            )
-            costs = _path_cost_gather(pr_pad, path_edges)
-            return loads, costs
-
-        return fused
-    if backend == "scatter":
-        if shared:
-            P, L = path_edges.shape
-            flat = path_edges.reshape(-1)
-
-            def fused(rates, prices):
-                r = jnp.repeat(rates, L, axis=1)  # (Bt, P*L)
-                loads = (
-                    jnp.zeros((n_batch, n_slots + 1), jnp.float32)
-                    .at[:, flat]
-                    .add(r)[:, :n_slots]
-                )
-                pr_pad = jnp.concatenate(
-                    [prices, jnp.zeros((n_batch, 1), jnp.float32)], axis=1
-                )
-                costs = _path_cost_gather(pr_pad, path_edges)
-                return loads, costs
-
-            return fused
-
-        Bt, P, L = path_edges.shape
-        s1 = n_slots + 1
-        flat_idx = (
-            jnp.arange(Bt, dtype=jnp.int32)[:, None, None] * s1 + path_edges
-        ).reshape(-1)
-
-        def fused(rates, prices):
-            r = jnp.repeat(rates.reshape(-1), L)
-            loads = (
-                jnp.zeros((Bt * s1,), jnp.float32)
-                .at[flat_idx]
-                .add(r)
-                .reshape(Bt, s1)[:, :n_slots]
-            )
-            pr_pad = jnp.concatenate(
-                [prices, jnp.zeros((Bt, 1), jnp.float32)], axis=1
-            )
-            costs = _path_cost_gather(pr_pad, path_edges)
-            return loads, costs
-
-        return fused
-
-    if backend not in ("dense", "pallas"):
-        raise ValueError(f"unknown congestion backend: {backend!r}")
-    kernel_backend = "pallas" if backend == "pallas" else "auto"
-    if shared:
-        b = dense_incidence(path_edges, n_slots)  # (P, S)
-
-        def fused(rates, prices):
-            # shared incidence: two plain batched matmuls over one B
-            return rates @ b, prices @ b.T
-
-        return fused
-    b3 = jax.vmap(lambda pe: dense_incidence(pe, n_slots))(path_edges)
-
-    def fused(rates, prices):
-        return ops.congestion(b3, rates, prices, backend=kernel_backend)
-
-    return fused
-
-
-def make_loads_fn_batch(
-    path_edges: jnp.ndarray,
-    n_slots: int,
-    n_batch: int,
-    backend: str,
-    slot_gather: jnp.ndarray | None = None,
-):
-    """Loads-only ``B^T r`` batched closure — the congestion backends' load
-    half, for inner loops that never consume path costs.
-
-    The flow-level simulator's waterfilling (``repro.sim.engine``) needs
-    per-slot loads and flow counts but no ``B w`` product; routing it
-    through ``make_congestion_fn_batch`` would compute (and discard) the
-    costs gather every call — about half the iteration cost on the CPU
-    gather path.  Accumulation order per backend is identical to the fused
-    closure's loads half (``gather`` reproduces the scatter-add
-    association bit-exactly, see ``_ordered_fan_in_sum``); ``dense`` /
-    ``pallas`` go through ``ops.congestion`` unchanged — the fused kernel
-    reads each B tile once either way, so the costs half is free there.
-    """
-    shared = path_edges.ndim == 2
-    if backend == "gather":
-        if slot_gather is None:
-            raise ValueError(
-                "gather backend needs the PathSystemBatch fan-in tables"
-            )
-        L = path_edges.shape[-1]
-
-        def loads_fn(rates):
-            fr = jnp.concatenate(
-                [
-                    jnp.repeat(rates, L, axis=1),
-                    jnp.zeros((rates.shape[0], 1), jnp.float32),
-                ],
-                axis=1,
-            )
             return _ordered_fan_in_sum(fr, slot_gather)
 
         return loads_fn
     if backend == "scatter":
-        if shared:
-            P, L = path_edges.shape
-            flat = path_edges.reshape(-1)
-
-            def loads_fn(rates):
-                r = jnp.repeat(rates, L, axis=1)
-                return (
-                    jnp.zeros((n_batch, n_slots + 1), jnp.float32)
-                    .at[:, flat]
-                    .add(r)[:, :n_slots]
-                )
-
-            return loads_fn
-        Bt, P, L = path_edges.shape
         s1 = n_slots + 1
         flat_idx = (
             jnp.arange(Bt, dtype=jnp.int32)[:, None, None] * s1 + path_edges
@@ -574,19 +378,7 @@ def make_loads_fn_batch(
             )
 
         return loads_fn
-    if backend not in ("dense", "pallas"):
-        raise ValueError(f"unknown congestion backend: {backend!r}")
-    kernel_backend = "pallas" if backend == "pallas" else "auto"
-    if shared:
-        # one (P, S) incidence, batched rates: a plain matmul, exactly the
-        # loads half of the fused shared path
-        b = dense_incidence(path_edges, n_slots)
-
-        def loads_fn(rates):
-            return rates @ b
-
-        return loads_fn
-    b3 = jax.vmap(lambda pe: dense_incidence(pe, n_slots))(path_edges)
+    b3, kernel_backend = _stacked_incidence(path_edges, n_slots, backend)
 
     def loads_fn(rates):
         return ops.congestion_loads(b3, rates, backend=kernel_backend)
@@ -594,121 +386,48 @@ def make_loads_fn_batch(
     return loads_fn
 
 
-def _resolve_backend(
-    backend: str, n_paths: int, n_slots: int, n_batch: int = 1
-) -> str:
-    if backend == "auto":
-        return ops.preferred_congestion_backend(n_paths, n_slots, n_batch=n_batch)
-    return backend
+def _stacked_incidence(path_edges: jnp.ndarray, n_slots: int, backend: str):
+    """The (Bt, P, S) incidence and the ``ops`` kernel backend of the
+    ``dense`` (kernel on TPU, reference elsewhere) and ``pallas`` (kernel
+    everywhere) congestion backends."""
+    if backend not in ("dense", "pallas"):
+        raise ValueError(f"unknown congestion backend: {backend!r}")
+    b3 = jax.vmap(lambda pe: dense_incidence(pe, n_slots))(path_edges)
+    return b3, "pallas" if backend == "pallas" else "auto"
 
 
-# --------------------------------------------------------------------------- #
-# JAX multiplicative-weights solver
-# --------------------------------------------------------------------------- #
-
-
-@solver_jit(spec="_ir_cases_mw_window")
-@functools.partial(jax.jit, static_argnames=("iters_total", "n_steps",
-                                              "seg_max", "backend"))
-def _mw_window(
-    path_edges: jnp.ndarray,  # (P, L) int32 padded with S (= n_slots)
-    owner: jnp.ndarray,  # (P,) int32
-    demands: jnp.ndarray,  # (K,) f32
-    inv_cap: jnp.ndarray,  # (S,) f32  (1 / capacity per directed slot)
-    carry,  # (x, rel_prev, best_alpha, best_x) — see _mw_carry_init
-    t0,  # first global iteration index of this window (traced scalar)
-    valid_steps,  # traced scalar: steps that actually advance the iterate
-    iters_total: int,  # anneal horizon (the FULL budget, not the window)
-    n_steps: int,
-    seg_max: int,  # _seg_norm pass count (_seg_passes of owner)
-    backend: str = "scatter",
-):
-    """``n_steps`` MW iterations starting at global step ``t0``.
-
-    The temperature anneal is driven by the *global* step over the full
-    ``iters_total`` horizon, so chaining windows reproduces the single-scan
-    trajectory exactly — which is what lets ``mw_concurrent_flow`` check the
-    best-alpha plateau between windows (adaptive iteration count) without
-    perturbing the converged-run result.
-
-    ``valid_steps`` is TRACED: steps with ``t - t0 >= valid_steps`` pass the
-    carry through unchanged (masked no-ops).  The adaptive driver always
-    calls with the same static ``n_steps = check_every`` and pads a short
-    final window with no-ops, so one compilation serves the whole solve
-    instead of the last window tracing a fresh scan.
-    """
-    S = inv_cap.shape[0]
-    fused = make_congestion_fn(path_edges, S, backend)
-    pos, run = _seg_layout(owner, seg_max)
-
-    def body(carry, t):
-        x, rel_prev, best_alpha, best_x = carry
-        # softmax weights from the PREVIOUS iterate's loads (one-step lag) so
-        # the fused kernel computes this iterate's loads and the gradient's
-        # path costs in a single pass over B.  rel_prev = 0 at t = 0 gives
-        # uniform weights.
-        mx_prev = jnp.max(rel_prev)
-        # GEOMETRIC temperature anneal (0.2 -> 0.005 of max load) +
-        # 1/sqrt(t) step decay; the lagged recurrence measures ~0.98 of the
-        # LP optimum at 400 iterations on RRG(128,24,18)
-        # (benchmarks/kernels_bench.py mw_vs_lp_quality_128)
-        frac = 0.2 * (0.005 / 0.2) ** (t.astype(jnp.float32) / iters_total)
-        tau = jnp.maximum(mx_prev, 1e-12) * frac
-        w = _masked_softmax(rel_prev / tau)
-        rates = x * demands[owner]
-        loads, costs = fused(rates, w * inv_cap)
-        rel = loads * inv_cap  # relative load per directed slot (exact)
-        mx = jnp.max(rel)
-        alpha = 1.0 / jnp.maximum(mx, 1e-12)
-        live = t - t0 < valid_steps
-        take = live & (alpha > best_alpha)
-        best_alpha = jnp.where(take, alpha, best_alpha)
-        best_x = jnp.where(take, x, best_x)
-        g = costs * demands[owner]
-        g = g / jnp.maximum(jnp.max(g), 1e-12)
-        eta = 2.0 / jnp.sqrt(1.0 + t.astype(jnp.float32))
-        x_next = _seg_norm(x * jnp.exp(-eta * g), pos, run, seg_max)
-        x = jnp.where(live, x_next, x)
-        rel = jnp.where(live, rel, rel_prev)
-        return (x, rel, best_alpha, best_x), None
-
-    carry, _ = jax.lax.scan(body, carry, t0 + jnp.arange(n_steps))
-    return carry
-
-
-@solver_jit(spec="_ir_cases_mw_final")
-@functools.partial(jax.jit, static_argnames=("backend",))
-def _mw_final(
+def make_congestion_fn_batch(
     path_edges: jnp.ndarray,
-    owner: jnp.ndarray,
-    demands: jnp.ndarray,
-    inv_cap: jnp.ndarray,
-    carry,
-    backend: str = "scatter",
+    n_slots: int,
+    backend: str,
+    slot_gather: jnp.ndarray | None = None,
 ):
-    """One exact evaluation of the last iterate, then the best-iterate result."""
-    S = inv_cap.shape[0]
-    fused = make_congestion_fn(path_edges, S, backend)
-    x, _, best_alpha, best_x = carry
-    rates = x * demands[owner]
-    loads, _ = fused(rates, jnp.zeros((S,), jnp.float32))
-    mx = jnp.max(loads * inv_cap)
-    alpha = 1.0 / jnp.maximum(mx, 1e-12)
-    better = alpha > best_alpha
-    best_alpha = jnp.where(better, alpha, best_alpha)
-    best_x = jnp.where(better, x, best_x)
-    best_rates = best_x * demands[owner] * jnp.minimum(best_alpha, 1.0)
-    return best_alpha, best_rates, 1.0 / best_alpha
+    """Batched fused (loads, costs) = (B^T r, B w) closure: (Bt, P) rates
+    and (Bt, S) prices to (Bt, S) loads and (Bt, P) costs.
 
+    ``scatter`` and ``gather`` are ``make_loads_fn_batch``'s load half plus
+    the per-path price sums of ``_path_cost_gather``.  ``dense``/``pallas``
+    make both products in ONE ``ops.congestion`` pass over the stacked
+    incidence: the fused kernel reads each B tile from HBM once per
+    iteration.
+    """
+    if backend in ("dense", "pallas"):
+        b3, kernel_backend = _stacked_incidence(path_edges, n_slots, backend)
 
-@solver_jit(spec="_ir_cases_mw_carry_init")
-@functools.partial(jax.jit, static_argnames=("seg_max",))
-def _mw_carry_init(
-    x_init: jnp.ndarray, owner: jnp.ndarray, inv_cap: jnp.ndarray,
-    demands: jnp.ndarray, seg_max: int,
-):
-    x0 = _seg_norm(x_init, *_seg_layout(owner, seg_max), seg_max)
-    return (x0, jnp.zeros_like(inv_cap), jnp.float32(0.0), x0)
+        def fused(rates, prices):
+            return ops.congestion(b3, rates, prices, backend=kernel_backend)
+
+        return fused
+    loads_fn = make_loads_fn_batch(path_edges, n_slots, backend, slot_gather)
+
+    def fused(rates, prices):
+        loads = loads_fn(rates)
+        pr_pad = jnp.concatenate(
+            [prices, jnp.zeros((prices.shape[0], 1), jnp.float32)], axis=1
+        )
+        return loads, _path_cost_gather(pr_pad, path_edges)
+
+    return fused
 
 
 def _warm_split(ps: PathSystem, warm: "FlowResult | np.ndarray") -> np.ndarray:
@@ -735,101 +454,6 @@ def _warm_split(ps: PathSystem, warm: "FlowResult | np.ndarray") -> np.ndarray:
     return np.maximum(x0, floor)
 
 
-def mw_concurrent_flow(
-    ps: PathSystem,
-    iters: int = 400,
-    backend: str = "auto",
-    warm: "FlowResult | np.ndarray | None" = None,
-    early_stop: bool = False,
-    check_every: int = 50,
-    rel_tol: float = 1e-3,
-    patience: int = 2,
-    target_alpha: float | None = None,
-) -> FlowResult:
-    """MW/mirror-descent max concurrent flow.
-
-    ``backend``: ``"auto"`` (platform/size dispatch), ``"scatter"``,
-    ``"dense"`` (incidence matmul via ops.congestion), or ``"pallas"``
-    (force the fused kernel, interpret mode off-TPU).
-
-    ``warm``: a FlowResult (or raw per-path rate vector) from the
-    *predecessor* path system of a delta update; requires ``ps.row_map``
-    (set by ``routing.update_path_system``).  Warm-started solves reach a
-    given alpha quality in substantially fewer iterations on small topology
-    deltas, which is where the expansion/failure sweeps spend their time.
-
-    Adaptive iteration count: with ``early_stop=True`` the solve runs in
-    ``check_every``-iteration windows and stops once the best alpha has
-    improved by less than ``rel_tol`` (relative) for ``patience`` consecutive
-    windows — the anneal schedule stays pinned to the full ``iters`` horizon,
-    so a run that never plateaus is bit-identical to ``early_stop=False``.
-    ``target_alpha`` additionally stops as soon as the best (exactly
-    evaluated) alpha reaches it — the feasibility-probe mode that keeps the
-    ``core.capacity.max_servers_at_full_capacity`` bisection from burning
-    the full budget on clearly-feasible probes.  ``FlowResult.iters``
-    reports the iterations actually run.
-    """
-    if ps.n_paths == 0:
-        return FlowResult(0.0, np.zeros(0), np.inf, "mw", 0)
-    backend = _resolve_backend(backend, ps.n_paths, ps.n_slots)
-    if warm is not None and ps.row_map is not None:
-        x_init = _warm_split(ps, warm)
-    else:
-        x_init = np.ones(ps.n_paths, dtype=np.float32)
-    pe = jnp.asarray(ps.path_edges)
-    owner = jnp.asarray(ps.path_owner)
-    demands = jnp.asarray(ps.demands, dtype=jnp.float32)
-    inv_cap = jnp.asarray(1.0 / ps.capacities, dtype=jnp.float32)
-    seg_max = _seg_passes(ps.path_owner)
-    carry = _mw_carry_init(
-        jnp.asarray(x_init, dtype=jnp.float32), owner, inv_cap, demands,
-        seg_max,
-    )
-    adaptive = early_stop or target_alpha is not None
-    if not adaptive:
-        carry = _mw_window(pe, owner, demands, inv_cap, carry, 0, iters, iters,
-                           iters, seg_max, backend)
-        done = iters
-    else:
-        done = 0
-        best_prev = 0.0
-        stall = 0
-        stop_reason = "budget"
-        while done < iters:
-            # always trace the same static window length; a short final
-            # window runs `step` live iterations and check_every - step
-            # masked no-ops, so one compilation serves the whole solve
-            step = min(check_every, iters - done)
-            with obs.span("mw/window", t0=done, step=step):
-                carry = _mw_window(pe, owner, demands, inv_cap, carry, done,
-                                   step, iters, check_every, seg_max, backend)
-                done += step
-                best = float(carry[2])  # best alpha so far (exact evals)
-            obs.counter("mw/windows").inc()
-            obs.counter_event("mw/alpha", best)
-            if target_alpha is not None and best >= target_alpha:
-                stop_reason = "target"
-                break
-            if early_stop:
-                if best - best_prev < rel_tol * max(best, 1e-12):
-                    stall += 1
-                    if stall >= patience:
-                        stop_reason = "plateau"
-                        break
-                else:
-                    stall = 0
-                best_prev = max(best, best_prev)
-        obs.counter(f"mw/stop/{stop_reason}").inc()
-    alpha, rates, max_load = _mw_final(pe, owner, demands, inv_cap, carry, backend)
-    res = FlowResult(
-        float(alpha), np.asarray(rates), float(max_load), f"mw-{backend}", done
-    )
-    obs.counter("mw/solves").inc()
-    obs.counter("mw/iters").inc(done)
-    obs.gauge("mw/alpha").set(res.alpha)
-    return res
-
-
 # --------------------------------------------------------------------------- #
 # Batched multi-instance MW solver
 # --------------------------------------------------------------------------- #
@@ -853,36 +477,34 @@ class PathSystemBatch:
       one of its padded slots or, for the widest instance, on the shared
       garbage slot — harmless either way.
 
-    The shared-topology fast path (``from_shared``) stores ONE (P, L) path
-    table and per-instance demands only — the sweep-over-traffic-matrices
-    case, where stacking B copies of the incidence would be pure waste.
+    A sweep over traffic matrices on one routing is B copies of that path
+    system, each with its own demands.
 
     Construction also precomputes the TRANSPOSED fan-in table that backs
-    the ``gather`` congestion path (the CPU default for batches):
-    ``slot_gather[.., s, :]`` holds the flat positions (``p * L + l``) of
+    the ``gather`` congestion path (the CPU default):
+    ``slot_gather[b, s, :]`` holds the flat positions (``p * L + l``) of
     every real path hop crossing slot s, padded with an out-of-range
     sentinel that gathers a zero.  Slot loads then become vectorized
     gather+sum instead of an XLA scatter-add (which executes as a
     serialized element loop on CPU and dominates the scatter backend's
     iteration).  A skew guard skips the table when one slot's fan-in would
     blow it up past ``_GATHER_TABLE_GUARD`` times the hop count — the
-    driver falls back to ``scatter``.  The per-commodity split sums need
-    no table: each instance's rows keep the canonical layout, every
-    commodity one contiguous run and the dummy's rows at the tail
-    (``seg_max``, ``_seg_norm``).
+    solve then falls back to ``scatter`` (``congestion_backend``).  The
+    per-commodity split sums need no table: each instance's rows keep the
+    canonical layout, every commodity one contiguous run and the dummy's
+    rows at the tail (``seg_max``, ``_seg_norm``).
     """
 
-    path_edges: np.ndarray  # (B, P, L) int32 — or (P, L) when shared
-    path_owner: np.ndarray  # (B, P) int32 — or (P,) when shared
-    demands: np.ndarray  # (B, K [+ 1 dummy when stacked]) f32
-    inv_cap: np.ndarray  # (B, S) f32, 0 on padded slots — or (S,) shared
-    slot_valid: np.ndarray  # (B, S) bool — or (S,) all-True shared
+    path_edges: np.ndarray  # (B, P, L) int32
+    path_owner: np.ndarray  # (B, P) int32
+    demands: np.ndarray  # (B, K + 1 dummy) f32
+    inv_cap: np.ndarray  # (B, S) f32, 0 on padded slots
+    slot_valid: np.ndarray  # (B, S) bool
     n_paths: np.ndarray  # (B,) true per-instance path counts
     systems: list  # the original PathSystem objects (result slicing, warm)
-    shared: bool = False
     # transposed fan-in table for the gather backend (None: skew guard hit
     # or a hand-built batch; the solver then falls back to scatter)
-    slot_gather: np.ndarray | None = None  # (B, S, D) int32 — or (S, D)
+    slot_gather: np.ndarray | None = None  # (B, S, D) int32
 
     @property
     def n_batch(self) -> int:
@@ -890,11 +512,11 @@ class PathSystemBatch:
 
     @property
     def p_max(self) -> int:
-        return self.path_edges.shape[-2]
+        return self.path_edges.shape[1]
 
     @property
     def s_max(self) -> int:
-        return self.inv_cap.shape[-1]
+        return self.inv_cap.shape[1]
 
     @property
     def seg_max(self) -> int:
@@ -902,6 +524,28 @@ class PathSystemBatch:
         real commodity over the instances, bucketed (``_seg_passes``)."""
         uniq = {id(ps): ps for ps in self.systems}.values()
         return max(_seg_passes(ps.path_owner) for ps in uniq)
+
+    def congestion_backend(
+        self, requested: str
+    ) -> tuple[str, np.ndarray | None]:
+        """The congestion backend a solve over this batch runs, and the
+        fan-in table it needs (``gather`` only; ``None`` otherwise).
+
+        ``auto`` takes ``ops.preferred_congestion_backend``'s batch policy,
+        asked with ``n_batch >= 2`` so that a batch of one gets it too
+        (gather tables on CPU; dense or scatter by size on TPU).
+        ``gather`` falls back to ``scatter`` when the batch has no table
+        (skew guard tripped, or a hand-built batch).  Every other request
+        passes through.
+        """
+        backend = requested
+        if backend == "auto":
+            backend = ops.preferred_congestion_backend(
+                self.p_max, self.s_max, n_batch=max(self.n_batch, 2)
+            )
+        if backend == "gather" and self.slot_gather is None:
+            backend = "scatter"
+        return backend, self.slot_gather if backend == "gather" else None
 
     @staticmethod
     def _slot_table(pe2d: np.ndarray, n_slots: int) -> tuple[np.ndarray, np.ndarray]:
@@ -1014,51 +658,6 @@ class PathSystemBatch:
             check_path_system_batch(batch, name="from_systems")
         return batch
 
-    @classmethod
-    def from_shared(
-        cls, ps: PathSystem, demands: np.ndarray
-    ) -> "PathSystemBatch":
-        """B instances over ONE path system, differing only in demands.
-
-        ``demands`` is (B, n_commodities); the path table, owners, and
-        capacities are stored once and broadcast by the batched window.
-        """
-        dem = np.ascontiguousarray(np.asarray(demands, dtype=np.float32))
-        if dem.ndim != 2 or dem.shape[1] != ps.n_commodities:
-            raise ValueError(
-                f"shared-batch demands must be (B, {ps.n_commodities}); "
-                f"got {dem.shape}"
-            )
-        S = max(ps.n_slots, 1)
-        inv = np.zeros(S, dtype=np.float32)
-        sval = np.zeros(S, dtype=bool)
-        if ps.n_slots:
-            inv[: ps.n_slots] = 1.0 / ps.capacities
-            sval[: ps.n_slots] = True
-        pe = np.asarray(ps.path_edges, dtype=np.int32)
-        owner = np.asarray(ps.path_owner, dtype=np.int32)
-        slot_tab: np.ndarray | None = None
-        if ps.n_paths:
-            tab, _ = cls._slot_table(pe, ps.n_slots)
-            d = max(tab.shape[1], 1)
-            if S * d <= _GATHER_TABLE_GUARD * (pe.size + 1):
-                slot_tab = np.full((S, d), pe.size, dtype=np.int32)
-                slot_tab[: tab.shape[0], : tab.shape[1]] = tab
-        batch = cls(
-            path_edges=pe,
-            path_owner=owner,
-            demands=dem,
-            inv_cap=inv,
-            slot_valid=sval,
-            n_paths=np.full(dem.shape[0], ps.n_paths, dtype=np.int64),
-            systems=[ps] * dem.shape[0],
-            shared=True,
-            slot_gather=slot_tab,
-        )
-        if checks_enabled():
-            check_path_system_batch(batch, name="from_shared")
-        return batch
-
 
 def _empty_path_system() -> PathSystem:
     """Zero-path filler instance for batch-size bucketing (inactive from the
@@ -1074,25 +673,12 @@ def _empty_path_system() -> PathSystem:
     )
 
 
-def _batch_demand_per_path(demands, owner):
-    """(Bt, P) demand of each path's commodity, for either owner rank."""
-    if owner.ndim == 1:  # shared: one owner table, per-instance demands
-        return demands[:, owner]
-    return jnp.take_along_axis(demands, owner, axis=1)
-
-
-def _batch_seg_layout(owner, n_comm, seg_max):
-    """``_seg_layout`` for either owner rank: a stacked (Bt, P) owner pads
-    with the dummy commodity ``n_comm - 1``; the shared (P,) one has none."""
-    return _seg_layout(owner, seg_max, None if owner.ndim == 1 else n_comm - 1)
-
-
 @solver_jit(spec="_ir_cases_mw_carry_init_batch")
 @functools.partial(jax.jit, static_argnames=("seg_max",))
 def _mw_carry_init_batch(x_init, owner, inv_cap, demands, seg_max: int):
     Bt, K = demands.shape
-    S = inv_cap.shape[-1]
-    x0 = _seg_norm(x_init, *_batch_seg_layout(owner, K, seg_max), seg_max)
+    S = inv_cap.shape[1]
+    x0 = _seg_norm(x_init, *_seg_layout(owner, seg_max, K - 1), seg_max)
     return (
         x0,
         jnp.zeros((Bt, S), jnp.float32),
@@ -1105,11 +691,11 @@ def _mw_carry_init_batch(x_init, owner, inv_cap, demands, seg_max: int):
 @functools.partial(jax.jit, static_argnames=("iters_total", "n_steps",
                                               "seg_max", "backend"))
 def _mw_window_batch(
-    path_edges,  # (Bt, P, L) int32 — or (P, L) shared
-    owner,  # (Bt, P) int32 — or (P,) shared
-    demands,  # (Bt, K) f32
-    inv_cap,  # (Bt, S) f32 — or (S,) shared
-    slot_valid,  # (Bt, S) bool — or (S,) shared
+    path_edges,  # (Bt, P, L) int32
+    owner,  # (Bt, P) int32
+    demands,  # (Bt, K) f32, K - 1 the zero-demand dummy
+    inv_cap,  # (Bt, S) f32
+    slot_valid,  # (Bt, S) bool
     carry,  # (x (Bt,P), rel_prev (Bt,S), best_alpha (Bt,), best_x (Bt,P))
     t0,  # traced scalar: first global iteration of this window
     valid_steps,  # traced scalar: live steps this window (rest are no-ops)
@@ -1120,34 +706,45 @@ def _mw_window_batch(
     backend: str = "scatter",
     slot_gather=None,  # fan-in table; required by the gather backend
 ):
-    """Batched mirror of ``_mw_window``: per-instance masked updates.
+    """``n_steps`` MW iterations of every instance, from global step ``t0``.
 
-    Each batch member runs the SAME per-step recurrence as the sequential
-    window (same anneal, same lagged softmax, same exact alpha bookkeeping),
-    with two masks composed per step: ``t - t0 < valid_steps`` (window
-    padding, satellite of the jit-churn fix) and ``active`` (per-instance
-    early-stop).  A masked step selects the old carry bit-exactly, so a
-    frozen instance's state — and therefore its final result — is identical
-    to stopping its sequential solve at the same window.
+    The temperature anneal is driven by the *global* step over the full
+    ``iters_total`` horizon, so chaining windows reproduces the single-scan
+    trajectory exactly — which is what lets ``mw_concurrent_flow_batch``
+    check each instance's best-alpha plateau between windows (adaptive
+    iteration count) without perturbing the converged-run result.
+
+    Two masks compose per step: ``t - t0 < valid_steps`` (TRACED, so an
+    adaptive solve always calls with the same static ``n_steps =
+    check_every`` and pads a short final window with no-ops — one
+    compilation serves the whole solve) and ``active`` (per-instance
+    early-stop).  A masked step
+    selects the old carry bit-exactly, so a frozen instance's state — and
+    therefore its final result — is that of stopping it alone at the same
+    window.
     """
     Bt, K = demands.shape
-    S = inv_cap.shape[-1]
-    fused = make_congestion_fn_batch(path_edges, S, Bt, backend, slot_gather)
-    pos, run = _batch_seg_layout(owner, K, seg_max)
-    dem = _batch_demand_per_path(demands, owner)
-    inv = inv_cap if inv_cap.ndim == 2 else inv_cap[None, :]
+    S = inv_cap.shape[1]
+    fused = make_congestion_fn_batch(path_edges, S, backend, slot_gather)
+    pos, run = _seg_layout(owner, seg_max, K - 1)
+    dem = jnp.take_along_axis(demands, owner, axis=1)
     neg_inf = jnp.float32(-jnp.inf)
 
     def body(carry, t):
         x, rel_prev, best_alpha, best_x = carry
+        # softmax weights from the PREVIOUS iterate's loads (one-step lag) so
+        # the fused kernel computes this iterate's loads and the gradient's
+        # path costs in a single pass over B.  rel_prev = 0 at t = 0 gives
+        # uniform weights.  GEOMETRIC temperature anneal (0.2 -> 0.005 of
+        # max load) + 1/sqrt(t) step decay.
         mx_prev = jnp.max(rel_prev, axis=1)
         frac = 0.2 * (0.005 / 0.2) ** (t.astype(jnp.float32) / iters_total)
         tau = jnp.maximum(mx_prev, 1e-12) * frac
         logits = jnp.where(slot_valid, rel_prev / tau[:, None], neg_inf)
         w = _masked_softmax(logits)
         rates = x * dem
-        loads, costs = fused(rates, w * inv)
-        rel = loads * inv
+        loads, costs = fused(rates, w * inv_cap)
+        rel = loads * inv_cap
         mx = jnp.max(rel, axis=1)
         alpha = 1.0 / jnp.maximum(mx, 1e-12)
         live = active & (t - t0 < valid_steps)
@@ -1170,16 +767,16 @@ def _mw_window_batch(
 @functools.partial(jax.jit, static_argnames=("backend",))
 def _mw_final_batch(path_edges, owner, demands, inv_cap, carry,
                     backend: str = "scatter", slot_gather=None):
-    """Batched mirror of ``_mw_final``: exact last-iterate eval, best result."""
+    """One exact evaluation of each last iterate, then the best-iterate
+    result."""
     Bt, K = demands.shape
-    S = inv_cap.shape[-1]
-    fused = make_congestion_fn_batch(path_edges, S, Bt, backend, slot_gather)
-    dem = _batch_demand_per_path(demands, owner)
-    inv = inv_cap if inv_cap.ndim == 2 else inv_cap[None, :]
+    S = inv_cap.shape[1]
+    fused = make_congestion_fn_batch(path_edges, S, backend, slot_gather)
+    dem = jnp.take_along_axis(demands, owner, axis=1)
     x, _, best_alpha, best_x = carry
     rates = x * dem
     loads, _ = fused(rates, jnp.zeros((Bt, S), jnp.float32))
-    mx = jnp.max(loads * inv, axis=1)
+    mx = jnp.max(loads * inv_cap, axis=1)
     alpha = 1.0 / jnp.maximum(mx, 1e-12)
     better = alpha > best_alpha
     best_alpha = jnp.where(better, alpha, best_alpha)
@@ -1202,21 +799,38 @@ def mw_concurrent_flow_batch(
     """Solve B independent MW instances in ONE batched window scan.
 
     Accepts a ``PathSystemBatch`` or any sequence of ``PathSystem``s (which
-    is pad-and-stacked on the fly; pass ``PathSystemBatch.from_shared`` to
-    hit the shared-topology fast path).  Per-instance results match
-    ``mw_concurrent_flow`` with the same arguments to float tolerance
-    (bit-exactly under ``backend="scatter"``), and the adaptive state
-    (plateau early-stop, ``target_alpha`` cutoff) is tracked PER INSTANCE:
-    a converged instance's carry is frozen bit-exactly (so
-    ``FlowResult.iters`` agrees exactly with the sequential solve) while
-    the rest of the batch runs on.
+    is pad-and-stacked on the fly, the batch size bucketed with empty
+    fillers).  An instance's result does not depend on the rest of the
+    batch: the adaptive state (plateau early-stop, ``target_alpha``
+    cutoff) is tracked PER INSTANCE, and a converged instance's carry is
+    frozen bit-exactly while the rest of the batch runs on.
 
     ``backend``: ``"auto"`` (gather tables on CPU, dense/scatter by size on
-    TPU), ``"gather"``, ``"scatter"``, ``"dense"``, or ``"pallas"``.
+    TPU), ``"gather"``, ``"scatter"``, ``"dense"``, or ``"pallas"`` (force
+    the fused kernel, interpret mode off-TPU); resolved by
+    ``PathSystemBatch.congestion_backend``.
 
-    ``warm`` is an optional per-instance sequence of predecessor flow
-    results/rate vectors, applied through each instance's ``row_map``
-    exactly as in ``mw_concurrent_flow``.
+    ``warm``: per instance, ``None`` or a FlowResult (or raw per-path rate
+    vector) from the *predecessor* path system of a delta update, applied
+    through that instance's ``row_map`` (``_warm_split``).  Warm-started
+    solves reach a given alpha quality in substantially fewer iterations
+    on small topology deltas, which is where the expansion/failure sweeps
+    spend their time.
+
+    Adaptive iteration count: with ``early_stop=True`` the solve runs in
+    ``check_every``-iteration windows and an instance stops once its best
+    alpha has improved by less than ``rel_tol`` (relative) for ``patience``
+    consecutive windows — the anneal schedule stays pinned to the full
+    ``iters`` horizon, so a run that never plateaus is bit-identical to
+    ``early_stop=False``.  ``target_alpha`` additionally stops an instance
+    as soon as its best (exactly evaluated) alpha reaches it — the
+    feasibility-probe mode of the ``core.capacity`` search.
+    ``FlowResult.iters`` reports the iterations each instance ran.
+
+    Metrics: ``mw/solves`` and ``mw/iters`` count the instances solved and
+    their iterations, the ``mw/alpha`` gauge holds the last instance's
+    alpha, ``mw/windows_batch`` counts adaptive windows and
+    ``mw/stop/{target,plateau,budget}`` each instance's stop reason.
 
     Traced (``repro.obs``), the host phases are spans: ``mw/assemble``
     (stacking a sequence; ``rows``), ``mw/upload`` (the host tables sent
@@ -1253,12 +867,7 @@ def mw_concurrent_flow_batch(
         out = [FlowResult(0.0, np.zeros(0), np.inf, method_tag, 0)
                for _ in range(B)]
         return out if n_asked is None else out[:n_asked]
-    # max(B, 2): even a B=1 batch wants the BATCH backend policy (gather
-    # tables on CPU), not the single-instance dispatch
-    backend = _resolve_backend(backend, batch.p_max, batch.s_max,
-                               n_batch=max(B, 2))
-    if backend == "gather" and batch.slot_gather is None:
-        backend = "scatter"  # skew guard tripped or a hand-built batch
+    backend, slot_table = batch.congestion_backend(backend)
     method_tag = f"mw-batch-{backend}"
     x_init = np.ones((B, batch.p_max), dtype=np.float32)
     if warm is not None:
@@ -1266,8 +875,7 @@ def mw_concurrent_flow_batch(
             if w is not None and ps.row_map is not None and ps.n_paths:
                 x_init[i, : ps.n_paths] = _warm_split(ps, w)
     host = (batch.path_edges, batch.path_owner, batch.demands,
-            batch.inv_cap, batch.slot_valid, x_init,
-            batch.slot_gather if backend == "gather" else None)
+            batch.inv_cap, batch.slot_valid, x_init, slot_table)
     with obs.span("mw/upload",
                   bytes=sum(a.nbytes for a in host if a is not None)):
         pe, owner, demands, inv_cap, slot_valid, x_dev, slot_tab = (
@@ -1310,8 +918,6 @@ def mw_concurrent_flow_batch(
                     obs.counter_event("mw/alpha_batch_mean",
                                       float(best[active].mean()))
                 for b in np.flatnonzero(active):
-                    # identical decision sequence to mw_concurrent_flow's
-                    # window loop, applied per instance
                     if target_alpha is not None and best[b] >= target_alpha:
                         active[b] = False
                         obs.counter("mw/stop/target").inc()
@@ -1349,7 +955,32 @@ def mw_concurrent_flow_batch(
                     float(max_load[b]), method_tag, int(done[b]),
                 )
             )
+            obs.gauge("mw/alpha").set(out[-1].alpha)
+    obs.counter("mw/solves").inc(int((~empty).sum()))
+    obs.counter("mw/iters").inc(int(done.sum()))
     return out if n_asked is None else out[:n_asked]
+
+
+def mw_concurrent_flow(
+    ps: PathSystem,
+    iters: int = 400,
+    backend: str = "auto",
+    warm: "FlowResult | np.ndarray | None" = None,
+    early_stop: bool = False,
+    check_every: int = 50,
+    rel_tol: float = 1e-3,
+    patience: int = 2,
+    target_alpha: float | None = None,
+) -> FlowResult:
+    """MW max concurrent flow of one path system: a batch of one of
+    ``mw_concurrent_flow_batch``, whose arguments it takes (``warm`` for
+    this one instance)."""
+    return mw_concurrent_flow_batch(
+        [ps], iters=iters, backend=backend,
+        warm=None if warm is None else [warm], early_stop=early_stop,
+        check_every=check_every, rel_tol=rel_tol, patience=patience,
+        target_alpha=target_alpha,
+    )[0]
 
 
 # --------------------------------------------------------------------------- #
@@ -1506,28 +1137,12 @@ _IR_B, _IR_D = 2, 4  # batch, gather fan-in width
 _IR_SEG = 4  # _seg_passes of the IR owner vector (runs of 2 rows)
 
 
-def _ir_seq_args():
-    import numpy as np
-
-    pe = np.full((_IR_P, _IR_L), _IR_S, np.int32)
-    pe[:, 0] = np.arange(_IR_P) % _IR_S
-    owner = np.sort(np.arange(_IR_P) % _IR_K).astype(np.int32)
-    demands = np.ones(_IR_K, np.float32)
-    inv_cap = np.ones(_IR_S, np.float32)
-    carry = (
-        np.ones(_IR_P, np.float32),
-        np.zeros(_IR_S, np.float32),
-        np.float32(0.0),
-        np.ones(_IR_P, np.float32),
-    )
-    return pe, owner, demands, inv_cap, carry
-
-
 def _ir_batch_args():
     import numpy as np
 
-    pe, owner, _, _, _ = _ir_seq_args()
-    pe3 = np.broadcast_to(pe, (_IR_B, _IR_P, _IR_L)).copy()
+    pe = np.full((_IR_B, _IR_P, _IR_L), _IR_S, np.int32)
+    pe[:, :, 0] = np.arange(_IR_P) % _IR_S
+    owner = np.sort(np.arange(_IR_P) % _IR_K).astype(np.int32)
     owner2 = np.broadcast_to(owner, (_IR_B, _IR_P)).copy()
     dem2 = np.ones((_IR_B, _IR_K), np.float32)
     inv2 = np.ones((_IR_B, _IR_S), np.float32)
@@ -1540,62 +1155,13 @@ def _ir_batch_args():
         np.ones((_IR_B, _IR_P), np.float32),
     )
     active = np.ones(_IR_B, bool)
-    return pe3, owner2, dem2, inv2, sval2, slot_gather, carry_b, active
+    return pe, owner2, dem2, inv2, sval2, slot_gather, carry_b, active
 
 
 _IR_DENSE_EXEMPT = {
     "JF101": "dense backend contracts via matmul by design; its reassociation "
     "drift vs scatter/gather is a documented contract (CG-3), not a bug",
 }
-
-
-def _ir_cases_mw_window():
-    from ..analysis.registry import AuditCase
-    import numpy as np
-
-    def mk(backend):
-        def make():
-            pe, owner, demands, inv_cap, carry = _ir_seq_args()
-            return (
-                (pe, owner, demands, inv_cap, carry, np.int32(0), np.int32(4)),
-                {"iters_total": 10, "n_steps": 4, "seg_max": _IR_SEG,
-                 "backend": backend},
-            )
-
-        return make
-
-    return [
-        AuditCase(label="scatter", make=mk("scatter"), backend="scatter"),
-        AuditCase(
-            label="dense",
-            make=mk("dense"),
-            backend="dense",
-            exempt=_IR_DENSE_EXEMPT,
-            budget=False,
-        ),
-    ]
-
-
-def _ir_cases_mw_final():
-    from ..analysis.registry import AuditCase
-
-    def make():
-        pe, owner, demands, inv_cap, carry = _ir_seq_args()
-        return (pe, owner, demands, inv_cap, carry), {"backend": "scatter"}
-
-    return [AuditCase(label="scatter", make=make, backend="scatter")]
-
-
-def _ir_cases_mw_carry_init():
-    from ..analysis.registry import AuditCase
-    import numpy as np
-
-    def make():
-        _, owner, demands, inv_cap, _ = _ir_seq_args()
-        return ((np.ones(_IR_P, np.float32), owner, inv_cap, demands),
-                {"seg_max": _IR_SEG})
-
-    return [AuditCase(label="seq", make=make)]
 
 
 def _ir_cases_mw_carry_init_batch():

@@ -20,11 +20,13 @@ against the LP/MW solvers: PF throughput <= max-concurrent-flow alpha and
 reproduced by benchmarks/fig8_mptcp.py.
 
 The price iteration's two incidence products per step — path prices
-``q = B p`` and link loads ``ld = B^T r`` — go through the same congestion
-backend machinery as ``core.flow`` (``make_congestion_fn``): scatter/gather
-on CPU, the fused Pallas kernel over a materialized incidence on TPU.  To
-let the fused kernel compute both in one pass over B, the price update uses
-the previous step's rates (one-step Jacobi lag); the equilibrium is
+``q = B p`` and link loads ``ld = B^T r`` — go through the MW solver's
+congestion closure (``core.flow.make_congestion_fn_batch``) as a batch of
+one.  The backend is ``ops.preferred_congestion_backend``'s single-instance
+choice: a dense incidence for toy instances and scatter otherwise on CPU,
+the fused Pallas kernel over a materialized incidence on TPU when it fits.
+To let the fused kernel compute both in one pass over B, the price update
+uses the previous step's rates (one-step Jacobi lag); the equilibrium is
 unchanged and the final exact feasibility rescale is lag-free.
 """
 
@@ -39,7 +41,8 @@ import jax
 import jax.numpy as jnp
 
 from ..analysis.registry import AuditCase, solver_jit
-from .flow import _resolve_backend, _warm_split, make_congestion_fn
+from ..kernels import ops
+from .flow import _warm_split, make_congestion_fn_batch
 from .routing import PathSystem
 
 __all__ = ["MptcpResult", "mptcp_throughput"]
@@ -82,7 +85,11 @@ def _pf_solve(
     P, L = path_edges.shape
     E = caps.shape[0]
     K = demands.shape[0]
-    fused = make_congestion_fn(path_edges, E, backend)
+    fused_b = make_congestion_fn_batch(path_edges[None], E, backend)
+
+    def fused(rates, prices):
+        loads, costs = fused_b(rates[None], prices[None])
+        return loads[0], costs[0]
 
     seg_min_init = jnp.full((K,), jnp.inf, jnp.float32)
     beta0 = 0.2
@@ -145,7 +152,8 @@ def mptcp_throughput(
     """
     if ps.n_paths == 0:
         return MptcpResult(np.zeros(0), 0.0, 1.0, 0, np.zeros(0))
-    backend = _resolve_backend(backend, ps.n_paths, ps.n_slots)
+    if backend == "auto":
+        backend = ops.preferred_congestion_backend(ps.n_paths, ps.n_slots)
     r_init = None
     if warm is not None and ps.row_map is not None:
         prev = warm.rates if isinstance(warm, MptcpResult) else warm
@@ -171,11 +179,11 @@ def mptcp_throughput(
 # ---- IR audit cases (python -m repro.analysis ir) ------------------------- #
 
 def _ir_cases_pf_solve():
-    from .flow import _ir_seq_args
+    from .flow import _ir_batch_args
 
     def make():
-        pe, owner, demands, inv_cap, _ = _ir_seq_args()
-        caps = 1.0 / inv_cap
+        pe3, owner2, dem2, inv2, _, _, _, _ = _ir_batch_args()
+        pe, owner, demands, caps = pe3[0], owner2[0], dem2[0], 1.0 / inv2[0]
         return (pe, owner, demands, caps, int(demands.shape[0])), {
             "iters": 8,
             "backend": "scatter",

@@ -12,18 +12,23 @@ TPU is the *target*; this container is CPU-only.  Policy:
 
 Flow-solver backend selection
 -----------------------------
-The MW / MPTCP inner loops (``core.flow``, ``core.mptcp``) need the fused
-incidence products ``(B^T r, B w)`` every iteration.  Whether to materialize
-the dense (P, 2E) incidence B and call the fused ``congestion`` kernel, or to
-stay with gather/segment-sum over the padded path table, is a platform *and*
-size question, answered here by ``preferred_congestion_backend``:
+The MW / MPTCP inner loops (``core.flow``, ``core.mptcp``) and the sim's
+waterfilling need the incidence products ``(B^T r, B w)`` every iteration.
+Whether to materialize the dense (P, 2E) incidence B and call the fused
+``congestion`` kernel, or to stay with gather/segment-sum over the padded
+path table, is a platform *and* size question, answered here by
+``preferred_congestion_backend``:
 
 * On TPU the dense kernel wins whenever B fits comfortably in HBM (scatter
   adds are serialized and MXU-hostile), so: ``dense`` iff
-  ``P * 2E * 4 bytes <= dense_budget_bytes``.
-* On CPU the scatter path wins at any interesting size (B is ~99% zeros and
-  XLA's scatter-add is cache-friendly), so: ``scatter`` unless the instance
-  is tiny.
+  ``n_batch * P * 2E * 4 bytes <= dense_budget_bytes``.
+* On CPU a batch (``n_batch > 1``) gets ``gather``: the transposed fan-in
+  tables built with every ``core.flow.PathSystemBatch``.  The MW solver and
+  the sim ask through ``PathSystemBatch.congestion_backend``, with
+  ``n_batch >= 2`` even for a batch of one.
+* ``n_batch == 1`` on CPU is ``core.mptcp``'s question: ``scatter`` unless
+  the instance is tiny (B is ~99% zeros and XLA's scatter-add is
+  cache-friendly), ``dense`` for toy instances.
 
 ``apsp_minplus`` is the TPU-shaped APSP (min-plus squaring, dense f32);
 ``apsp_minplus_blocked`` is its out-of-core sibling — host-resident int16
@@ -81,10 +86,10 @@ def preferred_congestion_backend(
     dense_budget_bytes: int | None = None,
     n_batch: int = 1,
 ) -> str:
-    """Pick the flow-solver congestion backend ('dense' or 'scatter') by size.
+    """Pick the flow-solver congestion backend by platform and size.
 
     ``n_paths`` x ``n_slots`` is the incidence shape (P, 2E); see module
-    docstring for the policy.  ``n_batch`` > 1 is the batched MW solver
+    docstring for the policy.  ``n_batch`` > 1 is a ``PathSystemBatch``
     asking about a stacked (n_batch, P, 2E) incidence: on TPU the dense
     budget is shared by the whole stack (the rank-3 fused kernel needs
     ``n_batch`` times the headroom); on CPU the answer is ``gather`` — the

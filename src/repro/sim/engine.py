@@ -56,7 +56,6 @@ from ..core.flow import (
     PathSystem,
     PathSystemBatch,
     _fold_sum,
-    _resolve_backend,
     make_loads_fn_batch,
 )
 from .ecmp import flow_hash
@@ -142,17 +141,12 @@ def _path_min_gather(share_pad: jnp.ndarray, pe: jnp.ndarray) -> jnp.ndarray:
     (``core.flow._path_cost_gather``) needs a positional halving tree over
     the columns to keep the same association as ``_fold_sum``.
     """
-    B = share_pad.shape[0]
-    L = pe.shape[-1]
-    P = pe.shape[-2]
+    B, P, L = pe.shape
     acc = jnp.full((B, P), jnp.inf, jnp.float32)
     for j in range(L):
-        if pe.ndim == 2:  # shared path table
-            acc = jnp.minimum(acc, share_pad[:, pe[:, j]])
-        else:
-            acc = jnp.minimum(
-                acc, jnp.take_along_axis(share_pad, pe[:, :, j], axis=1)
-            )
+        acc = jnp.minimum(
+            acc, jnp.take_along_axis(share_pad, pe[:, :, j], axis=1)
+        )
     return acc
 
 
@@ -179,19 +173,13 @@ def _slot_min_gather(
         d = slot_gather.shape[-1]
         acc = jnp.full((B, n_slots), jnp.inf, jnp.float32)
         for j in range(d):
-            if slot_gather.ndim == 2:
-                acc = jnp.minimum(acc, fr[:, slot_gather[:, j]])
-            else:
-                acc = jnp.minimum(
-                    acc,
-                    jnp.take_along_axis(fr, slot_gather[:, :, j], axis=1),
-                )
+            acc = jnp.minimum(
+                acc,
+                jnp.take_along_axis(fr, slot_gather[:, :, j], axis=1),
+            )
         return acc
     vals = jnp.repeat(per_path, L, axis=1)  # (B, P*L)
-    if pe.ndim == 2:
-        flat = jnp.broadcast_to(pe.reshape(-1)[None], (B, P * L))
-    else:
-        flat = pe.reshape(B, P * L)
+    flat = pe.reshape(B, P * L)
     out = jnp.full((B, n_slots + 1), jnp.inf, jnp.float32)
     out = out.at[jnp.arange(B)[:, None], flat].min(vals)
     return out[:, :n_slots]
@@ -287,8 +275,8 @@ def _waterfill_core(loads_of, pe, nflow, cap, sval, wf_iters: int,
 @functools.partial(jax.jit, static_argnames=("wf_iters", "backend", "rule"))
 def _waterfill_jit(pe, nflow, cap, sval, slot_gather, *, wf_iters,
                    backend, rule="exact"):
-    B, S = nflow.shape[0], cap.shape[-1]
-    loads_of = make_loads_fn_batch(pe, S, B, backend, slot_gather)
+    S = cap.shape[1]
+    loads_of = make_loads_fn_batch(pe, S, backend, slot_gather)
     return _waterfill_core(loads_of, pe, nflow, cap, sval, wf_iters,
                            slot_gather, rule=rule)
 
@@ -334,14 +322,12 @@ def waterfill_rates(
         )
     if nflow.shape[1] < P:  # instance rows sit at the front of the envelope
         nflow = np.pad(nflow, ((0, 0), (0, P - nflow.shape[1])))
-    backend = _resolve_backend(backend, P, batch.s_max, n_batch=max(B, 2))
-    if backend == "gather" and batch.slot_gather is None:
-        backend = "scatter"
-    slot_tab = jnp.asarray(batch.slot_gather) if backend == "gather" else None
+    backend, slot_tab = batch.congestion_backend(backend)
     cap, _, sval = _cap_arrays(batch)
     rate, loads = _waterfill_jit(
         jnp.asarray(batch.path_edges), jnp.asarray(nflow), cap, sval,
-        slot_tab, wf_iters=wf_iters, backend=backend, rule=rule,
+        None if slot_tab is None else jnp.asarray(slot_tab),
+        wf_iters=wf_iters, backend=backend, rule=rule,
     )
     return np.asarray(rate), np.asarray(loads)
 
@@ -362,9 +348,6 @@ def _cap_arrays(batch: PathSystemBatch):
     capacity, zero inverse — they can never bind a fair share)."""
     inv = np.asarray(batch.inv_cap, np.float32)
     sval = np.asarray(batch.slot_valid)
-    if inv.ndim == 1:
-        inv = np.broadcast_to(inv, (batch.n_batch, inv.shape[0]))
-        sval = np.broadcast_to(sval, inv.shape)
     cap = np.where(inv > 0, 1.0 / np.maximum(inv, 1e-30), np.inf).astype(
         np.float32
     )
@@ -429,8 +412,6 @@ def _commodity_tables(batch: PathSystemBatch, n_comm: int):
 def _owner_padded(batch: PathSystemBatch, n_comm: int) -> np.ndarray:
     """(B, P+1) commodity of each path row; empty sentinel row -> K."""
     owner = np.asarray(batch.path_owner, np.int32)
-    if owner.ndim == 1:
-        owner = np.broadcast_to(owner, (batch.n_batch, owner.shape[0]))
     pad = np.full((batch.n_batch, 1), n_comm, np.int32)
     return np.concatenate([owner, pad], axis=1)
 
@@ -487,7 +468,7 @@ def _init_carry(
 def _sim_scan(
     carry0,  # scan carry (see _init_carry; may be a migrated mid-run carry)
     ts,  # (T,) int32 ABSOLUTE step indices (the per-step RNG fold source)
-    pe,  # (B, P, L) int32 — or (P, L) shared
+    pe,  # (B, P, L) int32
     owner_pad,  # (B, P+1) int32, commodity of each row (K = dummy)
     cap,  # (B, S) f32, +inf on padded slots
     inv,  # (B, S) f32
@@ -513,20 +494,18 @@ def _sim_scan(
     backend: str,
 ):
     B, K = rows_cnt.shape
-    P = pe.shape[-2]
-    L = pe.shape[-1]
-    S = inv.shape[-1]
+    _, P, L = pe.shape
+    S = inv.shape[1]
     D = rows_tab.shape[-1]
     A = n_arrivals
     F = carry0[0].shape[-1]
     nbins = carry0[7].shape[-1] - 1
     W_new = A * D if policy == "mptcp" else A
-    loads_of = make_loads_fn_batch(pe, S, B, backend, slot_gather)
+    loads_of = make_loads_fn_batch(pe, S, backend, slot_gather)
     bidx = jnp.arange(B)[:, None]
     if policy == "ksp_lc":
-        pe3 = pe if pe.ndim == 3 else jnp.broadcast_to(pe[None], (B, P, L))
         pe_pad = jnp.concatenate(
-            [pe3, jnp.full((B, 1, L), S, jnp.int32)], axis=1
+            [pe, jnp.full((B, 1, L), S, jnp.int32)], axis=1
         )
 
     def step(carry, inp):
@@ -702,14 +681,13 @@ def _scan_inputs(batch: PathSystemBatch, policy: str, cfg: SimConfig,
     backend resolution, and the per-step admission-width check — everything
     ``_sim_scan`` needs that depends only on the batch (not the workload or
     the carry)."""
-    B, P, S = batch.n_batch, batch.p_max, batch.s_max
+    B = batch.n_batch
     if B > SIM_MAX_BATCH:
         raise ValueError(
             f"batch has {B} instances > REPRO_SIM_MAX_BATCH={SIM_MAX_BATCH}; "
             "raise the env cap or split the batch"
         )
-    stacked = not batch.shared
-    K = batch.demands.shape[1] - (1 if stacked else 0)
+    K = batch.demands.shape[1] - 1  # the last column is the dummy's
     rows_tab, rows_cnt, comm_src, comm_dst = _commodity_tables(batch, K)
     D = rows_tab.shape[-1]
     w_new = cfg.max_arrivals * D if policy == "mptcp" else cfg.max_arrivals
@@ -721,10 +699,7 @@ def _scan_inputs(batch: PathSystemBatch, policy: str, cfg: SimConfig,
         )
     owner_pad = _owner_padded(batch, K)
     cap, inv, sval = _cap_arrays(batch)
-    backend = _resolve_backend(backend, P, S, n_batch=max(B, 2))
-    if backend == "gather" and batch.slot_gather is None:
-        backend = "scatter"
-    slot_tab = jnp.asarray(batch.slot_gather) if backend == "gather" else None
+    backend, slot_tab = batch.congestion_backend(backend)
     return {
         "n_comm": K,
         "pe": jnp.asarray(batch.path_edges),
@@ -736,7 +711,7 @@ def _scan_inputs(batch: PathSystemBatch, policy: str, cfg: SimConfig,
         "rows_cnt": jnp.asarray(rows_cnt),
         "comm_src": jnp.asarray(comm_src),
         "comm_dst": jnp.asarray(comm_dst),
-        "slot_tab": slot_tab,
+        "slot_tab": None if slot_tab is None else jnp.asarray(slot_tab),
         "backend": backend,
     }
 

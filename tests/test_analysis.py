@@ -390,19 +390,14 @@ def _layout_batch():
 
 def test_contract_batch_segment_layout_holds(checks_on):
     """The canonical layout the split normalisation sums over holds for
-    built batches, stacked and shared, on every instance."""
+    built batches, on every instance."""
     batch = _layout_batch()
     check_path_system_batch(batch, max_instances=0)
     check_segment_layout(batch)
-    ps = batch.systems[0]
-    shared = PathSystemBatch.from_shared(ps, np.ones((3, ps.n_commodities)))
-    check_path_system_batch(shared, max_instances=0)
-    check_segment_layout(shared)
 
 
 @pytest.mark.parametrize("corrupt, match", [
     ("owner_decreases", "grouped by commodity"),
-    ("shared_owner_decreases", "grouped by commodity"),
     ("dummy_inside", "must not belong to the dummy"),
     ("real_in_tail", "padded row"),
 ])
@@ -413,13 +408,7 @@ def test_contract_batch_segment_layout_fires(checks_on, corrupt, match):
     owner = batch.path_owner.copy()
     n1 = int(batch.n_paths[1])
     check = functools.partial(check_path_system_batch, max_instances=0)
-    if corrupt == "shared_owner_decreases":
-        ps = batch.systems[0]
-        batch = PathSystemBatch.from_shared(ps, np.ones((2, ps.n_commodities)))
-        owner = batch.path_owner.copy()
-        owner[[0, -1]] = owner[[-1, 0]]
-        check = check_segment_layout
-    elif corrupt == "owner_decreases":
+    if corrupt == "owner_decreases":
         first = int(np.argmax(owner[1] != owner[1, 0]))
         owner[1, [0, first]] = owner[1, [first, 0]]
         check = check_segment_layout

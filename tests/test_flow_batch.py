@@ -1,13 +1,15 @@
 """Batched MW solver + speculative bisection validation.
 
-Parity contract: ``mw_concurrent_flow_batch`` reproduces per-instance
-``mw_concurrent_flow`` results — bit-exactly on the scatter backend (same
-accumulation order) and on the gather backend (ordered fan-in sums match
-the scatter association), including EXACT per-instance iteration counts
-under the frozen-instance adaptive early-stop.  Plus ragged/empty/B=1
-batches, the shared-topology fast path, the jit-churn window padding, the
-speculative bisection's sequential-equality guarantee, the
-REPRO_LP_PATH_LIMIT import validation, and the MPTCP warm start.
+Parity contract: an instance's ``mw_concurrent_flow_batch`` result does
+not depend on the batch it is solved in — a batch of one
+(``mw_concurrent_flow``), other instances, or bucket padding — bit-exactly
+on the scatter backend and on the gather backend (ordered fan-in sums
+match the scatter association), including EXACT per-instance iteration
+counts under the frozen-instance adaptive early-stop.  Plus ragged/empty
+batches, one routing under several demand vectors, the one congestion
+backend decision, the jit-churn window padding, the speculative
+bisection's sequential-equality guarantee, the REPRO_LP_PATH_LIMIT import
+validation, and the MPTCP warm start.
 """
 
 import dataclasses
@@ -177,12 +179,6 @@ def test_pathsystembatch_gather_tables_cover_real_hops():
         real = int((batch.slot_gather[i] < P * L).sum())
         hops = int(ps.path_len.sum())
         assert real == hops  # every real hop appears exactly once
-    # the shared path's one table, over its one (P, L) path table
-    ps = systems[1]
-    shared = PathSystemBatch.from_shared(ps, np.ones((2, ps.n_commodities)))
-    assert shared.slot_gather.ndim == 2
-    real = int((shared.slot_gather < shared.path_edges.size).sum())
-    assert real == int(ps.path_len.sum())
 
 
 # --------------------------------------------------------------------------- #
@@ -211,13 +207,13 @@ def _seg_case(name):
             runs[0] = top
             rows.append(_owner_rows(runs, P - runs.sum(), K))
         return np.stack(rows), K + 1
-    if name == "shared":  # one owner row for every instance, no dummy
+    if name == "single_row":  # one (P,) owner row, no dummy
         runs = rng.integers(1, 8, size=10)
         return _owner_rows(runs, 0, 0), len(runs)
     raise ValueError(name)
 
 
-@pytest.mark.parametrize("case", ["ragged", "mixed_seg_max", "shared"])
+@pytest.mark.parametrize("case", ["ragged", "mixed_seg_max"])
 def test_seg_norm_bit_exact_to_scatter_add(case):
     """The shifted-add normalisation equals the XLA:CPU scatter-add one
     bit for bit on every real row; the dummy tail (longer than seg_max)
@@ -228,42 +224,39 @@ def test_seg_norm_bit_exact_to_scatter_add(case):
     from repro.core import flow
 
     owner, n_comm = _seg_case(case)
-    Bt = 3 if owner.ndim == 1 else owner.shape[0]
-    P = owner.shape[-1]
+    Bt, P = owner.shape
     rng = np.random.default_rng(5)
     x = (rng.random((Bt, P)) * np.exp(rng.normal(0, 4, (Bt, P)))).astype(
         np.float32) + np.float32(1e-3)
-    own2 = np.broadcast_to(owner, (Bt, P))
-    seg_max = max(flow._seg_passes(o[o < n_comm - (owner.ndim == 2)])
-                  for o in own2)
+    seg_max = max(flow._seg_passes(o[o < n_comm - 1]) for o in owner)
 
     @jax.jit
     def new(x, owner):
-        pos, run = flow._batch_seg_layout(owner, n_comm, seg_max)
+        pos, run = flow._seg_layout(owner, seg_max, n_comm - 1)
         return flow._seg_norm(x, pos, run, seg_max)
 
     @jax.jit
     def scatter(x, owner):
-        owner = jnp.broadcast_to(owner, x.shape)
         s = jnp.zeros((Bt, n_comm), jnp.float32).at[
             jnp.arange(Bt)[:, None], owner].add(x)
         return x / jnp.take_along_axis(s, owner, axis=1)
 
     got = np.asarray(new(x, owner))
     ref = np.asarray(scatter(x, owner))
-    real = own2 != n_comm - 1 if owner.ndim == 2 else np.ones_like(own2, bool)
+    real = owner != n_comm - 1
     np.testing.assert_array_equal(got[real], ref[real])
     np.testing.assert_array_equal(got[~real], x[~real])
 
 
 def test_seg_norm_sequential_bit_exact_to_scatter_add():
-    """The sequential solver's (P,) rank: same helper, same bits."""
+    """One (P,) owner row with no dummy commodity: the helpers act on the
+    last axis, so the same bits as the scatter-add."""
     import jax
     import jax.numpy as jnp
 
     from repro.core import flow
 
-    owner, K = _seg_case("shared")
+    owner, K = _seg_case("single_row")
     x = np.random.default_rng(2).random(owner.shape).astype(np.float32)
     seg_max = flow._seg_passes(owner)
     got = jax.jit(lambda x: flow._seg_norm(
@@ -306,33 +299,49 @@ def test_window_compile_shared_within_seg_max_bucket():
 
 
 # --------------------------------------------------------------------------- #
-# shared-topology fast path
+# one routing under several demand vectors; the one backend decision
 # --------------------------------------------------------------------------- #
 
 
 def test_shared_batch_matches_sequential():
+    """A sweep over demands on one routing is B copies of that system."""
     (ps,) = _systems((48,))
     rng = np.random.default_rng(0)
-    dems = np.stack(
-        [
-            ps.demands * (0.5 + rng.random(ps.n_commodities).astype(np.float32))
-            for _ in range(3)
-        ]
-    )
-    shared = PathSystemBatch.from_shared(ps, dems)
-    assert shared.shared and shared.path_edges.ndim == 2
-    bat = mw_concurrent_flow_batch(shared, iters=100)
-    for d, b in zip(dems, bat):
-        s = mw_concurrent_flow(
-            dataclasses.replace(ps, demands=d), iters=100, backend="scatter"
+    copies = [
+        dataclasses.replace(
+            ps,
+            demands=ps.demands
+            * (0.5 + rng.random(ps.n_commodities).astype(np.float32)),
         )
+        for _ in range(3)
+    ]
+    batch = PathSystemBatch.from_systems(copies)
+    assert batch.path_edges.ndim == 3 and batch.slot_gather is not None
+    bat = mw_concurrent_flow_batch(batch, iters=100)
+    for c, b in zip(copies, bat):
+        s = mw_concurrent_flow(c, iters=100, backend="scatter")
         assert s.alpha == b.alpha
 
 
-def test_shared_batch_rejects_bad_demands():
-    (ps,) = _systems((24,))
-    with pytest.raises(ValueError, match="shared-batch demands"):
-        PathSystemBatch.from_shared(ps, np.ones((2, ps.n_commodities + 1)))
+@pytest.mark.parametrize("n_batch, requested, has_table, want", [
+    (1, "auto", True, "gather"),  # the batch policy, even for B = 1
+    (3, "auto", True, "gather"),
+    (2, "gather", False, "scatter"),  # no fan-in table: scatter
+    (2, "auto", False, "scatter"),
+    (2, "scatter", True, "scatter"),  # explicit choices pass through
+    (2, "dense", True, "dense"),
+    (2, "pallas", True, "pallas"),
+])
+def test_congestion_backend_decision(n_batch, requested, has_table, want):
+    batch = PathSystemBatch.from_systems(_systems((24, 40, 32)[:n_batch]))
+    if not has_table:
+        batch = dataclasses.replace(batch, slot_gather=None)
+    backend, table = batch.congestion_backend(requested)
+    assert backend == want
+    if want == "gather":
+        assert table is batch.slot_gather
+    else:
+        assert table is None
 
 
 # --------------------------------------------------------------------------- #
@@ -363,10 +372,10 @@ def test_adaptive_single_compilation_per_solve():
     (ps,) = _systems((32,))
     mw_concurrent_flow(ps, iters=130, early_stop=True, check_every=50,
                        rel_tol=0.0, patience=10**9)
-    base = flow._mw_window._cache_size()
+    base = flow._mw_window_batch._cache_size()
     mw_concurrent_flow(ps, iters=130, early_stop=True, check_every=50,
                        rel_tol=0.0, patience=10**9)
-    assert flow._mw_window._cache_size() == base
+    assert flow._mw_window_batch._cache_size() == base
 
 
 # --------------------------------------------------------------------------- #

@@ -136,7 +136,7 @@ def test_jf102_fires_on_scatter_under_gather_backend():
     assert _audit_fn(scat, x, idx, backend="scatter") == []
     # and the gather backend's real accumulator is scatter-free
     fr = np.ones((2, 9), np.float32)
-    table = np.full((8, 4), 8, np.int32)
+    table = np.full((2, 8, 4), 8, np.int32)
     assert _audit_fn(flow._ordered_fan_in_sum, fr, table,
                      backend="gather") == []
 
@@ -322,7 +322,7 @@ def test_fold_sum_corruption_caught_statically(monkeypatch, fresh_traces):
     tree = audit_fold_tree()
     assert tree and all(f.rule == "JF101" for f in tree)
     # ...and so does tracing the MW window that routes costs through it
-    entry = registered_entries()["repro.core.flow._mw_window"]
+    entry = registered_entries()["repro.core.flow._mw_window_batch"]
     case = next(c for c in entry.cases() if c.label == "scatter")
     fired = audit_case(entry, case)
     assert any(f.rule == "JF101" for f in fired)
@@ -373,7 +373,7 @@ def _census_congestion(backend):
     kw = {}
     if backend == "gather":
         kw["slot_gather"] = jnp.asarray(slot_gather)
-    fn = flow.make_congestion_fn_batch(jnp.asarray(pe3), S, B, backend, **kw)
+    fn = flow.make_congestion_fn_batch(jnp.asarray(pe3), S, backend, **kw)
     rates = np.ones((B, P), np.float32)
     prices = np.ones((B, S), np.float32)
     return primitive_census(jax.make_jaxpr(fn)(rates, prices))

@@ -253,7 +253,7 @@ def test_mw_solve_traced_bit_identical():
     assert base[0] == traced[0]  # alpha, bit-exact
     assert np.array_equal(base[1], traced[1])  # rates, bit-exact
     assert base[2:] == traced[2:]
-    assert any(sp.name == "mw/window" for sp in spans)
+    assert any(sp.name == "mw/window_batch" for sp in spans)
 
 
 def test_mw_batch_traced_bit_identical():
@@ -347,7 +347,7 @@ def test_solver_metrics_recorded():
                        rel_tol=0.5)  # coarse tol: plateaus fast
     snap = obs.snapshot()
     assert snap["mw/solves"] >= 1
-    assert snap["mw/windows"] >= 1
+    assert snap["mw/windows_batch"] >= 1
     assert snap["mw/iters"] >= 40
     assert snap["mw/alpha"] > 0.0
     assert any(k.startswith("mw/stop/") for k in snap)

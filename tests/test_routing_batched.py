@@ -184,17 +184,17 @@ def test_fused_kernel_products_match_scatter_math():
     """
     import jax.numpy as jnp
 
-    from repro.core.flow import dense_incidence, make_congestion_fn
+    from repro.core.flow import make_congestion_fn_batch
 
     ps = _parity_system()
-    pe = jnp.asarray(ps.path_edges)
+    pe = jnp.asarray(ps.path_edges)[None]
     rng = np.random.default_rng(0)
     rates = jnp.asarray(rng.uniform(size=ps.n_paths).astype(np.float32))
     prices = jnp.asarray(rng.uniform(size=ps.n_slots).astype(np.float32))
-    scatter = make_congestion_fn(pe, ps.n_slots, "scatter")
-    pallas = make_congestion_fn(pe, ps.n_slots, "pallas")
-    ls, cs = scatter(rates, prices)
-    lp_, cp = pallas(rates, prices)
+    scatter = make_congestion_fn_batch(pe, ps.n_slots, "scatter")
+    pallas = make_congestion_fn_batch(pe, ps.n_slots, "pallas")
+    ls, cs = (a[0] for a in scatter(rates[None], prices[None]))
+    lp_, cp = (a[0] for a in pallas(rates[None], prices[None]))
     np.testing.assert_allclose(np.asarray(ls), np.asarray(lp_), atol=1e-5)
     np.testing.assert_allclose(np.asarray(cs), np.asarray(cp), atol=1e-5)
     # loads also agree with the numpy PathSystem oracle
@@ -223,7 +223,7 @@ def test_mw_pallas_kernel_matches_scatter():
     ps = _parity_system()
     a = mw_concurrent_flow(ps, iters=25, backend="scatter")
     b = mw_concurrent_flow(ps, iters=25, backend="pallas")
-    assert b.method == "mw-pallas"
+    assert b.method == "mw-batch-pallas"
     assert a.alpha == pytest.approx(b.alpha, abs=1e-5)
     # both feasible
     for res in (a, b):

@@ -188,8 +188,8 @@ def test_loads_fn_matches_fused_backends():
     rates = jnp.asarray(rng.random((B, batch.p_max)).astype(np.float32))
     zeros = jnp.zeros((B, S), jnp.float32)
     for be in ("gather", "scatter", "dense"):
-        fused = make_congestion_fn_batch(pe, S, B, be, tab)
-        loads_fn = make_loads_fn_batch(pe, S, B, be, tab)
+        fused = make_congestion_fn_batch(pe, S, be, tab)
+        loads_fn = make_loads_fn_batch(pe, S, be, tab)
         want = np.asarray(fused(rates, zeros)[0])
         got = np.asarray(loads_fn(rates))
         if be == "dense":

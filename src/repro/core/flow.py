@@ -27,8 +27,9 @@ softmax; padded path rows belong to a zero-demand dummy commodity), and
 ``mw_concurrent_flow_batch`` runs ONE window scan over the stack:
 
 * per-instance adaptive state — plateau / ``target_alpha`` early-stop is
-  tracked per instance on the host, and a converged instance's carry is
-  frozen bit-exactly (masked updates) while stragglers run on;
+  tracked per instance on the host, and converged instances leave the
+  computed batch at a window edge (their carries kept as they were) while
+  stragglers run on;
 * every backend normalises each commodity's split over its contiguous
   run of path rows (``_seg_norm``): at most ``seg_max`` dense shifted
   adds and selects, no scatter or gather.
@@ -699,7 +700,9 @@ def _mw_window_batch(
     carry,  # (x (Bt,P), rel_prev (Bt,S), best_alpha (Bt,), best_x (Bt,P))
     t0,  # traced scalar: first global iteration of this window
     valid_steps,  # traced scalar: live steps this window (rest are no-ops)
-    active,  # (Bt,) bool: instances still iterating (frozen ones pass through)
+    active,  # (Bt,) bool: instances still iterating; an adaptive solve
+    #          drops frozen ones from the batch, and its rung's filler rows
+    #          pass through
     iters_total: int,
     n_steps: int,
     seg_max: int,  # _seg_norm pass count (PathSystemBatch.seg_max)
@@ -717,11 +720,11 @@ def _mw_window_batch(
     Two masks compose per step: ``t - t0 < valid_steps`` (TRACED, so an
     adaptive solve always calls with the same static ``n_steps =
     check_every`` and pads a short final window with no-ops — one
-    compilation serves the whole solve) and ``active`` (per-instance
-    early-stop).  A masked step
-    selects the old carry bit-exactly, so a frozen instance's state — and
-    therefore its final result — is that of stopping it alone at the same
-    window.
+    compilation serves each batch size) and ``active`` (per-instance
+    early-stop).  A masked step selects the old carry bit-exactly, so a
+    row that is not active (a filler of a compacted batch) passes through
+    unchanged.  Every op acts within one instance's row, so an instance's
+    trajectory does not depend on which rows sit beside it, or how many.
     """
     Bt, K = demands.shape
     S = inv_cap.shape[1]
@@ -785,6 +788,67 @@ def _mw_final_batch(path_edges, owner, demands, inv_cap, carry,
     return best_alpha, best_rates, 1.0 / best_alpha
 
 
+#: The window jit itself, for ahead-of-time compiles (``_compile_rungs``):
+#: a caller may wrap the module attribute ``_mw_window_batch`` to record
+#: its calls, and a wrapper has no ``lower``.
+_WINDOW_JIT = _mw_window_batch
+
+#: Window programs compiled ahead of time in this process, by batch size
+#: and envelope.
+_RUNGS_COMPILED: set = set()
+
+
+def _rung(n_live: int) -> int:
+    """Batch size an adaptive window runs at with ``n_live`` live instances:
+    ``n_live`` up to 4, else rounded up to a multiple of 4, so a solve of a
+    bucketed batch of B instances compiles at most ``3 + B / 4`` window
+    programs."""
+    return n_live if n_live <= 4 else _bucket_up(n_live, 4)
+
+
+def _compile_rungs(sizes, tables, carry, iters, check_every, seg_max,
+                   backend):
+    """Compile, without running, the adaptive window program at each batch
+    size in ``sizes``, once per process.
+
+    A compacted solve runs its later windows at smaller batches, which a
+    warm-up solve whose instances all freeze at the first check never
+    reaches; compiled here, no window of the solve compiles.
+    ``lower(...).compile()`` fills the jit's own cache, so a later call at
+    those shapes compiles nothing.  Only the shapes of ``tables`` (the six
+    host tables, the fan-in table ``None`` off ``gather``) and of the full
+    batch's ``carry`` are read.
+    """
+    def at(a, size):
+        return None if a is None else jax.ShapeDtypeStruct(
+            (size,) + a.shape[1:], a.dtype)
+
+    envelope = (iters, check_every, seg_max, backend) + tuple(
+        None if a is None else (a.shape[1:], str(a.dtype))
+        for a in (*tables, *carry))
+    for size in sizes:
+        if (size, envelope) in _RUNGS_COMPILED:
+            continue
+        pe, owner, demands, inv_cap, slot_valid, slot_tab = (
+            at(a, size) for a in tables)
+        _WINDOW_JIT.lower(
+            pe, owner, demands, inv_cap, slot_valid,
+            tuple(at(a, size) for a in carry), 0, check_every,
+            jax.ShapeDtypeStruct((size,), jnp.bool_), iters, check_every,
+            seg_max, backend, slot_tab,
+        ).compile()
+        _RUNGS_COMPILED.add((size, envelope))
+
+
+def _write_back(full, rows, carry) -> list:
+    """Copy the computed batch's carry to the host and into ``full``, the
+    full batch's carry, at the instances ``rows``; returns the copy."""
+    got = [np.asarray(a) for a in carry]
+    for f, a in zip(full, got):
+        f[rows] = a
+    return got
+
+
 def mw_concurrent_flow_batch(
     systems: "PathSystemBatch | Sequence[PathSystem]",
     iters: int = 400,
@@ -802,8 +866,8 @@ def mw_concurrent_flow_batch(
     is pad-and-stacked on the fly, the batch size bucketed with empty
     fillers).  An instance's result does not depend on the rest of the
     batch: the adaptive state (plateau early-stop, ``target_alpha``
-    cutoff) is tracked PER INSTANCE, and a converged instance's carry is
-    frozen bit-exactly while the rest of the batch runs on.
+    cutoff) is tracked PER INSTANCE, and a converged instance leaves the
+    computed batch with its carry as it was, while the rest run on.
 
     ``backend``: ``"auto"`` (gather tables on CPU, dense/scatter by size on
     TPU), ``"gather"``, ``"scatter"``, ``"dense"``, or ``"pallas"`` (force
@@ -825,20 +889,31 @@ def mw_concurrent_flow_batch(
     ``early_stop=False``.  ``target_alpha`` additionally stops an instance
     as soon as its best (exactly evaluated) alpha reaches it — the
     feasibility-probe mode of the ``core.capacity`` search.
-    ``FlowResult.iters`` reports the iterations each instance ran.
+    ``FlowResult.iters`` reports the iterations each instance ran.  At each
+    window edge where the live count falls below the batch (empty fillers
+    are never live), the next windows run on a batch of the live instances
+    only, re-stacked through host copies: the live count up to 4, else
+    that rounded up to a multiple of 4 (``_rung``), topped up with frozen
+    rows that pass through.  A leaving instance's carry is written back to
+    the full batch's, and the final evaluation runs on the full batch.
+    Every window program a solve can reach is compiled at its first solve
+    of an envelope (``_compile_rungs``), so no later window compiles.
 
     Metrics: ``mw/solves`` and ``mw/iters`` count the instances solved and
     their iterations, the ``mw/alpha`` gauge holds the last instance's
-    alpha, ``mw/windows_batch`` counts adaptive windows and
+    alpha, ``mw/windows_batch`` counts adaptive windows,
+    ``mw/compactions`` the re-stacks, and
     ``mw/stop/{target,plateau,budget}`` each instance's stop reason.
 
     Traced (``repro.obs``), the host phases are spans: ``mw/assemble``
     (stacking a sequence; ``rows``), ``mw/upload`` (the host tables sent
     and the carry's set-up; ``bytes``), ``mw/window_batch`` (one per window
     dispatched; ``active`` live instances of ``instances`` computed, the
-    padded batch; ``seg_max`` the split normalisation's pass count),
-    ``mw/sync`` (adaptive solves only, one per window: the host's read of
-    every instance's best alpha and the stop decisions) and
+    batch the window ran at; ``seg_max`` the split normalisation's pass
+    count), ``mw/compact`` (adaptive solves only, one per re-stack: the
+    batch sizes ``before`` and ``after``), ``mw/sync`` (adaptive solves
+    only, one per window: the host's read of the live instances' best
+    alphas and the stop decisions) and
     ``mw/readback`` (the final evaluation copied back: the host's wait for
     the device).
     """
@@ -897,33 +972,65 @@ def mw_concurrent_flow_batch(
     else:
         best_prev = np.zeros(B)
         stall = np.zeros(B, dtype=np.int64)
+        # the computed batch: the instance each of its rows holds, and its
+        # device tables; ``full`` is the full batch's carry on the host,
+        # written once the batch is first compacted
+        rows = np.arange(B)
+        tables = (pe, owner, demands, inv_cap, slot_valid, slot_tab)
+        host_tables = (batch.path_edges, batch.path_owner, batch.demands,
+                       batch.inv_cap, batch.slot_valid, slot_table)
+        full = [np.empty(a.shape, a.dtype) for a in carry]
+        # every smaller batch the solve can reach (the live count only falls)
+        reach = range(1, int(active.sum()) + 1)
+        _compile_rungs(sorted({_rung(n) for n in reach if _rung(n) < B}),
+                       host_tables, carry, iters, check_every, seg_max,
+                       backend)
         t0 = 0
         while t0 < iters and active.any():
+            live = active[rows]
+            n_live = int(live.sum())
+            size = _rung(n_live)
+            if size < len(rows):
+                # frozen instances (and the pad) leave the computed batch;
+                # a rung above the live count is filled with frozen rows,
+                # which pass through
+                with obs.span("mw/compact", t0=t0, before=len(rows),
+                              after=size):
+                    got = _write_back(full, rows, carry)
+                    keep = np.sort(np.concatenate([
+                        np.flatnonzero(live),
+                        np.flatnonzero(~live)[: size - n_live]]))
+                    rows = rows[keep]
+                    live = active[rows]
+                    tables = tuple(None if a is None else jnp.asarray(a[rows])
+                                   for a in host_tables)
+                    carry = tuple(jnp.asarray(a[keep]) for a in got)
+                obs.counter("mw/compactions").inc()
             step = min(check_every, iters - t0)
             with obs.span("mw/window_batch", t0=t0, step=step,
-                          active=int(active.sum()), instances=B,
+                          active=n_live, instances=len(rows),
                           seg_max=seg_max):
                 carry = _mw_window_batch(
-                    pe, owner, demands, inv_cap, slot_valid, carry, t0, step,
-                    jnp.asarray(active), iters, check_every, seg_max, backend,
-                    slot_tab,
+                    *tables[:5], carry, t0, step, jnp.asarray(live), iters,
+                    check_every, seg_max, backend, tables[5],
                 )
                 t0 += step
                 done[active] += step
             obs.counter("mw/windows_batch").inc()
             # the host waits here for the window, then decides who stops
-            with obs.span("mw/sync", t0=t0, active=int(active.sum())):
+            with obs.span("mw/sync", t0=t0, active=n_live):
                 best = np.asarray(carry[2])
                 if obs.trace_enabled():
                     obs.counter_event("mw/alpha_batch_mean",
-                                      float(best[active].mean()))
-                for b in np.flatnonzero(active):
-                    if target_alpha is not None and best[b] >= target_alpha:
+                                      float(best[live].mean()))
+                for j in np.flatnonzero(live):
+                    b = rows[j]
+                    if target_alpha is not None and best[j] >= target_alpha:
                         active[b] = False
                         obs.counter("mw/stop/target").inc()
                         continue
                     if early_stop:
-                        if best[b] - best_prev[b] < rel_tol * max(best[b],
+                        if best[j] - best_prev[b] < rel_tol * max(best[j],
                                                                   1e-12):
                             stall[b] += 1
                             if stall[b] >= patience:
@@ -932,9 +1039,12 @@ def mw_concurrent_flow_batch(
                                 continue
                         else:
                             stall[b] = 0
-                        best_prev[b] = max(best[b], best_prev[b])
+                        best_prev[b] = max(best[j], best_prev[b])
         if active.any():
             obs.counter("mw/stop/budget").inc(int(active.sum()))
+        if len(rows) < B:
+            _write_back(full, rows, carry)
+            carry = tuple(jnp.asarray(a) for a in full)
     # the host waits here for the device to finish the solve
     with obs.span("mw/readback", instances=B):
         alpha, rates, max_load = _mw_final_batch(

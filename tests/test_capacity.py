@@ -142,9 +142,14 @@ def test_wave_traced_spans_and_bit_identical():
     syncs = [s for s in spans if s.name == "mw/sync"]
     # 500 iterations in windows of 50: the budget-bound instance runs all
     assert len(windows) == len(syncs) == 10
-    assert all(s.attrs["instances"] == 4 for s in windows)
     assert [s.attrs["active"] for s in windows] == [4, 4, 4, 2, 2, 2, 2, 1,
                                                     1, 1]
+    # frozen instances leave the computed batch at the window edge
+    assert [s.attrs["instances"] for s in windows] == [
+        s.attrs["active"] for s in windows]
+    assert [(s.attrs["t0"], s.attrs["before"], s.attrs["after"])
+            for s in spans if s.name == "mw/compact"] == [(150, 4, 2),
+                                                          (350, 2, 1)]
 
 
 def test_fixed_budget_window_carries_instances():

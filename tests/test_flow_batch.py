@@ -5,7 +5,8 @@ not depend on the batch it is solved in — a batch of one
 (``mw_concurrent_flow``), other instances, or bucket padding — bit-exactly
 on the scatter backend and on the gather backend (ordered fan-in sums
 match the scatter association), including EXACT per-instance iteration
-counts under the frozen-instance adaptive early-stop.  Plus ragged/empty
+counts under the adaptive early-stop, whose frozen instances leave the
+computed batch (and compile nothing after a warm-up).  Plus ragged/empty
 batches, one routing under several demand vectors, the one congestion
 backend decision, the jit-churn window padding, the speculative
 bisection's sequential-equality guarantee, the REPRO_LP_PATH_LIMIT import
@@ -96,6 +97,97 @@ def test_batch_adaptive_iteration_counts_agree_exactly():
     for s, b in zip(seq, bat):
         assert s.iters == b.iters
         assert s.alpha == b.alpha
+
+
+#: Adaptive solves whose instances freeze at different windows.  With 3
+#: instances the batch is bucketed to 4 and the empty pad is dropped before
+#: the first window; with 9 it is bucketed to 12, and the 7 left live after
+#: the first freezes run at a batch of 8 topped up by one frozen instance.
+_FREEZE_KW = dict(iters=300, early_stop=True, check_every=25,
+                  target_alpha=0.55)
+_FREEZE_SIZES = {
+    "four": (24, 40, 60, 32),
+    "three_and_pad": (24, 40, 60),
+    "nine_with_filler": (24, 40, 60, 32, 48, 36, 28, 44, 52),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_FREEZE_SIZES))
+@pytest.mark.parametrize("backend", ["scatter", "gather"])
+def test_compacted_batch_bit_exact_to_alone(backend, case):
+    """Frozen instances leave the computed batch at a window edge: every
+    instance's alpha, rates and iteration count equal its solve alone, bit
+    for bit."""
+    systems = _systems(_FREEZE_SIZES[case])
+    bat = mw_concurrent_flow_batch(systems, backend=backend, **_FREEZE_KW)
+    assert len({b.iters for b in bat}) >= 3, "freeze windows differ"
+    for ps, b in zip(systems, bat):
+        s = mw_concurrent_flow(ps, backend=backend, **_FREEZE_KW)
+        assert (s.alpha, s.iters, s.max_load) == (b.alpha, b.iters,
+                                                  b.max_load)
+        np.testing.assert_array_equal(s.rates, b.rates)
+
+
+@pytest.mark.parametrize("case, compactions", [
+    ("three_and_pad", [(0, 4, 3), (75, 3, 2), (125, 2, 1)]),
+    ("nine_with_filler", [(75, 12, 8), (225, 8, 4)]),
+])
+def test_compacted_windows_compute_the_live_instances(case, compactions):
+    """Each window computes the live instances only (the live count, or
+    that rounded up to a multiple of 4 above 4), and each re-stack is one
+    ``mw/compact`` span and one ``mw/compactions`` count."""
+    from repro import obs
+
+    systems = _systems(_FREEZE_SIZES[case])
+    prev = obs.set_trace(True)
+    before = obs.snapshot().get("mw/compactions", 0)
+    try:
+        obs.reset_trace()
+        mw_concurrent_flow_batch(systems, backend="scatter", **_FREEZE_KW)
+        spans = sorted(obs.get_spans(), key=lambda s: s.t0)
+        after = obs.snapshot()["mw/compactions"]
+    finally:
+        obs.set_trace(prev)
+        obs.reset_trace()
+    windows = [s for s in spans if s.name == "mw/window_batch"]
+    live = [s.attrs["active"] for s in windows]
+    assert [s.attrs["instances"] for s in windows] == [
+        n if n <= 4 else -(-n // 4) * 4 for n in live]
+    assert [(s.attrs["t0"], s.attrs["before"], s.attrs["after"])
+            for s in spans if s.name == "mw/compact"] == compactions
+    assert after - before == len(compactions)
+
+
+def test_compacted_solve_compiles_nothing_after_warm_up():
+    """A warm-up solve whose instances all freeze at the first check
+    compiles every window program a later solve of the same envelope can
+    compact to: that solve, whose instances freeze at three different
+    windows, compiles nothing."""
+    import jax
+
+    from repro.core import flow
+
+    systems = _systems(_FREEZE_SIZES["three_and_pad"])
+    jax.clear_caches()  # a fresh process: nothing compiled yet
+    flow._RUNGS_COMPILED.clear()
+    compiles = []
+
+    def on(event, duration, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(duration)
+
+    jax.monitoring.register_event_duration_secs_listener(on)
+    try:
+        mw_concurrent_flow_batch(systems, iters=300, check_every=25,
+                                 backend="scatter", target_alpha=0.0)
+        warm = len(compiles)
+        res = mw_concurrent_flow_batch(systems, backend="scatter",
+                                       **_FREEZE_KW)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on)
+    assert warm > 0
+    assert sorted({r.iters for r in res}) == [75, 125, 300]
+    assert len(compiles) == warm
 
 
 def test_batch_plateau_early_stop_agrees():
